@@ -102,14 +102,13 @@ DEFAULT_COEFFS = {
 
 
 def device_kind() -> str:
-    """Kind string of device 0 ("cpu", "TPU v5e", ...), or "none"."""
-    try:
-        import jax
+    """Kind string of device 0 ("cpu", "TPU v5 lite", ...). A missing
+    backend raises: a profile key must never name a device that was not
+    there."""
+    import jax
 
-        d = jax.devices()[0]
-        return str(getattr(d, "device_kind", None) or d.platform)
-    except Exception:  # no backend at all
-        return "none"
+    d = jax.devices()[0]
+    return str(d.device_kind or d.platform)
 
 
 def profile_key(backend: str, kind: Optional[str] = None) -> str:

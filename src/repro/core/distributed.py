@@ -56,7 +56,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import plan as _plan
 from repro.core import schedule as _schedule
 
@@ -217,7 +216,7 @@ def spamm_rowpart(
                                            backend=backend,
                                            sched_levels=sched_levels,
                                            offsets=offsets)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _local_spamm, tau=tau, tile=tile, backend=backend,
             block_n=block_n, compute_dtype=compute_dtype,
@@ -303,7 +302,7 @@ def spamm_2d(
                                            backend=backend,
                                            sched_levels=sched_levels,
                                            offsets=offsets)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _local_spamm_psum,
             tau=tau,

@@ -892,6 +892,7 @@ def plan(
     if (tau is None) == (valid_ratio is None):
         raise ValueError("give exactly one of tau / valid_ratio")
     bk = kops.get_backend(backend)
+    bk.check_tile(tile)
 
     compute_dtype = kquant.canonical_dtype(compute_dtype)
     a_scale = b_scale = None

@@ -16,7 +16,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from repro.launch.mesh import mesh_from_devices
 from repro.models import model as M
 
 
@@ -35,7 +34,7 @@ def build_elastic_mesh(devices: Optional[Sequence] = None,
     devices = list(devices if devices is not None else jax.devices())
     data, model = best_mesh_shape(len(devices), model_parallel)
     used = np.array(devices[: data * model]).reshape(data, model)
-    return mesh_from_devices(used, ("data", "model"))
+    return Mesh(used, ("data", "model"))
 
 
 def reshard_state(state, cfg, pcfg, new_mesh: Mesh):

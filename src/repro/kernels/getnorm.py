@@ -9,11 +9,16 @@ TPU adaptation of the paper's reduction design:
     resident tile in one shot.
   * the paper's tensor-core reduction (Eq. 3–4: D = 1·X, D' = D·1) is kept as
     an optional MXU path (`use_mxu=True`): two `lax.dot`s against a ones
-    vector/matrix — useful when the tile is large and MXU-aligned.
-  * output blocking: each kernel invocation owns one *row* of the normmap
-    ((1, grid_k) block revisited across the k grid dimension), so the normmap
-    row stays VMEM-resident and is flushed to HBM once — the analogue of the
-    paper's "thread 0 writes the result back" without a global sync.
+    block — useful when the tile is large and MXU-aligned.
+  * output blocking: each kernel invocation owns one *row* of the normmap,
+    held as an (8, grid_k) block revisited across the k grid dimension (8
+    sublanes, so the block is legal for the TPU compiler; every sublane holds
+    the same row and the wrapper keeps one). The normmap row stays
+    VMEM-resident and is flushed to HBM once — the analogue of the paper's
+    "thread 0 writes the result back" without a global sync.
+
+Compiled (interpret=False) calls need a tile that is a multiple of 128
+(`repro.kernels.common.check_tile`); interpret mode takes any tile.
 """
 from __future__ import annotations
 
@@ -24,34 +29,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
 from repro.kernels import quantize as _quant
+from repro.kernels.common import LANE, check_tile, mesh_vma
+
+SUBLANES = 8   # rows of an output row block (one f32 vreg is 8 × 128)
 
 
 def _tile_sumsq(sq, *, use_mxu: bool):
-    """Reduce one resident (t, t) f32 tile of squares to a scalar — the
+    """Reduce one resident (t, t) f32 tile of squares to a (1, 1) sum — the
     body shared by the plain and fused-quantizing get-norm kernels (one
     reduction implementation ⇒ the fused norms are bit-identical to the
     unfused quantize→dequantize→norms composition)."""
     if use_mxu:
-        # Paper Eq. 3–4 on the MXU: row-sum then total via dot against ones.
-        t = sq.shape[0]
-        ones_col = jnp.ones((t, 1), jnp.float32)
-        rows = jax.lax.dot_general(  # (1, t) · (t, t) -> row sums? use X^T·1
-            sq, ones_col, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (t, 1) row sums
+        # Paper Eq. 3–4 on the MXU: row sums, then their total, each a dot
+        # against a lane-dense ones block (every output column is the same)
+        ones = jnp.ones((sq.shape[0], LANE), jnp.float32)
+        hi = jax.lax.Precision.HIGHEST
+        rows = jax.lax.dot_general(
+            sq, ones, (((1,), (0,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32)      # (t, LANE) row sums
         total = jax.lax.dot_general(
-            ones_col, rows, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (1, 1)
-        return total[0, 0]
-    return jnp.sum(sq)
+            ones, rows, (((0,), (0,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32)      # (LANE, LANE) totals
+        # a reduction (not a slice) yields the (1, 1) value: Mosaic cannot
+        # broadcast a sliced (1, 1) vector over sublanes and lanes at once
+        return jnp.sum(total[:1, :1], keepdims=True)
+    return jnp.sum(sq, keepdims=True)
+
+
+def _put_norm(o_ref, j, val):
+    """Write the (1, 1) `val` into column j of the resident (8, grid_k) row
+    block: a masked full-block select instead of a scalar store, so the
+    store stays lane-dense while j is a grid index."""
+    @pl.when(j == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o_ref[...] = jnp.where(col == j, val, o_ref[...])
 
 
 def _getnorm_kernel(x_ref, o_ref, *, use_mxu: bool):
-    j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)
-    s = _tile_sumsq(x * x, use_mxu=use_mxu)
-    o_ref[0, j] = jnp.sqrt(s)
+    _put_norm(o_ref, pl.program_id(1),
+              jnp.sqrt(_tile_sumsq(x * x, use_mxu=use_mxu)))
 
 
 def _getnorm_quant_kernel(x_ref, o_ref, s_ref, *, use_mxu: bool):
@@ -67,31 +88,32 @@ def _getnorm_quant_kernel(x_ref, o_ref, s_ref, *, use_mxu: bool):
     """
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)
-    scale = (jnp.maximum(jnp.max(jnp.abs(x)), _quant._TINY)
+    scale = (jnp.maximum(jnp.max(jnp.abs(x), keepdims=True), _quant._TINY)
              * jnp.float32(_quant._INV127))
     dq = jnp.clip(jnp.round(x / scale), -127.0, 127.0) * scale
-    s = _tile_sumsq(dq * dq, use_mxu=use_mxu)
-    o_ref[0, j] = jnp.sqrt(s)
-    s_ref[0, j] = scale
+    _put_norm(o_ref, j, jnp.sqrt(_tile_sumsq(dq * dq, use_mxu=use_mxu)))
+    _put_norm(s_ref, j, scale)
 
 
 def _pool_kernel(n_ref, o_ref):
-    """sqrt-sumsq 2×2 pooling: one grid step pools one coarse normmap row.
+    """sqrt-sumsq 2×2 pooling of a whole even-dimensioned normmap.
 
-    Row pairing is a VPU add; column pairing runs as a dot against the
-    0/1 pooling matrix (kf // 2 == kc) so the lane-dim reduction stays
-    MXU/VPU-friendly (no strided lane slicing)."""
-    x = n_ref[...].astype(jnp.float32)          # (2, 2·gkc) fine rows pair
-    sq = x * x
-    rows = sq[0:1, :] + sq[1:2, :]              # (1, 2·gkc) row-pooled sumsq
-    w = rows.shape[1]
-    kf = jax.lax.broadcasted_iota(jnp.int32, (w, w // 2), 0)
-    kc = jax.lax.broadcasted_iota(jnp.int32, (w, w // 2), 1)
-    pool = (kf // 2 == kc).astype(jnp.float32)  # (2·gkc, gkc) column pairing
-    s = jax.lax.dot_general(
-        rows, pool, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                           # (1, gkc)
-    o_ref[0, :] = jnp.sqrt(s[0])
+    Row pairing and column pairing each run as a dot against a 0/1 pooling
+    matrix (fine index // 2 == coarse index), so neither needs strided
+    sublane or lane slicing. HIGHEST precision keeps the dots at f32."""
+    sq = jnp.square(n_ref[...].astype(jnp.float32))     # (2·gmc, 2·gkc)
+    gmc, gkc = o_ref.shape
+    gm2, gk2 = sq.shape
+    rpool = (jax.lax.broadcasted_iota(jnp.int32, (gmc, gm2), 1) // 2
+             == jax.lax.broadcasted_iota(jnp.int32, (gmc, gm2), 0))
+    cpool = (jax.lax.broadcasted_iota(jnp.int32, (gk2, gkc), 0) // 2
+             == jax.lax.broadcasted_iota(jnp.int32, (gk2, gkc), 1))
+    hi = jax.lax.Precision.HIGHEST
+    rows = jnp.dot(rpool.astype(jnp.float32), sq, precision=hi,
+                   preferred_element_type=jnp.float32)  # (gmc, 2·gkc)
+    s = jnp.dot(rows, cpool.astype(jnp.float32), precision=hi,
+                preferred_element_type=jnp.float32)     # (gmc, gkc)
+    o_ref[...] = jnp.sqrt(s)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -101,7 +123,8 @@ def pool_norms(normmap: jax.Array, *, interpret: bool = False) -> jax.Array:
     normmap: (gm, gk) f32 level-(l-1) normmap; odd dims are zero-padded.
     Returns (⌈gm/2⌉, ⌈gk/2⌉) f32 — sqrt of 2×2 sumsq pooling, i.e. the exact
     Frobenius norm of each 2×2 tile group (one cheap reduction, no re-read of
-    the underlying matrix).
+    the underlying matrix). A normmap is small (gm·gk floats), so one grid
+    step pools the whole of it: whole-array blocks are always legal.
     """
     gm, gk = normmap.shape
     pm, pk = gm % 2, gk % 2
@@ -110,10 +133,8 @@ def pool_norms(normmap: jax.Array, *, interpret: bool = False) -> jax.Array:
     gmc, gkc = (gm + pm) // 2, (gk + pk) // 2
     return pl.pallas_call(
         _pool_kernel,
-        grid=(gmc,),
-        in_specs=[pl.BlockSpec((2, 2 * gkc), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, gkc), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((gmc, gkc), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((gmc, gkc), jnp.float32,
+                                       vma=mesh_vma(normmap)),
         interpret=interpret,
         name="spamm_norm_pool",
     )(normmap)
@@ -141,6 +162,32 @@ def norm_pyramid(
     return tuple(maps)
 
 
+def _norm_rows_call(kernel, x, tile, n_out, interpret, name):
+    """Run a get-norm `kernel` over the (tile × tile) grid of x with `n_out`
+    (M//tile, K//tile) f32 outputs, each written as (8, K//tile) row blocks."""
+    m, k = x.shape
+    if m % tile or k % tile:
+        raise ValueError(f"shape {x.shape} not divisible by tile {tile}")
+    if not interpret:
+        check_tile(tile)
+    gm, gk = m // tile, k // tile
+    rows = pl.BlockSpec((SUBLANES, gk), lambda i, j: (i, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(gm, gk),
+        in_specs=[pl.BlockSpec((tile, tile), lambda i, j: (i, j))],
+        out_specs=[rows] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((gm * SUBLANES, gk), jnp.float32,
+                                        vma=mesh_vma(x))] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name=name,
+    )(x)
+    return [o.reshape(gm, SUBLANES, gk)[:, 0] for o in outs]
+
+
 @functools.partial(
     jax.jit, static_argnames=("tile", "use_mxu", "interpret")
 )
@@ -155,23 +202,8 @@ def tile_norms(
 
     x: (M, K) with M % tile == 0 == K % tile. Returns (M//tile, K//tile) f32.
     """
-    m, k = x.shape
-    if m % tile or k % tile:
-        raise ValueError(f"shape {x.shape} not divisible by tile {tile}")
-    gm, gk = m // tile, k // tile
     kernel = functools.partial(_getnorm_kernel, use_mxu=use_mxu)
-    return pl.pallas_call(
-        kernel,
-        grid=(gm, gk),
-        in_specs=[pl.BlockSpec((tile, tile), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, gk), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((gm, gk), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="spamm_getnorm",
-    )(x)
+    return _norm_rows_call(kernel, x, tile, 1, interpret, "spamm_getnorm")[0]
 
 
 @functools.partial(
@@ -195,26 +227,7 @@ def tile_norms_quant(
     read) into one, which is how `execute()`-bound int8 plans get their
     activation scales without a separate per-call pass.
     """
-    m, k = x.shape
-    if m % tile or k % tile:
-        raise ValueError(f"shape {x.shape} not divisible by tile {tile}")
-    gm, gk = m // tile, k // tile
     kernel = functools.partial(_getnorm_quant_kernel, use_mxu=use_mxu)
-    return pl.pallas_call(
-        kernel,
-        grid=(gm, gk),
-        in_specs=[pl.BlockSpec((tile, tile), lambda i, j: (i, j))],
-        out_specs=[
-            pl.BlockSpec((1, gk), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, gk), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((gm, gk), jnp.float32),
-            jax.ShapeDtypeStruct((gm, gk), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="spamm_getnorm_quant",
-    )(x)
+    norms, scales = _norm_rows_call(kernel, x, tile, 2, interpret,
+                                    "spamm_getnorm_quant")
+    return norms, scales

@@ -1,7 +1,8 @@
 """Backend registry + jit'd wrappers for the cuSpAMM kernels.
 
 backends (each a `Backend` record in `BACKENDS`):
-  "pallas"    — compiled Pallas TPU kernels (requires a real TPU).
+  "pallas"    — compiled Pallas TPU kernels (requires a real TPU; tiles
+                must be multiples of 128, `repro.kernels.common`).
   "interpret" — Pallas kernels executed with interpret=True (CPU-correctness
                 path; runs the exact kernel body in Python/XLA emulation).
   "jnp"       — pure-jnp oracles from ref.py (used for the CPU dry-run and as
@@ -28,6 +29,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import common as _common
 from repro.kernels import getnorm as _getnorm
 from repro.kernels import ref as _ref
 from repro.kernels import spamm_mm as _spamm_mm
@@ -35,10 +37,7 @@ from repro.kernels import spamm_mm as _spamm_mm
 
 @functools.cache
 def _has_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # no backend
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -84,6 +83,8 @@ class Backend:
       QUANTIZED view plus the per-tile quantization scales from one read.
       None ⇒ `int8_norms_and_scales` composes the unfused
       quantize→dequantize→norms path (bit-identical results either way).
+    compiled: the kernels go through the TPU compiler, whose block layout
+      rules bind the tile (`check_tile`); interpret/jnp take any tile.
     """
     name: str
     norms: Callable[..., jax.Array]
@@ -93,6 +94,12 @@ class Backend:
     matmul_worklist: Callable[..., jax.Array] = None
     matmul_worklist_int8: Callable[..., jax.Array] = None
     norms_quant: Callable[..., tuple] = None
+    compiled: bool = False
+
+    def check_tile(self, tile: int) -> None:
+        """Raise a ValueError if this backend cannot run `tile`."""
+        if self.compiled:
+            _common.check_tile(tile)
 
 
 def _jnp_norms(x, tile, use_mxu=False):
@@ -194,7 +201,8 @@ BACKENDS = {
                       pyramid_norms=_pallas_pyramid_norms(False),
                       matmul_worklist=_pallas_matmul_worklist(False),
                       matmul_worklist_int8=_pallas_matmul_worklist_int8(False),
-                      norms_quant=_pallas_norms_quant(False)),
+                      norms_quant=_pallas_norms_quant(False),
+                      compiled=True),
 }
 
 VALID_BACKENDS = ("auto", *BACKENDS)

@@ -60,20 +60,24 @@ def spamm_matmul_ref(
     tile: int,
     *,
     precision=None,
+    mask=None,
 ) -> jax.Array:
     """Reference SpAMM: C[i,j] = sum_k bitmap[i,j,k] * A[i,k] @ B[k,j].
 
     a: (M, K), b: (K, N); M, K, N divisible by `tile`.
     Computed as a dense blocked einsum with the mask applied to A-blocks —
-    mathematically identical to skipping the products.
+    mathematically identical to skipping the products. `mask` (gm, gn, gk)
+    replaces the τ-test bitmap (tau is then ignored): a kernel given a
+    plan's work-list is checked against the product over that same set.
     """
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
     gm, gk, gn = m // tile, k // tile, n // tile
-    na = tile_norms_ref(a, tile)  # (gm, gk)
-    nb = tile_norms_ref(b, tile)  # (gk, gn)
-    mask = spamm_mask_ref(na, nb, jnp.asarray(tau, jnp.float32))  # (gm, gn, gk)
+    if mask is None:
+        na = tile_norms_ref(a, tile)  # (gm, gk)
+        nb = tile_norms_ref(b, tile)  # (gk, gn)
+        mask = spamm_mask_ref(na, nb, jnp.asarray(tau, jnp.float32))
     a4 = a.reshape(gm, tile, gk, tile)
     b4 = b.reshape(gk, tile, gn, tile)
     # out[i p, j q] = sum_{k, s} mask[i,j,k] a[i,p,k,s] b[k,s,j,q]

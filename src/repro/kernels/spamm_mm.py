@@ -48,7 +48,8 @@ Dtype contract (paper Alg. 3's tensor-core path, generalized):
   f32 in VMEM regardless of input dtype, and the FLUSH step casts it to
   `out_dtype` exactly once per output tile. Three input precisions:
 
-  * f32:  `spamm_mm_worklist` as-is. MXU accumulates in f32.
+  * f32:  `spamm_mm_worklist` as-is. The MXU multiplies at f32 (HIGHEST
+          precision, `_mxu_dot`) and accumulates in f32.
   * bf16: the SAME `spamm_mm_worklist` entry point — pass bf16 `a`/`b` and
     the `jnp.dot(..., preferred_element_type=f32)` body feeds the MXU's
     native bf16×bf16→f32 path. No kernel change: the flag-bit step-table
@@ -76,7 +77,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
+from repro.kernels.common import check_smem, check_tile, mesh_vma, varying
+
+
+def _mxu_dot(a, b):
+    """One tile product into the f32 accumulator. f32 operands multiply at
+    f32 (HIGHEST): Mosaic's default for them is one bf16 pass, measured at
+    3.2e-3 of max |C| on a v5e at n = 4096 against 4.2e-7 for f32. bf16
+    operands take their native single pass (products exact in f32)."""
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
 
 
 def _spamm_mm_kernel(kidx_ref, nv_ref, a_ref, b_ref, o_ref, acc_ref):
@@ -93,9 +103,7 @@ def _spamm_mm_kernel(kidx_ref, nv_ref, a_ref, b_ref, o_ref, acc_ref):
     # trailing steps are masked out (their block fetches are revisits = free).
     @pl.when(t < nv_ref[i, j])
     def _compute():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when(t == nt - 1)
     def _flush():
@@ -136,6 +144,9 @@ def spamm_mm(
     gn = n // (tile * block_n)
     assert kidx.shape == (gm, gn, gk), (kidx.shape, (gm, gn, gk))
     assert nvalid.shape == (gm, gn)
+    if not interpret:
+        check_tile(tile)
+        check_smem("spamm_mm", kidx, nvalid)
 
     grid = (gm, gn, gk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -155,8 +166,9 @@ def spamm_mm(
     return pl.pallas_call(
         _spamm_mm_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(
+            (m, n), out_dtype, vma=mesh_vma(a, b, kidx, nvalid)),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -187,9 +199,7 @@ def _spamm_mm_worklist_kernel(
     # carry no flag bits, so they neither accumulate nor flush).
     @pl.when((f & STEP_ACC) != 0)
     def _compute():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when((f & STEP_FLUSH) != 0)
     def _flush():
@@ -235,6 +245,9 @@ def spamm_mm_worklist(
         a.shape, b.shape, tile, block_n)
     s = step_i.shape[0]
     assert step_j.shape == step_k.shape == step_flags.shape == (s,)
+    if not interpret:
+        check_tile(tile)
+        check_smem("spamm_mm_worklist", step_i, step_j, step_k, step_flags)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -259,21 +272,22 @@ def spamm_mm_worklist(
         ),
         scratch_shapes=[pltpu.VMEM((tile, tile * block_n), jnp.float32)],
     )
+    vma = mesh_vma(a, b, step_i, step_j, step_k, step_flags)
     return pl.pallas_call(
         _spamm_mm_worklist_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
         # index 4 counts the scalar-prefetch tables: the zeros operand seeds
         # the output buffer, so (i, j) tiles the work-list never visits are
         # zero rather than uninitialized
         input_output_aliases={4: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="spamm_mm_worklist",
     )(step_i, step_j, step_k, step_flags,
-      jnp.zeros((m, n), out_dtype), a, b)
+      varying(jnp.zeros((m, n), out_dtype), vma), a, b)
 
 
 def _spamm_mm_worklist_int8_kernel(
@@ -358,6 +372,10 @@ def spamm_mm_worklist_int8(
     assert b_scale.shape == (gk, gn), (b_scale.shape, (gk, gn))
     s = step_i.shape[0]
     assert step_j.shape == step_k.shape == step_flags.shape == (s,)
+    if not interpret:
+        check_tile(tile)
+        check_smem("spamm_mm_worklist_int8", step_i, step_j, step_k,
+                   step_flags, a_scale, b_scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -382,17 +400,19 @@ def spamm_mm_worklist_int8(
         scratch_shapes=[pltpu.VMEM((tile, tile * block_n), jnp.float32)],
     )
     kernel = functools.partial(_spamm_mm_worklist_int8_kernel, block_n=block_n)
+    vma = mesh_vma(a_q, b_q, a_scale, b_scale, step_i, step_j, step_k,
+                   step_flags)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
         # index 6 counts the 6 scalar-prefetch tables; the zeros operand
         # seeds the aliased output buffer (unvisited tiles stay zero)
         input_output_aliases={6: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="spamm_mm_worklist_int8",
     )(step_i, step_j, step_k, step_flags, a_scale, b_scale,
-      jnp.zeros((m, n), out_dtype), a_q, b_q)
+      varying(jnp.zeros((m, n), out_dtype), vma), a_q, b_q)

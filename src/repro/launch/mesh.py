@@ -3,44 +3,23 @@
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialization).
 
-Also the home of the `AxisType` compat shim: jax >= 0.5 grew
-`jax.sharding.AxisType` and `jax.make_mesh(..., axis_types=...)`; on
-jax 0.4.x neither exists (every axis is implicitly "auto"). All mesh
-construction in this repo goes through `make_mesh` / `mesh_from_devices`
-below so the same code runs on both.
+`jax.make_mesh` defaults every axis to `AxisType.Explicit`; the model zoo
+shards with auto axes (sharding constraints + GSPMD propagation), so every
+mesh here is built through `make_mesh` below, which asks for `Auto`.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-
-    _AXIS_TYPES = True
-except ImportError:  # jax 0.4.x: axes are implicitly auto-sharded
-    AxisType = None
-    _AXIS_TYPES = False
+from jax.sharding import AxisType, Mesh
 
 from repro.models.transformer import NetCtx
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """`jax.make_mesh` with every axis auto-sharded, on any jax version."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _AXIS_TYPES:
-        kw["axis_types"] = (AxisType.Auto,) * len(axis_shapes)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def mesh_from_devices(device_array, axis_names) -> Mesh:
-    """`Mesh(devices, names)` with auto axes where the jax version has them."""
-    if _AXIS_TYPES:
-        return Mesh(device_array, axis_names,
-                    axis_types=(AxisType.Auto,) * len(axis_names))
-    return Mesh(device_array, axis_names)
+    """`jax.make_mesh` with every axis auto-sharded."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

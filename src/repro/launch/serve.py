@@ -2,19 +2,54 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch musicgen-large --reduced \
       --num-requests 8 --prompt-len 32 --max-new 16
+
+`make_engine` is the construction every serving entry point shares (this
+CLI and `chip_smoke.py`).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import ParallelConfig, SpammConfig, get_config
+from repro.kernels import ops as kops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_ctx, make_host_mesh
 from repro.models import model as M
 from repro.serving.engine import Engine, Request
+
+# f32 weights and activations; flash attention in 64-token chunks
+SERVE_PCFG = ParallelConfig(
+    compute_dtype="float32", remat="none", decode_seq_shard=False,
+    attn_q_chunk=64, attn_kv_chunk=64,
+)
+
+
+def default_tile(backend: str) -> int:
+    """SpAMM tile when none is given: 128 where the kernels are compiled
+    (their (tile, tile) blocks must span the 128-wide lane dimension), 32
+    on the interpret/jnp backends."""
+    return 128 if kops.get_backend(backend).compiled else 32
+
+
+def init_params(cfg, seed: int):
+    """Random weights from `seed`, made in one compiled program so no eager
+    per-leaf temporaries sit beside the full-size weights."""
+    return jax.jit(functools.partial(M.init_params, cfg, SERVE_PCFG))(
+        jax.random.key(seed))
+
+
+def make_engine(cfg, params, *, max_len: int, spamm_cfg=None,
+                **engine_kw) -> Engine:
+    """The serving engine on a one-device host mesh (pod-sharded serving
+    builds its own mesh from `mesh_devices`)."""
+    ctx = make_ctx(make_host_mesh())
+    return Engine(cfg, SERVE_PCFG, ctx, params, max_len=max_len,
+                  spamm_cfg=spamm_cfg, **engine_kw)
 
 
 def main():
@@ -46,7 +81,10 @@ def main():
                     help="enable SpAMM norm-gated GEMMs at this τ — prefill "
                          "AND decode gate (decode through frozen plans); "
                          "one SpammContext per engine")
-    ap.add_argument("--spamm-tile", type=int, default=32)
+    ap.add_argument("--spamm-tile", type=int, default=None,
+                    help="gating tile; default 128 on the pallas backend "
+                         "(the compiled kernels need multiples of 128), 32 "
+                         "on interpret/jnp")
     ap.add_argument("--spamm-backend", default="auto")
     ap.add_argument("--spamm-block-n", type=int, default=1,
                     help="super-column width of the mm kernel; must match "
@@ -116,21 +154,20 @@ def main():
                          "trace JSON (freeze, plan assembly, prefill, "
                          "decode steps, reshard probes; load in Perfetto)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    pcfg = ParallelConfig(
-        compute_dtype="float32", remat="none", decode_seq_shard=False,
-        attn_q_chunk=64, attn_kv_chunk=64,
-    )
-    mesh = make_host_mesh()
-    ctx = make_ctx(mesh)
-    params = M.init_params(cfg, pcfg, jax.random.key(args.seed))
+    params = init_params(cfg, args.seed)
     spamm_cfg = None
     if args.spamm_tau is not None:
+        backend = kops.resolve_backend(args.spamm_backend)
+        tile = args.spamm_tile or default_tile(backend)
+        print(f"spamm backend: {args.spamm_backend} -> {backend} "
+              f"(tile {tile}, device {jax.devices()[0].device_kind})")
         spamm_cfg = SpammConfig(enable=True, tau=args.spamm_tau,
-                                tile=args.spamm_tile,
+                                tile=tile,
                                 backend=args.spamm_backend,
                                 block_n=args.spamm_block_n,
                                 levels=args.spamm_levels,
@@ -150,15 +187,15 @@ def main():
     from repro.obs import Observability
 
     obs = Observability(process_name="repro-serve")
-    eng = Engine(cfg, pcfg, ctx, params, max_len=args.max_len,
-                 spamm_cfg=spamm_cfg, plan_store=args.plan_store,
-                 freeze_plans=not args.no_freeze_plans,
-                 reshard_cfg=reshard_cfg,
-                 mesh_devices=args.spamm_mesh_devices,
-                 shard_max_width=args.spamm_shard_width or None,
-                 prefill_chunk=args.prefill_chunk,
-                 max_slots=args.max_slots,
-                 obs=obs)
+    eng = make_engine(cfg, params, max_len=args.max_len,
+                      spamm_cfg=spamm_cfg, plan_store=args.plan_store,
+                      freeze_plans=not args.no_freeze_plans,
+                      reshard_cfg=reshard_cfg,
+                      mesh_devices=args.spamm_mesh_devices,
+                      shard_max_width=args.spamm_shard_width or None,
+                      prefill_chunk=args.prefill_chunk,
+                      max_slots=args.max_slots,
+                      obs=obs)
 
     rng = np.random.default_rng(args.seed)
     if args.mixed_lengths:
@@ -207,9 +244,14 @@ def main():
             print(line)
         cres = sp.get("cost_residual")
         if cres:
+            # uncalibrated coefficients are a nominal table, not a fit to
+            # this device: their predictions are not device numbers
+            coeffs = eng.spamm_ctx.cost_coeffs
+            src = ("calibrated" if coeffs is not None and coeffs.calibrated
+                   else "nominal coefficients, not a device measurement")
             for phase, c in cres.items():
                 print(f"  cost[{phase}]: predicted={c['predicted_s']:.4f}s "
-                      f"measured={c['measured_s']:.4f}s "
+                      f"({src}) measured={c['measured_s']:.4f}s "
                       f"log2_residual={c['log2_ratio']:+.2f}")
         gb = sp.get("gemm_bytes_moved")
         dgb = sp.get("decode_gemm_bytes_moved")

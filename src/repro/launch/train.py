@@ -19,6 +19,7 @@ import os
 import jax
 
 from repro.configs import ParallelConfig, SpammConfig, TrainConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_ctx, make_host_mesh, make_production_mesh
 from repro.train.loop import train
 
@@ -61,6 +62,7 @@ def main():
 
     if os.environ.get("JAX_COORDINATOR"):
         jax.distributed.initialize()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.kernels.common import mesh_vma, varying
 
 NEG_INF = -1e30
 
@@ -105,7 +105,10 @@ def _causal_flash_packed(q5, k4, v4, scale, chunk):
     o0 = jnp.zeros((b, hk, g, qc, d), jnp.float32)
     m0 = jnp.full((b, hk, g, qc), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hk, g, qc), jnp.float32)
-    _, outs = jax.lax.scan(body, (o0, m0, l0), (t_iq, t_ik, row_start, row_end))
+    # inside shard_map the carry varies over the q/k/v axes: seed it so
+    vma = mesh_vma(q5, k4, v4)
+    carry0 = tuple(varying(c, vma) for c in (o0, m0, l0))
+    _, outs = jax.lax.scan(body, carry0, (t_iq, t_ik, row_start, row_end))
     o = outs[end_idx]  # (nq, B, hk, g, qc, D)
     o = o.transpose(1, 0, 4, 2, 3, 5).reshape(b, nq * qc, hk * g, d)
     return o
@@ -215,7 +218,9 @@ def flash_attention(
         m0 = jnp.full((b, hk, g, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hk, g, q_chunk), jnp.float32)
         (o, m, l), _ = jax.lax.scan(
-            kv_body, (o0, m0, l0), (jnp.arange(nk), k4.swapaxes(0, 1), v4.swapaxes(0, 1))
+            kv_body,
+            tuple(varying(c, mesh_vma(qc, k4, v4)) for c in (o0, m0, l0)),
+            (jnp.arange(nk), k4.swapaxes(0, 1), v4.swapaxes(0, 1))
         )
         return o / jnp.maximum(l, 1e-30)[..., None]
 
@@ -340,7 +345,7 @@ def decode_attention_seqsharded(
         return out.reshape(qc.shape[0], hq, d).astype(qc.dtype), kc, vc
 
     cspec = P(batch_axes, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import MoEConfig
 from repro.core.module import as_context, maybe_spamm_matmul, spamm_bmm_linear
 
@@ -214,7 +213,7 @@ def moe_block(
             y = y + ysh
         return y.reshape(bl, sl, d).astype(cdt), aux.reshape(1)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(w_specs, P(batch_axes, None, None)),

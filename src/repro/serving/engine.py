@@ -33,6 +33,7 @@ from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core import module as spmod
 from repro.core import schedule as _schedule
 from repro.core.plan import _bucket
+from repro.kernels import ops as kops
 from repro.models import model as M
 from repro.models.transformer import NetCtx, stack_kinds
 from repro.obs import (FRACTION_BUCKETS, Histogram, LATENCY_BUCKETS_S,
@@ -192,6 +193,11 @@ class Engine:
         self.max_len = max_len
         self.spamm_ctx = spmod.as_context(spamm_cfg)
         enabled = self.spamm_ctx is not None and self.spamm_ctx.enable
+        if enabled:
+            # fail at construction, not at the first compile, on a tile the
+            # backend cannot run (the pallas kernels need multiples of 128)
+            scfg = self.spamm_ctx.cfg
+            kops.get_backend(scfg.backend).check_tile(scfg.tile)
         # `prefill_chunk`: None = auto (chunked scheduler only for
         # mixed-length attention-stack batches), int C = always chunk at C
         # tokens, 0/False = never chunk (mixed lengths are rejected).
@@ -240,6 +246,11 @@ class Engine:
         self._steps = 0          # engine steps (prefill + decode), all waves
         self._shard = None       # live wave's sharding tables (sharded mode)
         self.trace_counts = {"prefill": 0, "decode": 0}  # (re)compile guard
+        # (B, vocab) f32 device array: the logits the last wave read its
+        # first tokens from, in request order (None after a chunked run,
+        # whose slots finish prefill in different steps) — what agreement
+        # checks compare against a reference engine
+        self.first_logits = None
         self._ndev = int(mesh_devices) if mesh_devices else 0
         self._sharded = self._ndev > 1
         self._shard_width = shard_max_width
@@ -259,10 +270,9 @@ class Engine:
                 raise ValueError(
                     f"mesh_devices={self._ndev} but only {len(devs)} "
                     f"devices visible")
-            from repro.launch.mesh import mesh_from_devices
+            from jax.sharding import Mesh
 
-            self._spamm_mesh = mesh_from_devices(
-                np.array(devs[:self._ndev]), ("rows",))
+            self._spamm_mesh = Mesh(np.array(devs[:self._ndev]), ("rows",))
         if reshard_cfg is not None and enabled and reshard_cfg.every > 0:
             if self._sharded and reshard_cfg.num_devices == 0:
                 reshard_cfg = dataclasses.replace(
@@ -281,13 +291,11 @@ class Engine:
             # coefficients resolve once, host-side, from the tune profile
             # (or the nominal table) at the config's resolved backend
             from repro.core import cost as _cost
-            from repro.kernels.ops import resolve_backend
 
-            scfg = self.spamm_ctx.cfg
             prof = _cost.CostProfile.load_or_default(
                 getattr(scfg, "tune_profile", None))
             self.spamm_ctx.enable_cost_taps(
-                prof.coeffs(resolve_backend(scfg.backend)))
+                prof.coeffs(kops.resolve_backend(scfg.backend)))
         if self.obs.enabled:
             reg = self.obs.registry
             self._m_ttft = reg.histogram(
@@ -367,8 +375,6 @@ class Engine:
             return
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         # the body computes one shard locally: a mesh-free ctx makes every
         # ctx.shard a no-op (no nested sharding constraints), and the frozen
         # plans / caches arrive with a leading shard dim that the body peels
@@ -395,11 +401,11 @@ class Engine:
             return logits, restack(cache)
 
         mesh = self._spamm_mesh
-        self._prefill = jax.jit(shard_map(
+        self._prefill = jax.jit(jax.shard_map(
             self._counted(pre_body, "prefill"), mesh=mesh,
             in_specs=(P(), P("rows"), P("rows")),
             out_specs=(P("rows"), P("rows"))))
-        self._decode = jax.jit(shard_map(
+        self._decode = jax.jit(jax.shard_map(
             self._counted(dec_body, "decode"), mesh=mesh,
             in_specs=(P(), P("rows"), P("rows"), P(), P("rows")),
             out_specs=(P("rows"), P("rows"))))
@@ -415,7 +421,7 @@ class Engine:
                                             unstack(frozen))
                 return restack(cache), logits
 
-            self._chunk = jax.jit(shard_map(
+            self._chunk = jax.jit(jax.shard_map(
                 self._counted(chunk_body, "prefill"), mesh=mesh,
                 in_specs=(P(), P("rows"), P("rows"), P("rows"), P("rows"),
                           P("rows")),
@@ -1010,6 +1016,9 @@ class Engine:
                     else:
                         self._note_gm(-(-(b * plen) // tile))
                 cache = self._pad_cache(cache)
+            self.first_logits = (
+                logits[jnp.asarray(self._shard["real_slots"])]
+                if self._sharded else logits)
             done = np.zeros(b, bool)
             cur = jnp.argmax(logits, -1).astype(jnp.int32)
             pos = plen
@@ -1175,6 +1184,7 @@ class Engine:
                             self._resharder.probes)
         frozen_ck = self._frozen_for(nslots * chunk)
         frozen_dec = self._frozen_for(nslots) if self._freeze else {}
+        self.first_logits = None
         cache = self._pad_cache(
             M.init_cache(self.cfg, self.pcfg, nslots, self.max_len),
             full=True)

@@ -34,13 +34,18 @@ def test_getnorm_sweep(shape, tile, dtype, use_mxu):
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("mkn", [(128, 128, 128), (128, 256, 192),
-                                 (256, 128, 384)])
+@pytest.mark.parametrize("mkn,tile", [
+    pytest.param((128, 128, 128), 64, id="mkn0"),
+    pytest.param((128, 256, 192), 64, id="mkn1"),
+    pytest.param((256, 128, 384), 64, id="mkn2"),
+    # the tile of the compiled chip path
+    pytest.param((128, 128, 128), 128, id="mkn0-tile128"),
+    pytest.param((256, 128, 384), 128, id="mkn2-tile128"),
+])
 @pytest.mark.parametrize("tau", [0.0, 0.5, 2.0, 100.0])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_spamm_mm_sweep(mkn, tau, dtype):
+def test_spamm_mm_sweep(mkn, tile, tau, dtype):
     m, k, n = mkn
-    tile = 64
     a = jnp.asarray(_decay(m, k, seed=2), dtype)
     b = jnp.asarray(_decay(k, n, seed=3), dtype)
     na = ref.tile_norms_ref(a, tile)
@@ -55,13 +60,15 @@ def test_spamm_mm_sweep(mkn, tau, dtype):
     )
 
 
-@pytest.mark.parametrize("block_n", [1, 2, 4])
-def test_spamm_block_n_superset_exactness(block_n):
+@pytest.mark.parametrize("block_n,tile", [
+    pytest.param(1, 64, id="1"), pytest.param(2, 64, id="2"),
+    pytest.param(4, 64, id="4"), pytest.param(2, 128, id="2-tile128"),
+])
+def test_spamm_block_n_superset_exactness(block_n, tile):
     """Grouped super-columns compute a SUPERSET of the τ mask: every result
     must equal the dense product on tiles the fine mask kept, and the info
     fraction must be ≥ the fine fraction (never drops valid work)."""
     m = k = n = 256
-    tile = 64
     a = jnp.asarray(_decay(m, k, 4))
     b = jnp.asarray(_decay(k, n, 5))
     tau = 0.4
@@ -106,10 +113,11 @@ def test_compact_invariants():
                 assert (kidx[i, j] == 0).all()
 
 
-def test_zero_valid_rows_write_zeros():
+@pytest.mark.parametrize("tile", [64, 128])
+def test_zero_valid_rows_write_zeros(tile):
     """nvalid == 0 for every output tile → kernel must still write zeros."""
-    a = jnp.ones((128, 128), jnp.float32) * 1e-6
-    b = jnp.ones((128, 128), jnp.float32) * 1e-6
-    c, info = ops.spamm_matmul(a, b, 1e3, tile=64, backend="interpret")
+    a = jnp.ones((256, 256), jnp.float32) * 1e-6
+    b = jnp.ones((256, 256), jnp.float32) * 1e-6
+    c, info = ops.spamm_matmul(a, b, 1e3, tile=tile, backend="interpret")
     assert float(info["valid_fraction"]) == 0.0
-    np.testing.assert_array_equal(np.asarray(c), np.zeros((128, 128), np.float32))
+    np.testing.assert_array_equal(np.asarray(c), np.zeros((256, 256), np.float32))

@@ -23,7 +23,7 @@ B, S = 2, 64
 
 
 def _ctx():
-    from repro.launch.mesh import make_mesh  # AxisType compat shim
+    from repro.launch.mesh import make_mesh  # auto-sharded axes
 
     return NetCtx(mesh=make_mesh((1, 1), ("data", "model")))
 
