@@ -20,11 +20,8 @@ Serving phase: musicgen-large at full width (48 layers, d_model 2048,
 d_ff 8192) through `Engine`, built as `repro.launch.serve` builds it:
 8 requests of 256 random tokens, 16 new tokens each. It runs dense, then
 SpAMM at tau = 0 and at tau > 0 (pallas backend, tile 128, frozen plans).
-The whole phase runs under HIGHEST matmul precision. At DEFAULT precision,
-XLA keeps bf16 copies of all 48 layers' f32 weights (4.85 GB) for its
-one-pass dots, and the decode step then does not fit 16 GB beside the
-9.7 GB of f32 weights and the KV cache (compile-time memory analysis).
-HIGHEST also makes the dense reference's dots f32, like the kernels'.
+This phase sets no matmul precision of its own: the engine traces its f32
+steps at HIGHEST, so the dense reference's dots are f32 like the kernels'.
 
 Four-chip phase: 512 requests of 128 tokens, 4 new tokens each, over a
 4-device "rows" mesh: one request group of tile = 128 requests per chip.
@@ -254,41 +251,38 @@ def serving_phase():
     rng = np.random.default_rng(SEED)
     prompts = [rng.integers(1, cfg.vocab, size=plen).astype(np.int32)
                for _ in range(nreq)]
-    with jax.default_matmul_precision("highest"):
-        t0 = time.perf_counter()
-        params = init_params(cfg, SEED)
-        jax.block_until_ready(params)
-        print(f"  params: {time.perf_counter() - t0:.1f}s", flush=True)
+    t0 = time.perf_counter()
+    params = init_params(cfg, SEED)
+    jax.block_until_ready(params)
+    print(f"  params: {time.perf_counter() - t0:.1f}s", flush=True)
 
-        results = {}
-        for name, scfg in (
-                ("dense", None),
-                ("tau=0", SpammConfig(enable=True, tau=0.0, tile=TILE,
-                                      backend="pallas")),
-                (f"tau={SERVE_TAU:g}", SpammConfig(
-                    enable=True, tau=SERVE_TAU, tile=TILE,
-                    backend="pallas"))):
-            if scfg is not None:
-                print(f"  {name}: backend {scfg.backend} -> "
-                      f"{kops.resolve_backend(scfg.backend)}, tile "
-                      f"{scfg.tile}, frozen plans", flush=True)
-                check(kops.resolve_backend(scfg.backend) == "pallas",
-                      "resolved backend is pallas")
-            eng = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg)
-            toks, logits, sp, first, warm = serve_twice(eng, prompts,
-                                                        max_new)
-            check(np.isfinite(logits).all(), f"{name}: logits are finite")
-            print(f"  {name}: first generate {first:.1f}s (plan freeze + "
-                  f"compile + run), warm generate {warm:.2f}s", flush=True)
-            if sp is not None:
-                print(f"  {name}: valid_fraction prefill "
-                      f"{sp['valid_fraction']} decode "
-                      f"{sp['decode_valid_fraction']}; gated GEMMs "
-                      f"{sp['gated_gemms']} prefill, "
-                      f"{sp['decode_gated_gemms']} decode", flush=True)
-            results[name] = (toks, logits, sp)
-            del eng
-            gc.collect()
+    results = {}
+    for name, scfg in (
+            ("dense", None),
+            ("tau=0", SpammConfig(enable=True, tau=0.0, tile=TILE,
+                                  backend="pallas")),
+            (f"tau={SERVE_TAU:g}", SpammConfig(
+                enable=True, tau=SERVE_TAU, tile=TILE, backend="pallas"))):
+        if scfg is not None:
+            print(f"  {name}: backend {scfg.backend} -> "
+                  f"{kops.resolve_backend(scfg.backend)}, tile "
+                  f"{scfg.tile}, frozen plans", flush=True)
+            check(kops.resolve_backend(scfg.backend) == "pallas",
+                  "resolved backend is pallas")
+        eng = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg)
+        toks, logits, sp, first, warm = serve_twice(eng, prompts, max_new)
+        check(np.isfinite(logits).all(), f"{name}: logits are finite")
+        print(f"  {name}: first generate {first:.1f}s (plan freeze + "
+              f"compile + run), warm generate {warm:.2f}s", flush=True)
+        if sp is not None:
+            print(f"  {name}: valid_fraction prefill "
+                  f"{sp['valid_fraction']} decode "
+                  f"{sp['decode_valid_fraction']}; gated GEMMs "
+                  f"{sp['gated_gemms']} prefill, "
+                  f"{sp['decode_gated_gemms']} decode", flush=True)
+        results[name] = (toks, logits, sp)
+        del eng
+        gc.collect()
 
     d_toks, d_logits, _ = results["dense"]
     z_toks, z_logits, z_sp = results["tau=0"]
@@ -368,32 +362,30 @@ def four_chip_phase():
                for _ in range(nreq)]
     scfg = SpammConfig(enable=True, tau=0.0, tile=TILE, backend="pallas",
                        block_n=4)
-    with jax.default_matmul_precision("highest"):
-        params = init_params(cfg, SEED)
-        eng4 = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg,
-                           mesh_devices=4, shard_max_width=1)
-        mesh_ids = sorted(d.id for d in eng4._spamm_mesh.devices.flat)
-        check(mesh_ids == sorted(d.id for d in devs),
-              f"engine mesh spans devices {mesh_ids}")
-        toks4, logits4, sp4, first, warm = serve_twice(eng4, prompts,
-                                                       max_new)
-        lay = eng4.shard_layout
-        print(f"  sharded: first generate {first:.1f}s (plan freeze + "
-              f"compile + run), warm {warm:.2f}s; shard offsets "
-              f"{[int(o) for o in lay['offsets']]}, "
-              f"{lay['slot_width']} slots each", flush=True)
-        check(lay["real"] == [TILE] * 4, "one request group per chip")
-        del eng4
-        gc.collect()
+    params = init_params(cfg, SEED)
+    eng4 = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg,
+                       mesh_devices=4, shard_max_width=1)
+    mesh_ids = sorted(d.id for d in eng4._spamm_mesh.devices.flat)
+    check(mesh_ids == sorted(d.id for d in devs),
+          f"engine mesh spans devices {mesh_ids}")
+    toks4, logits4, _, first, warm = serve_twice(eng4, prompts, max_new)
+    lay = eng4.shard_layout
+    print(f"  sharded: first generate {first:.1f}s (plan freeze + "
+          f"compile + run), warm {warm:.2f}s; shard offsets "
+          f"{[int(o) for o in lay['offsets']]}, "
+          f"{lay['slot_width']} slots each", flush=True)
+    check(lay["real"] == [TILE] * 4, "one request group per chip")
+    del eng4
+    gc.collect()
 
-        eng1 = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg)
-        toks1, logits1 = [], []
-        for w in range(4):
-            t, l, _, _, _ = serve_twice(eng1, prompts[w * TILE:(w + 1) * TILE],
-                                        max_new)
-            toks1 += t
-            logits1.append(l)
-        logits1 = np.concatenate(logits1)
+    eng1 = make_engine(cfg, params, max_len=max_len, spamm_cfg=scfg)
+    toks1, logits1 = [], []
+    for w in range(4):
+        t, l, _, _, _ = serve_twice(eng1, prompts[w * TILE:(w + 1) * TILE],
+                                    max_new)
+        toks1 += t
+        logits1.append(l)
+    logits1 = np.concatenate(logits1)
     e = rel_err(logits4, logits1)
     check(e <= TOL_SHARDED,
           f"sharded first-token logits vs one device: rel err {e:.3e} <= "

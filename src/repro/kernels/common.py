@@ -21,8 +21,10 @@ import jax
 
 LANE = 128
 SMEM_BYTES = 1 << 20
-# the compiler keeps ~1 KiB of SMEM for itself: 4 step tables of 65536
-# entries (exactly 1 MiB) are refused, 63488 entries compile
+# SMEM the compiler keeps for itself. A conservative bound, not the exact
+# boundary: 4 step tables of 65536 entries (exactly 1 MiB) were refused,
+# short by 1.1 KiB, and 63488 entries (32 KiB under 1 MiB) compiled; no
+# size between the two was tried, so tables in that gap are refused here
 SMEM_RESERVE = 32 << 10
 
 

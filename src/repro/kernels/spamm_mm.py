@@ -84,8 +84,11 @@ def _mxu_dot(a, b):
     """One tile product into the f32 accumulator. f32 operands multiply at
     f32 (HIGHEST): Mosaic's default for them is one bf16 pass, measured at
     3.2e-3 of max |C| on a v5e at n = 4096 against 4.2e-7 for f32. bf16
-    operands take their native single pass (products exact in f32)."""
-    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    operands take their native single pass (products exact in f32). The
+    precision is explicit either way, so a caller's
+    `jax.default_matmul_precision` cannot reach the kernel body."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
     return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
 
 
@@ -310,6 +313,7 @@ def _spamm_mm_worklist_int8_kernel(
         prod = jax.lax.dot_general(
             a_ref[...], b_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.int32,
         ).astype(jnp.float32) * sa_ref[i, kk]
         t = acc_ref.shape[0]

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import ParallelConfig, SpammConfig, get_config
 from repro.kernels import ops as kops
+from repro.kernels.common import LANE
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_ctx, make_host_mesh
 from repro.models import model as M
@@ -33,7 +34,7 @@ def default_tile(backend: str) -> int:
     """SpAMM tile when none is given: 128 where the kernels are compiled
     (their (tile, tile) blocks must span the 128-wide lane dimension), 32
     on the interpret/jnp backends."""
-    return 128 if kops.get_backend(backend).compiled else 32
+    return LANE if kops.get_backend(backend).compiled else 32
 
 
 def init_params(cfg, seed: int):

@@ -33,7 +33,6 @@ from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core import module as spmod
 from repro.core import schedule as _schedule
 from repro.core.plan import _bucket
-from repro.kernels import ops as kops
 from repro.models import model as M
 from repro.models.transformer import NetCtx, stack_kinds
 from repro.obs import (FRACTION_BUCKETS, Histogram, LATENCY_BUCKETS_S,
@@ -193,11 +192,13 @@ class Engine:
         self.max_len = max_len
         self.spamm_ctx = spmod.as_context(spamm_cfg)
         enabled = self.spamm_ctx is not None and self.spamm_ctx.enable
-        if enabled:
-            # fail at construction, not at the first compile, on a tile the
-            # backend cannot run (the pallas kernels need multiples of 128)
-            scfg = self.spamm_ctx.cfg
-            kops.get_backend(scfg.backend).check_tile(scfg.tile)
+        # f32 compute means f32 dots: the steps trace at HIGHEST. At DEFAULT
+        # a TPU runs an f32 dot as one bf16 pass, and XLA hoists bf16 copies
+        # of every layer's weights out of the layer scan (4.85 GB at
+        # musicgen-large, which then no longer fits one v5e beside its f32
+        # weights and KV cache)
+        self._matmul_precision = (
+            "highest" if pcfg.compute_dtype == "float32" else None)
         # `prefill_chunk`: None = auto (chunked scheduler only for
         # mixed-length attention-stack batches), int C = always chunk at C
         # tokens, 0/False = never chunk (mixed lengths are rejected).
@@ -246,11 +247,9 @@ class Engine:
         self._steps = 0          # engine steps (prefill + decode), all waves
         self._shard = None       # live wave's sharding tables (sharded mode)
         self.trace_counts = {"prefill": 0, "decode": 0}  # (re)compile guard
-        # (B, vocab) f32 device array: the logits the last wave read its
-        # first tokens from, in request order (None after a chunked run,
-        # whose slots finish prefill in different steps) — what agreement
-        # checks compare against a reference engine
-        self.first_logits = None
+        # the last wave's prefill logits and, when sharded, the slots of its
+        # real requests (`first_logits` gathers them on read)
+        self._first = None
         self._ndev = int(mesh_devices) if mesh_devices else 0
         self._sharded = self._ndev > 1
         self._shard_width = shard_max_width
@@ -291,11 +290,13 @@ class Engine:
             # coefficients resolve once, host-side, from the tune profile
             # (or the nominal table) at the config's resolved backend
             from repro.core import cost as _cost
+            from repro.kernels.ops import resolve_backend
 
+            scfg = self.spamm_ctx.cfg
             prof = _cost.CostProfile.load_or_default(
                 getattr(scfg, "tune_profile", None))
             self.spamm_ctx.enable_cost_taps(
-                prof.coeffs(kops.resolve_backend(scfg.backend)))
+                prof.coeffs(resolve_backend(scfg.backend)))
         if self.obs.enabled:
             reg = self.obs.registry
             self._m_ttft = reg.histogram(
@@ -348,10 +349,16 @@ class Engine:
 
     def _counted(self, fn, key: str):
         """Wrap a step body so Python re-execution (= a fresh jit trace)
-        bumps `trace_counts[key]` — the recompile-free re-shard guard."""
+        bumps `trace_counts[key]` — the recompile-free re-shard guard — and
+        traces at the engine's matmul precision."""
+        prec = self._matmul_precision
+
         def wrapped(*args):
             self.trace_counts[key] += 1
-            return fn(*args)
+            if prec is None:
+                return fn(*args)
+            with jax.default_matmul_precision(prec):
+                return fn(*args)
 
         return wrapped
 
@@ -426,6 +433,17 @@ class Engine:
                 in_specs=(P(), P("rows"), P("rows"), P("rows"), P("rows"),
                           P("rows")),
                 out_specs=(P("rows"), P("rows"))))
+
+    @property
+    def first_logits(self):
+        """(B, vocab) f32: the logits the last wave read its first tokens
+        from, in request order — what agreement checks compare against a
+        reference engine. None before a wave and after a chunked run, whose
+        slots finish prefill in different steps."""
+        if self._first is None:
+            return None
+        logits, slots = self._first
+        return logits if slots is None else logits[jnp.asarray(slots)]
 
     # -- drift-triggered re-sharding (control plane) -------------------------
     @property
@@ -1016,9 +1034,8 @@ class Engine:
                     else:
                         self._note_gm(-(-(b * plen) // tile))
                 cache = self._pad_cache(cache)
-            self.first_logits = (
-                logits[jnp.asarray(self._shard["real_slots"])]
-                if self._sharded else logits)
+            self._first = (logits, self._shard["real_slots"]
+                           if self._sharded else None)
             done = np.zeros(b, bool)
             cur = jnp.argmax(logits, -1).astype(jnp.int32)
             pos = plen
@@ -1184,7 +1201,7 @@ class Engine:
                             self._resharder.probes)
         frozen_ck = self._frozen_for(nslots * chunk)
         frozen_dec = self._frozen_for(nslots) if self._freeze else {}
-        self.first_logits = None
+        self._first = None
         cache = self._pad_cache(
             M.init_cache(self.cfg, self.pcfg, nslots, self.max_len),
             full=True)

@@ -1,9 +1,11 @@
 """Checkpoint roundtrip/atomicity/GC + serving-engine behavior."""
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import checkpoint as ck
 from repro.configs import ParallelConfig, get_config
@@ -63,6 +65,43 @@ def test_engine_greedy_matches_manual_decode():
     # (batch composition must not change a slot's tokens)
     outs_single = eng.generate([Request(prompt=prompts[0], max_new_tokens=6)])
     np.testing.assert_array_equal(outs[0], outs_single[0])
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_engine_step_dot_precision(compute_dtype):
+    """f32 compute traces every dot of the compiled steps at HIGHEST (a TPU
+    runs a DEFAULT f32 dot as one bf16 pass); other compute dtypes leave
+    the precision to the caller."""
+    cfg = get_config("musicgen-large").reduced()
+    pcfg = dataclasses.replace(PCFG, compute_dtype=compute_dtype)
+    params = M.init_params(cfg, pcfg, jax.random.key(0))
+    eng = Engine(cfg, pcfg, make_ctx(make_host_mesh()), params, max_len=32)
+    toks = jnp.zeros((2, 16), jnp.int32)
+    cache = M.init_cache(cfg, pcfg, 2, 32)
+    texts = [eng._prefill.lower(params, {"tokens": toks}, {}).as_text(),
+             eng._decode.lower(params, toks[:, :1], cache, jnp.int32(16),
+                               {}).as_text()]
+    for text in texts:
+        dots = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+        assert dots
+        highest = ["precision = [HIGHEST, HIGHEST]" in ln for ln in dots]
+        assert all(highest) if compute_dtype == "float32" else not any(highest)
+
+
+def test_engine_first_logits_pick_first_tokens():
+    """`first_logits` holds the prefill logits the wave's first tokens were
+    read from, in request order."""
+    cfg = get_config("musicgen-large").reduced()
+    params = M.init_params(cfg, PCFG, jax.random.key(0))
+    eng = Engine(cfg, PCFG, make_ctx(make_host_mesh()), params, max_len=48)
+    assert eng.first_logits is None
+    rng = np.random.default_rng(1)
+    outs = eng.generate([
+        Request(prompt=rng.integers(1, cfg.vocab, size=16).astype(np.int32),
+                max_new_tokens=3) for _ in range(3)])
+    logits = np.asarray(eng.first_logits)
+    assert logits.shape == (3, cfg.vocab)
+    np.testing.assert_array_equal(logits.argmax(-1), [o[0] for o in outs])
 
 
 def test_engine_spamm_telemetry_on_request_out():
