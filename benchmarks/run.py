@@ -29,7 +29,6 @@ MODULES = {
     "frozen_prefill": "frozen_prefill",
     "mixed_precision": "mixed_precision",
     "autotune": "autotune",
-    "obs_overhead": "obs_overhead",
     "scenario_sweep": "scenario_sweep",
     "roofline": "roofline",
 }

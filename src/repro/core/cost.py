@@ -391,11 +391,10 @@ def predict_plan_static(plan, coeffs: CostCoeffs):
     a pure function of static plan metadata (normmap shapes, step-table
     length, levels, coefficients): base + step overheads, norm/pyramid
     bytes, gate ops. The two traced quantities — GEMM bytes and valid
-    tiles — already ride the telemetry callback as operands (bytes
+    tiles — already leave the compiled step as its gating stats (bytes
     directly; valid tiles as valid_fraction × the static total_tiles), so
-    the HOST side of the callback can finish the prediction with
-    `finish_plan_time_s` and the armed graph stays IDENTICAL to the
-    unarmed one (benchmarks/obs_overhead.py holds that line).
+    the host finishes the prediction with `finish_plan_time_s` and the
+    armed graph stays IDENTICAL to the unarmed one.
 
     Returns `(const_s, total_tiles, tile, block_n)` host floats, or None
     for plans without static step tables (no frozen work-list — the
@@ -421,8 +420,10 @@ def predict_plan_static(plan, coeffs: CostCoeffs):
 def finish_plan_time_s(static, valid_fraction: float, gemm_bytes: float,
                        coeffs: CostCoeffs) -> float:
     """Host-side completion of `predict_plan_static`: add the executed-work
-    terms from the callback's concrete operands. By construction equal to
-    `predict_plan_time_s` on the same plan (tests pin the identity)."""
+    terms from the step's returned fraction and bytes (scalars, or numpy
+    arrays of many executions with `static` stacked to match). By
+    construction equal to `predict_plan_time_s` on the same plan (tests pin
+    the identity)."""
     const_s, total_tiles, tile, block_n = static
     flops = gemm_flops(valid_fraction * total_tiles, tile, block_n)
     return (const_s + gemm_bytes / coeffs.bytes_per_s

@@ -20,7 +20,9 @@ WeightPlanCache) instead of raw (tau, tile, backend, block_n) tuples — see
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -31,19 +33,80 @@ from repro.core import plan as _plan
 from repro.core.plan import WeightPlanCache, pad_to_tile
 
 
-class Tap(NamedTuple):
-    """One labeled telemetry event from a gated GEMM.
+class GemmTap(NamedTuple):
+    """One gated GEMM's telemetry as traced values, held in the context's
+    trace buffer while a step is traced.
 
-    `phase` and `site` are captured at TRACE time (static strings baked into
-    the callback partial); `layer` rides as a traced int32 operand so it
-    survives `lax.scan`-over-layers — the scan body feeds the per-iteration
-    layer index in, and the host sees the concrete value per execution.
-    `layer` is -1 when no layer label was in scope (eager callers, MoE
-    shard_map interiors)."""
-    phase: str
-    site: Optional[str]
-    layer: int
-    value: float
+    `frac` and `nbytes` are traced f32 arrays of one shape: one element per
+    execution (a scalar for a plain GEMM, one entry per expert under the
+    MoE block's vmap). `site` ("wq", "w1", ...) and `cost` (the static part
+    of the cost prediction, `cost.predict_plan_static`, or None) are host
+    values captured at trace time."""
+    site: str
+    frac: Any
+    nbytes: Any
+    cost: Optional[tuple] = None
+
+
+@jax.tree_util.register_pytree_node_class
+class StepStats:
+    """The gating stats one compiled step returns beside its outputs.
+
+    `values` is f32 `(..., N, 2)`: per tap row, the executed GEMM's
+    [valid_fraction, bytes_moved]; leading dims are further executions of
+    the same rows (the shards of a pod-sharded step). `layout` is static
+    (the pytree's aux data, so it leaves `jit` with the output and every
+    compiled shape carries its own): per row, `(layer, site, cost)`."""
+
+    __slots__ = ("values", "layout")
+
+    def __init__(self, values, layout: tuple):
+        self.values = values
+        self.layout = layout
+
+    def tree_flatten(self):
+        return (self.values,), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, leaves):
+        return cls(leaves[0], layout)
+
+
+def _pack(taps) -> tuple:
+    """`(values (n, 2) f32, rows)` from drained `GemmTap`s: every element
+    of a tap is one row, and `rows[r]` is its `(site, cost)`."""
+    if not taps:
+        return jnp.zeros((0, 2), jnp.float32), ()
+    cols = [jnp.stack([jnp.ravel(t.frac).astype(jnp.float32),
+                       jnp.ravel(t.nbytes).astype(jnp.float32)], -1)
+            for t in taps]
+    rows = tuple(r for t in taps
+                 for r in [(t.site, t.cost)] * math.prod(jnp.shape(t.frac)))
+    return jnp.concatenate(cols), rows
+
+
+def collect_taps(ctx: Optional["SpammContext"], fn, *args, **kwargs):
+    """`(fn(*args, **kwargs), values (n, 2) f32, rows)`: fn run with its
+    gated GEMMs tapped into a buffer of its own, packed — one row per
+    execution, `rows[r]` its static `(site, cost)`. The values are traced
+    in fn's trace, so a scan, vmap or shard_map body returns them as an
+    output. Without a context: `(fn(...), None, ())`."""
+    if ctx is None:
+        return fn(*args, **kwargs), None, ()
+    with ctx.collecting() as taps:
+        out = fn(*args, **kwargs)
+    return (out,) + _pack(taps)
+
+
+def retap(ctx: Optional["SpammContext"], values, rows) -> None:
+    """Record packed taps (`values (..., n, 2)` and `rows` from
+    `collect_taps` in an inner trace, which returned them as outputs) into
+    `ctx`'s open trace buffer."""
+    if ctx is None:
+        return
+    for j, (site, cost) in enumerate(rows):
+        ctx.tap(values[..., j, 0], values[..., j, 1], site=site,
+                cost_static=cost)
 
 
 class SpammContext:
@@ -53,40 +116,27 @@ class SpammContext:
     Hashed by identity (usable as a jit static / custom_vjp nondiff arg);
     create one per model/engine, not per call.
 
-    Gating telemetry: between `begin_stats()` and `end_stats()` every gated
-    GEMM taps its plan's valid_fraction through `jax.experimental.io_callback`
-    — an effectful host callback, so it survives jit AND lax.scan-over-layers
-    (the values materialize at *execution* time, per compiled call, not at
-    trace time). Events are LABELED `Tap(phase, site, layer, value)` records:
-    phase and site are static strings captured at trace time, the layer index
-    is a traced operand fed by the layer stack (`set_layer`). The serving
-    engine brackets each request wave with begin/end and attaches the drained
-    stats — per-wave aggregates plus a per-layer/per-site breakdown — to the
-    request metadata.
+    Gating telemetry rides the traced computation as values: while a trace
+    buffer is open (`collecting()`), every gated GEMM appends a `GemmTap`
+    of its traced valid fraction and bytes moved. The layer stacks open one
+    buffer per layer inside the scan body, return the packed taps as the
+    scan's ys, and the compiled step hands them back as a `StepStats`
+    output; no host callback runs inside a step. The same dataflow works
+    under `grad` and remat (the train step's metrics) and out of vmap and
+    shard_map bodies (the MoE block returns its expert taps as outputs).
 
-    Cost telemetry (optional): with `enable_cost_taps(coeffs)`, the frozen
-    path additionally records a per-executed-GEMM time prediction on the
-    SAME callback as the fraction/bytes, feeding the predicted-vs-measured
-    residual channel. The prediction's static terms are evaluated at trace
-    time (`cost.predict_plan_static`, host floats baked into the callback
-    partial) and finished host-side from the concrete fraction/bytes
-    operands (`cost.finish_plan_time_s`) — armed and unarmed contexts trace
-    IDENTICAL graphs, so arming costs nothing on the device timeline.
+    Cost telemetry (optional): with `enable_cost_taps(coeffs)`, frozen-path
+    GEMMs also capture the static part of their time prediction at trace
+    time (`cost.predict_plan_static`, host floats kept in the tap's static
+    layout); the host finishes it from the returned fraction and bytes
+    (`cost.finish_plan_time_s`), so arming adds no op to the compiled step.
     """
 
-    __slots__ = ("cfg", "cache", "_taps", "_byte_taps", "_cost_taps",
-                 "_collect", "_phase", "_layer", "_trace_buffer",
-                 "cost_coeffs")
+    __slots__ = ("cfg", "cache", "_trace_buffer", "cost_coeffs")
 
     def __init__(self, cfg: Any, cache: Optional[WeightPlanCache] = None):
         self.cfg = cfg
         self.cache = cache if cache is not None else WeightPlanCache()
-        self._taps: list = []
-        self._byte_taps: list = []
-        self._cost_taps: list = []
-        self._collect = False
-        self._phase = "prefill"
-        self._layer = None
         self._trace_buffer: Optional[list] = None
         self.cost_coeffs = None
 
@@ -98,194 +148,39 @@ class SpammContext:
         return bool(getattr(self.cfg, "enable", False))
 
     # -- gating telemetry ---------------------------------------------------
-    def begin_stats(self):
-        """Start collecting per-GEMM valid fractions (must be called before
-        the first trace of the step that should report them)."""
-        self._taps = []
-        self._byte_taps = []
-        self._cost_taps = []
-        self._collect = True
-
-    def set_phase(self, phase: str):
-        """Tag subsequent taps with a phase label ("prefill" | "decode" |
-        "train"). The label is captured at TRACE time, so set it before the
-        first call of each jitted step function — every execution of that
-        compiled step then reports under its phase, which is what lets the
-        engine tell prefill from decode gating fractions apart."""
-        self._phase = phase
-
-    def set_layer(self, layer):
-        """Tag subsequent taps with a layer index. Unlike the phase, the
-        layer may be a TRACED int32 (the `lax.scan` body feeds each
-        iteration's index in via the scan xs) — it rides the callback as an
-        operand, so every execution reports the concrete per-layer value.
-        Reset to None after the stack to avoid leaking a scan tracer into
-        unrelated taps."""
-        self._layer = layer
-
-    def swap_layer(self, layer):
-        """Set the layer label and return the previous one — bracketing for
-        regions whose taps must NOT close over an outer-trace layer tracer
-        (MoE blocks tap inside shard_map; an outer scan's index tracer must
-        not be captured there)."""
-        prev, self._layer = self._layer, layer
-        return prev
-
-    def _layer_arg(self):
-        layer = self._layer if self._layer is not None else -1
-        return jnp.asarray(layer, jnp.int32)
-
     def enable_cost_taps(self, coeffs):
         """Arm the cost-prediction channel: `coeffs` is a `cost.CostCoeffs`
-        (host floats, resolved once per engine from the tune profile). Must
-        be set BEFORE the first trace of the instrumented step — the
-        prediction arithmetic embeds into the compiled graph."""
+        (host floats, resolved once per engine from the tune profile). Set
+        it BEFORE the first trace of the instrumented step: the static
+        terms are captured while tracing."""
         self.cost_coeffs = coeffs
 
-    def _record(self, phase, site, f, layer):
-        # host side of the tap; re-check _collect at RUN time — once a
-        # callback is embedded in a compiled function it fires on every
-        # execution, including ones outside a begin/end window
-        if self._collect:
-            self._taps.append(Tap(phase, site, int(layer), float(f)))
+    @property
+    def is_collecting(self) -> bool:
+        """True while a trace buffer is open."""
+        return self._trace_buffer is not None
 
-    def _record_bytes(self, phase, site, nb, layer):
-        if self._collect:
-            self._byte_taps.append(Tap(phase, site, int(layer), float(nb)))
+    @contextlib.contextmanager
+    def collecting(self):
+        """Open a fresh trace buffer for the enclosed trace and yield it;
+        the enclosing buffer (if any) is restored on exit, so a buffer
+        never holds tracers of another trace level (a scan body, a vmap or
+        a shard_map body each collect their own and return them)."""
+        prev, self._trace_buffer = self._trace_buffer, []
+        try:
+            yield self._trace_buffer
+        finally:
+            self._trace_buffer = prev
 
-    def _record_gemm(self, phase, site, f, nb, layer):
-        if self._collect:
-            layer = int(layer)
-            self._taps.append(Tap(phase, site, layer, float(f)))
-            self._byte_taps.append(Tap(phase, site, layer, float(nb)))
-
-    def _record_gemm_cost(self, phase, site, cost_static, f, nb, layer):
-        if self._collect:
-            layer, f, nb = int(layer), float(f), float(nb)
-            self._taps.append(Tap(phase, site, layer, f))
-            self._byte_taps.append(Tap(phase, site, layer, nb))
-            if self.cost_coeffs is not None:
-                # finish the prediction host-side from the concrete operands
-                # (the static terms were baked into this partial at trace
-                # time) — the armed graph carries zero extra ops
-                pred = _cost.finish_plan_time_s(cost_static, f, nb,
-                                                self.cost_coeffs)
-                self._cost_taps.append(Tap(phase, site, layer, pred))
-
-    # -- trace-time buffering (the grad-safe path) --------------------------
-    # io_callback effects are DROPPED inside a custom_vjp fwd rule under
-    # value_and_grad (and inside grad-of-scan), so the train step cannot
-    # report through callbacks. Instead the stack collects taps as traced
-    # VALUES: while a trace buffer is open, tap() appends the traced
-    # fraction to it and the caller threads the sum through the scan carry
-    # into the step metrics — pure dataflow, survives grad and remat.
-    def begin_trace_buffer(self):
-        self._trace_buffer = []
-
-    def drain_trace_buffer(self) -> list:
-        buf, self._trace_buffer = (self._trace_buffer or []), None
-        return buf
-
-    def suspend_trace_buffer(self):
-        """Temporarily disable buffering (MoE blocks trace their gated GEMMs
-        inside shard_map — their tracers must not leak into an outer-trace
-        carry; those taps fall back to the callback path)."""
-        buf, self._trace_buffer = self._trace_buffer, None
-        return buf
-
-    def resume_trace_buffer(self, buf):
-        self._trace_buffer = buf
-
-    def tap(self, valid_fraction, site: Optional[str] = None):
-        """Record one gated GEMM's valid fraction, tagged with the current
-        phase/site/layer labels (no-op unless collecting or a trace buffer
-        is open).
-
-        The callback embeds into whatever computation is being traced, so a
-        jitted prefill reports fractions on every execution."""
-        if self._trace_buffer is not None:
-            self._trace_buffer.append(jnp.asarray(valid_fraction, jnp.float32))
+    def tap(self, valid_fraction, nbytes, site: str = "",
+            cost_static: Optional[tuple] = None):
+        """Record one gated GEMM's traced valid fraction and bytes moved
+        (same shape: one element per execution) into the open trace
+        buffer; a no-op when none is open."""
+        if self._trace_buffer is None:
             return
-        if not self._collect:
-            return
-        from jax.experimental import io_callback  # deferred: cheap import
-
-        io_callback(
-            functools.partial(self._record, self._phase, site), None,
-            jnp.asarray(valid_fraction, jnp.float32), self._layer_arg(),
-            ordered=False,
-        )
-
-    def tap_bytes(self, nbytes, site: Optional[str] = None):
-        """Record one gated GEMM's bytes-moved estimate (plan.bytes_moved()),
-        tagged with the current phase. Separate channel from tap(): the
-        fraction taps feed the gating-quality stats, the byte taps feed the
-        mixed-precision bandwidth telemetry — draining one must not consume
-        the other. Callback-only (no trace-buffer tier: bytes are a serving
-        metric, the grad path never reports them)."""
-        if not self._collect:
-            return
-        from jax.experimental import io_callback  # deferred: cheap import
-
-        io_callback(
-            functools.partial(self._record_bytes, self._phase, site), None,
-            jnp.asarray(nbytes, jnp.float32), self._layer_arg(),
-            ordered=False,
-        )
-
-    def tap_gemm(self, valid_fraction, nbytes, cost_static=None,
-                 site: Optional[str] = None):
-        """Record one gated GEMM's fraction + bytes (+ optionally a cost
-        prediction) through a SINGLE io_callback — the frozen serving
-        path's tap. One host roundtrip instead of two keeps the labeled
-        telemetry CHEAPER than the anonymous two-callback scheme it
-        replaced; the host side fans the operands back out into the
-        separate channels.
-
-        `cost_static` is `cost.predict_plan_static(...)` output (host
-        floats). It is baked into the callback partial, NOT traced: the
-        host recorder finishes the prediction from the fraction/bytes
-        operands already on the wire, so the cost channel adds no operands
-        and no graph ops — armed and unarmed steps compile identically."""
-        if not self._collect:
-            return
-        from jax.experimental import io_callback  # deferred: cheap import
-
-        frac = jnp.asarray(valid_fraction, jnp.float32)
-        nb = jnp.asarray(nbytes, jnp.float32)
-        if cost_static is not None:
-            io_callback(
-                functools.partial(self._record_gemm_cost, self._phase, site,
-                                  cost_static),
-                None, frac, nb, self._layer_arg(), ordered=False,
-            )
-        else:
-            io_callback(
-                functools.partial(self._record_gemm, self._phase, site),
-                None, frac, nb, self._layer_arg(), ordered=False,
-            )
-
-    def end_stats(self):
-        """Stop collecting and drain: list of `Tap(phase, site, layer,
-        valid_fraction)` events recorded since `begin_stats` (empty when no
-        gated GEMM executed)."""
-        taps, self._taps = self._taps, []
-        self._collect = False
-        return taps
-
-    def drain_byte_stats(self):
-        """Drain the bytes-moved taps: `Tap` events (value = bytes) recorded
-        since `begin_stats`. Call before `end_stats` flips _collect off if
-        callbacks may still be landing; the engine drains both together."""
-        taps, self._byte_taps = self._byte_taps, []
-        return taps
-
-    def drain_cost_stats(self):
-        """Drain the cost-prediction taps: `Tap` events (value = predicted
-        seconds) — empty unless `enable_cost_taps` armed the channel before
-        the instrumented steps were traced."""
-        taps, self._cost_taps = self._cost_taps, []
-        return taps
+        self._trace_buffer.append(GemmTap(site or "", valid_fraction, nbytes,
+                                          cost_static))
 
 
 def as_context(spamm_cfg) -> Optional[SpammContext]:
@@ -321,14 +216,14 @@ def _spamm_linear_stats(
     levels: int = 0,
     compute_dtype: str = "float32",
 ):
-    """(y, valid_fraction) — the gated GEMM plus its gating stat as a REAL
-    OUTPUT. The fraction must flow out of the custom_vjp rather than be
-    tapped inside it: the fwd rule is traced in its own subsidiary trace
-    under autodiff, so a tap fired there either gets dropped (callbacks) or
-    leaks an inner tracer (trace buffers). Callers tap the returned value."""
+    """(y, (valid_fraction, bytes_moved)) — the gated GEMM plus its gating
+    stats as REAL OUTPUTS. The stats must flow out of the custom_vjp rather
+    than be tapped inside it: the fwd rule is traced in its own subsidiary
+    trace under autodiff, so a tracer appended to a buffer there would leak.
+    Callers tap the returned values."""
     y, p = _fwd_impl(x, w, tau, tile, backend, block_n, ctx, levels,
                      compute_dtype)
-    return y, p.valid_fraction
+    return y, (p.valid_fraction, p.bytes_moved())
 
 
 def spamm_linear(
@@ -384,7 +279,8 @@ def _spamm_linear_fwd(x, w, tau, tile, backend, bwd, block_n, ctx, levels,
                      compute_dtype)
     # residuals carry the forward normmaps so bwd="spamm" replans without
     # re-running get-norm on x or w
-    return (y, p.valid_fraction), (x, w, tau, p.norm_a, p.norm_b)
+    return ((y, (p.valid_fraction, p.bytes_moved())),
+            (x, w, tau, p.norm_a, p.norm_b))
 
 
 def _spamm_linear_bwd(tile, backend, bwd, block_n, ctx, levels, compute_dtype,
@@ -395,7 +291,7 @@ def _spamm_linear_bwd(tile, backend, bwd, block_n, ctx, levels, compute_dtype,
     # low-precision grads would bias training for no serving win
     del compute_dtype
     x, w, tau, norm_x, norm_w = res
-    g, _ = g  # cotangent of the valid-fraction stat output is discarded
+    g, _ = g  # cotangents of the gating-stat outputs are discarded
     lead = x.shape[:-1]
     k, n = w.shape
     m = 1
@@ -443,7 +339,8 @@ def spamm_bmm_linear(x: jax.Array, w: jax.Array, spamm_ctx) -> jax.Array:
         tile=cfg.tile, block_n=cfg.block_n, backend=cfg.backend,
         cache=spamm_ctx.cache, levels=getattr(cfg, "levels", 0),
     )
-    spamm_ctx.tap(info.valid_fraction, site="moe_bmm")
+    # spamm_bmm reports no byte count: NaN marks the tap's bytes as unknown
+    spamm_ctx.tap(info.valid_fraction, jnp.float32(jnp.nan), site="moe_bmm")
     return c.astype(x.dtype)
 
 
@@ -459,19 +356,19 @@ def spamm_linear_frozen(x: jax.Array, w: jax.Array, fp,
     `spamm_linear` with the same config (the frozen tables are a superset
     re-gated by the exact flat τ-test). Inference path: no custom_vjp.
 
-    `site` labels the tap ("wq", "w1", ...); when the context has cost taps
-    armed the predicted call time rides the same callback — the static part
-    of the prediction is computed HERE at trace time (host floats baked
-    into the callback), the executed-work part on the host from the tap's
-    own operands, so arming costs zero extra graph ops."""
+    `site` labels the tap ("wq", "w1", ...). When the context has cost taps
+    armed, the static part of the predicted call time is computed HERE at
+    trace time and kept in the tap's static layout; the host finishes it
+    from the returned fraction and bytes, so arming adds no graph op."""
     tile = fp.tile
     xp, (lead, m, k) = _flatten_pad(x, tile)
     n = w.shape[-1]
     p = _plan.plan(xp, frozen_weight=fp)
-    if ctx is not None:
+    if ctx is not None and ctx.is_collecting:
         cost = (_cost.predict_plan_static(p, ctx.cost_coeffs)
                 if ctx.cost_coeffs is not None else None)
-        ctx.tap_gemm(p.valid_fraction, p.bytes_moved(), cost, site=site)
+        ctx.tap(p.valid_fraction, p.bytes_moved(), site=site,
+                cost_static=cost)
     wp = pad_to_tile(w, tile, tile * fp.block_n)
     c = _plan.execute(p, xp, wp)
     return c[:m, :n].reshape(*lead, n).astype(x.dtype)
@@ -479,7 +376,7 @@ def spamm_linear_frozen(x: jax.Array, w: jax.Array, fp,
 
 def maybe_spamm_matmul(x: jax.Array, w: jax.Array, spamm_cfg: Any,
                        frozen=None, require_frozen: bool = False,
-                       site: Optional[str] = None) -> jax.Array:
+                       site: str = "") -> jax.Array:
     """The hook the model zoo calls for every eligible GEMM: dense when
     spamm_cfg is disabled, plan-routed spamm_linear when enabled.
     `spamm_cfg` may be a SpammConfig or a SpammContext (cfg + plan cache).
@@ -496,7 +393,7 @@ def maybe_spamm_matmul(x: jax.Array, w: jax.Array, spamm_cfg: Any,
     if frozen is not None:
         return spamm_linear_frozen(x, w, frozen, ctx, site=site)
     cfg = ctx.cfg
-    y, frac = _spamm_linear_stats(
+    y, (frac, nbytes) = _spamm_linear_stats(
         x,
         w,
         jnp.asarray(cfg.tau, jnp.float32),
@@ -508,5 +405,5 @@ def maybe_spamm_matmul(x: jax.Array, w: jax.Array, spamm_cfg: Any,
         getattr(cfg, "levels", 0),
         getattr(cfg, "dtype", "float32"),
     )
-    ctx.tap(frac, site=site)
+    ctx.tap(frac, nbytes, site=site)
     return y

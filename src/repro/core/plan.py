@@ -69,6 +69,7 @@ from repro.kernels import ops as kops
 from repro.kernels import quantize as kquant
 from repro.kernels import ref as kref
 from repro.kernels import spamm_mm as kmm
+from repro.obs.tracer import annotate
 
 
 # ---------------------------------------------------------------------------
@@ -883,162 +884,172 @@ def plan(
     serving stream whose Σnvalid hovers around a bucket boundary stops
     re-jitting; 16 is the historical default.
     """
-    if frozen_weight is not None:
-        if tau is not None or valid_ratio is not None:
-            raise ValueError("frozen_weight carries its own tau; pass "
-                             "neither tau nor valid_ratio")
-        return _plan_frozen(a, frozen_weight, norm_a=norm_a,
-                            use_mxu_norm=use_mxu_norm)
-    if (tau is None) == (valid_ratio is None):
-        raise ValueError("give exactly one of tau / valid_ratio")
-    bk = kops.get_backend(backend)
-    bk.check_tile(tile)
+    # the span opens inside the function, so that the profiler's event of
+    # `plan` itself covers it and an idle gap within reads `spamm_plan`
+    with annotate("spamm_plan"):
+        if frozen_weight is not None:
+            if tau is not None or valid_ratio is not None:
+                raise ValueError("frozen_weight carries its own tau; pass "
+                                 "neither tau nor valid_ratio")
+            return _plan_frozen(a, frozen_weight, norm_a=norm_a,
+                                use_mxu_norm=use_mxu_norm)
+        if (tau is None) == (valid_ratio is None):
+            raise ValueError("give exactly one of tau / valid_ratio")
+        bk = kops.get_backend(backend)
+        bk.check_tile(tile)
 
-    compute_dtype = kquant.canonical_dtype(compute_dtype)
-    a_scale = b_scale = None
-    if compute_dtype != "float32":
-        # gate on what the kernel will multiply. int8: the fused
-        # absmax/scale + get-norm kernel turns each operand matrix into
-        # (quantized-view norms, per-tile scales) in ONE read — the plan
-        # keeps the scales so execute() skips its absmax pass; the matrix
-        # slot is cleared because the norms below ARE its only use (the
-        # hierarchical path pools pyramids from the fine normmap).
-        # bf16: the quantize-dequantized f32 view replaces the operand
-        # before any norm computation, as before.
-        if compute_dtype == "int8":
-            if a is not None and norm_a is None:
-                norm_a, a_scale = kops.int8_norms_and_scales(
-                    a, tile, backend=bk.name, use_mxu=use_mxu_norm)
-                a = None
-            if b is not None and norm_b is None:
-                norm_b, b_scale = kops.int8_norms_and_scales(
-                    b, tile, backend=bk.name, use_mxu=use_mxu_norm)
-                b = None
-        else:
-            if a is not None:
-                a = kquant.quantized_view(a, compute_dtype, tile)
-            if b is not None:
-                b = kquant.quantized_view(b, compute_dtype, tile)
-        if tau is not None:
-            tau = kquant.widen_tau(tau, compute_dtype, tile)
+        compute_dtype = kquant.canonical_dtype(compute_dtype)
+        a_scale = b_scale = None
+        if compute_dtype != "float32":
+            # gate on what the kernel will multiply. int8: the fused
+            # absmax/scale + get-norm kernel turns each operand matrix into
+            # (quantized-view norms, per-tile scales) in ONE read — the plan
+            # keeps the scales so execute() skips its absmax pass; the matrix
+            # slot is cleared because the norms below ARE its only use (the
+            # hierarchical path pools pyramids from the fine normmap).
+            # bf16: the quantize-dequantized f32 view replaces the operand
+            # before any norm computation, as before.
+            if compute_dtype == "int8":
+                if a is not None and norm_a is None:
+                    norm_a, a_scale = kops.int8_norms_and_scales(
+                        a, tile, backend=bk.name, use_mxu=use_mxu_norm)
+                    a = None
+                if b is not None and norm_b is None:
+                    norm_b, b_scale = kops.int8_norms_and_scales(
+                        b, tile, backend=bk.name, use_mxu=use_mxu_norm)
+                    b = None
+            else:
+                if a is not None:
+                    a = kquant.quantized_view(a, compute_dtype, tile)
+                if b is not None:
+                    b = kquant.quantized_view(b, compute_dtype, tile)
+            if tau is not None:
+                tau = kquant.widen_tau(tau, compute_dtype, tile)
 
-    hier = (levels > 0 or isinstance(norm_a, NormPyramid)
-            or isinstance(norm_b, NormPyramid))
-    if hier and _any_traced((a, b, norm_a, norm_b, tau)):
-        # Under jit the sparse descent can't run and the dense traced
-        # refinement produces the SAME mask as flat gating for strictly more
-        # work — downgrade to flat so `levels` is free on compiled paths
-        # (jitted prefill) while eager callers keep the hierarchical win.
-        # hier_gate_mask stays available for traced callers who want the
-        # level-by-level refinement explicitly.
-        if isinstance(norm_a, NormPyramid):
-            norm_a = norm_a.base
-        if isinstance(norm_b, NormPyramid):
-            norm_b = norm_b.base
-        hier = False
-    triples = None          # surviving (i, j, k); j granularity per flag
-    triples_grouped = False  # True ⇒ j is already a super-column id
-    mask = None
-    if hier:
-        want = max(
-            levels,
-            norm_a.num_levels if isinstance(norm_a, NormPyramid) else 0,
-            norm_b.num_levels if isinstance(norm_b, NormPyramid) else 0,
-        )
-        pyr_a = _side_pyramid(norm_a, a, want, tile, bk, use_mxu_norm, "a")
-        pyr_b = _side_pyramid(norm_b, b, want, tile, bk, use_mxu_norm, "b")
-        norm_a, norm_b = pyr_a.base, pyr_b.base
-        if valid_ratio is not None:
-            from repro.core.tau_search import search_tau_pyramid  # circular-safe
-
-            tau, _ = search_tau_pyramid(pyr_a, pyr_b, valid_ratio)
-        tau = jnp.asarray(tau, jnp.float32)
-        if _any_traced((pyr_a, pyr_b, tau)):
-            # even with concrete OPERANDS, an enclosing jit turns the
-            # nested-jit kernels (pyramid_norms, the τ-search) into tracer
-            # producers — the host descent can't run there, so gate with the
-            # traced coarse-to-fine refinement (bit-identical mask)
-            mask = hier_gate_mask(pyr_a, pyr_b, tau, block_n)
-        else:
-            # fully concrete: the descent hands over its surviving triples —
-            # the compacted set — and no dense bitmap is ever materialized
-            lv = min(pyr_a.num_levels, pyr_b.num_levels)
-            triples = _hier_descend_host(
-                [np.asarray(x) for x in pyr_a.levels[: lv + 1]],
-                [np.asarray(x) for x in pyr_b.levels[: lv + 1]],
-                float(np.asarray(tau)),
+        hier = (levels > 0 or isinstance(norm_a, NormPyramid)
+                or isinstance(norm_b, NormPyramid))
+        if hier and _any_traced((a, b, norm_a, norm_b, tau)):
+            # Under jit the sparse descent can't run and the dense traced
+            # refinement produces the SAME mask as flat gating for strictly more
+            # work — downgrade to flat so `levels` is free on compiled paths
+            # (jitted prefill) while eager callers keep the hierarchical win.
+            # hier_gate_mask stays available for traced callers who want the
+            # level-by-level refinement explicitly.
+            if isinstance(norm_a, NormPyramid):
+                norm_a = norm_a.base
+            if isinstance(norm_b, NormPyramid):
+                norm_b = norm_b.base
+            hier = False
+        triples = None          # surviving (i, j, k); j granularity per flag
+        triples_grouped = False  # True ⇒ j is already a super-column id
+        mask = None
+        if hier:
+            want = max(
+                levels,
+                norm_a.num_levels if isinstance(norm_a, NormPyramid) else 0,
+                norm_b.num_levels if isinstance(norm_b, NormPyramid) else 0,
             )
-    else:
-        if norm_a is None:
-            if a is None:
-                raise ValueError("need `a` or `norm_a`")
-            norm_a = bk.norms(a, tile, use_mxu=use_mxu_norm)
-        if norm_b is None:
-            if b is None:
-                raise ValueError("need `b` or `norm_b`")
-            norm_b = bk.norms(b, tile, use_mxu=use_mxu_norm)
+            # spamm_norms: from the get-norm dispatch until the norm maps are
+            # on the host; τ's search or upload is queued before the pull,
+            # so it overlaps the norm kernels
+            with annotate("spamm_norms"):
+                pyr_a = _side_pyramid(norm_a, a, want, tile, bk, use_mxu_norm,
+                                      "a")
+                pyr_b = _side_pyramid(norm_b, b, want, tile, bk, use_mxu_norm,
+                                      "b")
+                norm_a, norm_b = pyr_a.base, pyr_b.base
+                if valid_ratio is not None:
+                    # circular-safe
+                    from repro.core.tau_search import search_tau_pyramid
 
-        if valid_ratio is not None:
-            from repro.core.tau_search import search_tau  # circular-safe
-
-            tau, _ = search_tau(norm_a, norm_b, valid_ratio)
-        tau = jnp.asarray(tau, jnp.float32)
-        if _any_traced((norm_a, norm_b, tau)):
-            mask = gate_mask(norm_a, norm_b, tau, block_n)
+                    tau, _ = search_tau_pyramid(pyr_a, pyr_b, valid_ratio)
+                tau = jnp.asarray(tau, jnp.float32)
+                lv = min(pyr_a.num_levels, pyr_b.num_levels)
+                host = (None if _any_traced((pyr_a, pyr_b, tau)) else
+                        ([np.asarray(x) for x in pyr_a.levels[: lv + 1]],
+                         [np.asarray(x) for x in pyr_b.levels[: lv + 1]]))
+            if host is None:
+                # even with concrete OPERANDS, an enclosing jit turns the
+                # nested-jit kernels (pyramid_norms, the τ-search) into tracer
+                # producers — the host descent can't run there, so gate with the
+                # traced coarse-to-fine refinement (bit-identical mask)
+                mask = hier_gate_mask(pyr_a, pyr_b, tau, block_n)
+            else:
+                # fully concrete: the descent hands over its surviving triples —
+                # the compacted set — and no dense bitmap is ever materialized
+                triples = _hier_descend_host(*host, float(np.asarray(tau)))
         else:
-            # concrete flat gate on host: same fp32 products as gate_mask,
-            # then a nonzero scan — the triples feed compact_from_triples so
-            # kidx/nvalid need no sort over the (gm, gn, gk) grid
-            triples, mask = _flat_triples_host(
-                np.asarray(norm_a), np.asarray(norm_b),
-                float(np.asarray(tau)), block_n,
-                keep_mask=bk.matmul_worklist is None)
-            triples_grouped = True
+            with annotate("spamm_norms"):  # as in the hierarchical branch
+                if norm_a is None:
+                    if a is None:
+                        raise ValueError("need `a` or `norm_a`")
+                    norm_a = bk.norms(a, tile, use_mxu=use_mxu_norm)
+                if norm_b is None:
+                    if b is None:
+                        raise ValueError("need `b` or `norm_b`")
+                    norm_b = bk.norms(b, tile, use_mxu=use_mxu_norm)
+                if valid_ratio is not None:
+                    from repro.core.tau_search import search_tau  # circular-safe
 
-    gm, gk = norm_a.shape
-    gn = norm_b.shape[-1]
-    gnb = gn // block_n
-    if triples is not None:  # concrete plan: compacted-first
-        # per-step tables only for backends that will execute the ragged
-        # kernel; bitmap/dense-kidx backends never read them
-        steps = bk.matmul_worklist is not None
-        if triples_grouped:
-            # the chunked nonzero scan emits triples in row-major (sorted
-            # fused-key) order with grouping already applied — skip the sort
-            work_np, nvalid_np = compact_from_triples(
-                *triples, gm=gm, gn=gnb, gk=gk, block_n=1, steps=steps,
-                assume_sorted=True, bucket_min=bucket_min)
-        else:
-            work_np, nvalid_np = compact_from_triples(
-                *triples, gm=gm, gn=gn, gk=gk, block_n=block_n, steps=steps,
-                bucket_min=bucket_min)
-        valid_tiles = jnp.int32(int(work_np.klist.size))
-        nvalid = jnp.asarray(nvalid_np)
-        # dense kidx only for dense-grid kernels with no ragged entry point
-        kidx = (jnp.asarray(kidx_from_work(work_np, gm, gnb, gk))
-                if bk.needs_compaction and bk.matmul_worklist is None
-                else None)
-        if mask is None and not steps:
-            # no ragged executor means the executable form IS the bitmap (or
-            # the kidx above) — scatter it now from the pair view instead of
-            # lazily from step tables that were never built (numpy twin of
-            # SpammPlan.mask's traceable step-view scatter; keep in sync)
-            m_host = np.zeros((gm, gnb, gk), bool)
-            counts = np.diff(work_np.offsets)
-            m_host[np.repeat(work_np.rows, counts),
-                   np.repeat(work_np.cols, counts), work_np.klist] = True
-            mask = m_host
-        work = SpammWork(*(jnp.asarray(x) if x is not None else None
-                           for x in work_np))
-        mask = jnp.asarray(mask) if mask is not None else None
-    else:  # traced plan: dense bitmap, legacy compaction
-        valid_tiles = jnp.sum(mask, dtype=jnp.int32)
-        kidx, nvalid = _maybe_compact(mask, bk.name)
-        work = None
-    return SpammPlan(tau, norm_a, norm_b, mask, kidx, nvalid, valid_tiles,
-                     work, a_scale, b_scale, tile=tile, block_n=block_n,
-                     backend=bk.name, levels=(want if hier else 0),
-                     compute_dtype=compute_dtype)
+                    tau, _ = search_tau(norm_a, norm_b, valid_ratio)
+                tau = jnp.asarray(tau, jnp.float32)
+                host = (None if _any_traced((norm_a, norm_b, tau)) else
+                        (np.asarray(norm_a), np.asarray(norm_b)))
+            if host is None:
+                mask = gate_mask(norm_a, norm_b, tau, block_n)
+            else:
+                # concrete flat gate on host: same fp32 products as gate_mask,
+                # then a nonzero scan — the triples feed compact_from_triples so
+                # kidx/nvalid need no sort over the (gm, gn, gk) grid
+                triples, mask = _flat_triples_host(
+                    *host, float(np.asarray(tau)), block_n,
+                    keep_mask=bk.matmul_worklist is None)
+                triples_grouped = True
+
+        gm, gk = norm_a.shape
+        gn = norm_b.shape[-1]
+        gnb = gn // block_n
+        if triples is not None:  # concrete plan: compacted-first
+            # per-step tables only for backends that will execute the ragged
+            # kernel; bitmap/dense-kidx backends never read them
+            steps = bk.matmul_worklist is not None
+            if triples_grouped:
+                # the chunked nonzero scan emits triples in row-major (sorted
+                # fused-key) order with grouping already applied — skip the sort
+                work_np, nvalid_np = compact_from_triples(
+                    *triples, gm=gm, gn=gnb, gk=gk, block_n=1, steps=steps,
+                    assume_sorted=True, bucket_min=bucket_min)
+            else:
+                work_np, nvalid_np = compact_from_triples(
+                    *triples, gm=gm, gn=gn, gk=gk, block_n=block_n,
+                    steps=steps, bucket_min=bucket_min)
+            valid_tiles = jnp.int32(int(work_np.klist.size))
+            nvalid = jnp.asarray(nvalid_np)
+            # dense kidx only for dense-grid kernels with no ragged entry point
+            kidx = (jnp.asarray(kidx_from_work(work_np, gm, gnb, gk))
+                    if bk.needs_compaction and bk.matmul_worklist is None
+                    else None)
+            if mask is None and not steps:
+                # no ragged executor means the executable form IS the bitmap (or
+                # the kidx above) — scatter it now from the pair view instead of
+                # lazily from step tables that were never built (numpy twin of
+                # SpammPlan.mask's traceable step-view scatter; keep in sync)
+                m_host = np.zeros((gm, gnb, gk), bool)
+                counts = np.diff(work_np.offsets)
+                m_host[np.repeat(work_np.rows, counts),
+                       np.repeat(work_np.cols, counts), work_np.klist] = True
+                mask = m_host
+            work = SpammWork(*(jnp.asarray(x) if x is not None else None
+                               for x in work_np))
+            mask = jnp.asarray(mask) if mask is not None else None
+        else:  # traced plan: dense bitmap, legacy compaction
+            valid_tiles = jnp.sum(mask, dtype=jnp.int32)
+            kidx, nvalid = _maybe_compact(mask, bk.name)
+            work = None
+        return SpammPlan(tau, norm_a, norm_b, mask, kidx, nvalid, valid_tiles,
+                         work, a_scale, b_scale, tile=tile, block_n=block_n,
+                         backend=bk.name, levels=(want if hier else 0),
+                         compute_dtype=compute_dtype)
 
 
 def execute(p: SpammPlan, a: jax.Array, b: jax.Array, *, out_dtype=None):

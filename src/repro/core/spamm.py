@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core import plan as _plan
 from repro.core.plan import SpammInfo, pad_to_tile  # re-exported API
+from repro.obs.tracer import annotate
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +110,8 @@ def spamm(
         use_mxu_norm=use_mxu_norm,
         compute_dtype=compute_dtype,
     )
-    c = _plan.execute(p, ap, bp, out_dtype=out_dtype)[:m, :n]
+    with annotate("spamm_execute"):
+        c = _plan.execute(p, ap, bp, out_dtype=out_dtype)[:m, :n]
     frac = p.valid_fraction
     return c, SpammInfo(
         tau=p.tau,

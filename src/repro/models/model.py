@@ -302,13 +302,16 @@ def make_train_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
 
 def make_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
                       *, spamm_cfg=None):
-    """fn(params, batch, frozen=None) → (cache, last_logits). Logits only
-    for the final position (materializing (B, S, V) at 32k is not a
+    """fn(params, batch, frozen=None) → (cache, last_logits, stats). Logits
+    only for the final position (materializing (B, S, V) at 32k is not a
     production thing).
 
     `frozen` is the optional pytree of precomputed weight-side SpAMM plans
     (see `repro.plans`): a jit ARGUMENT, so the compiled graph consumes the
-    step tables as data instead of re-deriving weight normmaps per trace."""
+    step tables as data instead of re-deriving weight normmaps per trace.
+
+    `stats` is the step's gating stats (a `module.StepStats`), None
+    without an enabled SpammContext."""
     spamm_cfg = spmod.as_context(spamm_cfg)  # one context for every call
 
     def step(params, batch, frozen=None):
@@ -321,12 +324,12 @@ def make_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
         cache_len = (min(cfg.sliding_window, s) if cfg.sliding_window else s)
-        x, cache = tr.stack_prefill(params, x, cfg, pcfg, ctx, positions,
-                                    cache_len, spamm_cfg=spamm_cfg,
-                                    frozen=frozen)
+        x, cache, stats = tr.stack_prefill(params, x, cfg, pcfg, ctx,
+                                           positions, cache_len,
+                                           spamm_cfg=spamm_cfg, frozen=frozen)
         h_last = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
         logits = (h_last @ params["unembed"]["kernel"].astype(cdt)).astype(jnp.float32)
-        return cache, logits
+        return cache, logits, stats
 
     return step
 
@@ -334,17 +337,18 @@ def make_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
 def make_prefill_chunk_step(cfg: ModelConfig, pcfg: ParallelConfig,
                             ctx: NetCtx, *, spamm_cfg=None):
     """fn(params, batch, cache, positions, last_idx, frozen=None) →
-    (cache, logits). One tile-aligned chunk of position-offset prefill: the
-    chunk's tokens run the layer stack at ONE static (B, C) shape, writing
-    K/V into the LINEAR decode cache at `positions` (B, C) — absolute
-    per-row token indices; entries ≥ cache length are idle/pad sentinels
-    whose writes drop (`.at[].set(mode="drop")`). `logits` (B, V) is read
+    (cache, logits, stats). One tile-aligned chunk of position-offset
+    prefill: the chunk's tokens run the layer stack at ONE static (B, C)
+    shape, writing K/V into the LINEAR decode cache at `positions` (B, C) —
+    absolute per-row token indices; entries ≥ cache length are idle/pad
+    sentinels whose writes drop (`.at[].set(mode="drop")`). `logits` (B, V) is read
     at `last_idx` (B,), the in-chunk index of each row's final prompt token
     (clamped, so rows whose prompt does not end in this chunk return values
     the caller ignores). Attention stacks only — see `stack_prefill_chunk`.
 
     `frozen` is the chunk-shape FrozenPlan pytree (rows = B·C), a jit
-    argument exactly like the one-shot prefill's."""
+    argument exactly like the one-shot prefill's; `stats` as the one-shot
+    prefill's."""
     spamm_cfg = spmod.as_context(spamm_cfg)  # one context for every call
 
     def step(params, batch, cache, positions, last_idx, frozen=None):
@@ -355,14 +359,14 @@ def make_prefill_chunk_step(cfg: ModelConfig, pcfg: ParallelConfig,
             x = params["embed"]["embedding"].astype(cdt)[batch["tokens"]]
         x = ctx.shard(x, ctx.batch_axes, None, None)
         b, c, _ = x.shape
-        x, cache = tr.stack_prefill_chunk(
+        x, cache, stats = tr.stack_prefill_chunk(
             params, x, cache, positions, cfg, pcfg, ctx,
             spamm_cfg=spamm_cfg, frozen=frozen)
         idx = jnp.clip(last_idx, 0, c - 1)
         h_last = rms_norm(x[jnp.arange(b), idx], params["final_norm"],
                           cfg.norm_eps)
         logits = (h_last @ params["unembed"]["kernel"].astype(cdt)).astype(jnp.float32)
-        return cache, logits
+        return cache, logits, stats
 
     return step
 
@@ -370,8 +374,9 @@ def make_prefill_chunk_step(cfg: ModelConfig, pcfg: ParallelConfig,
 def make_decode_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
                      *, spamm_cfg=None):
     """fn(params, tokens_or_embeds (B,1[,d]), cache, pos, frozen=None) →
-    (logits, cache). Decode GEMMs gate only through `frozen` plans (sites
-    without one stay dense — see `stack_decode`)."""
+    (logits, cache, stats). Decode GEMMs gate only through `frozen` plans
+    (sites without one stay dense — see `stack_decode`); `stats` as the
+    prefill's."""
     spamm_cfg = spmod.as_context(spamm_cfg)  # one context for every call
 
     def step(params, inp, cache, pos, frozen=None):
@@ -381,11 +386,12 @@ def make_decode_step(cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
         else:
             x = params["embed"]["embedding"].astype(cdt)[inp]
         x = ctx.shard(x, ctx.batch_axes, None, None)
-        x, cache = tr.stack_decode(params, x, cache, pos, cfg, pcfg, ctx,
-                                   spamm_cfg=spamm_cfg, frozen=frozen)
+        x, cache, stats = tr.stack_decode(params, x, cache, pos, cfg, pcfg,
+                                          ctx, spamm_cfg=spamm_cfg,
+                                          frozen=frozen)
         h = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
         logits = (h @ params["unembed"]["kernel"].astype(cdt)).astype(jnp.float32)
-        return logits, cache
+        return logits, cache, stats
 
     return step
 

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
-from repro.core.module import as_context, maybe_spamm_matmul, spamm_bmm_linear
+from repro.core.module import (as_context, collect_taps, maybe_spamm_matmul,
+                               retap, spamm_bmm_linear)
 
 
 def moe_params(key, cfg: MoEConfig, d_model: int, dtype, model_axis_size: int = 1):
@@ -78,16 +79,17 @@ def _dispatch(x, router_w, cfg: MoEConfig, capacity: int):
     return se, st, sg, pos, keep, aux
 
 
-def _grouped_ffn(buf, w1, w3, w2, act, spamm_cfg):
+def _grouped_ffn(buf, w1, w3, w2, act, ctx):
     """buf: (E_loc, C, d) → (E_loc, C, d) via per-expert SwiGLU.
 
     With SpAMM enabled and `moe_bmm` set, the three grouped GEMMs run as
     batched (E, C, d) @ (E, d, ff) products through `core.plan.spamm_bmm`:
     one get-norm pass per operand, per-expert gating, weight-side plans
     shared with the context's cache. Otherwise (default / training) each
-    expert goes through the vmapped `spamm_linear` custom-vjp path."""
+    expert goes through the vmapped `spamm_linear` custom-vjp path; the
+    experts' taps come out of the vmap as outputs (one row per expert) and
+    are recorded into `ctx`'s open buffer."""
     cdt = buf.dtype
-    ctx = as_context(spamm_cfg)
 
     if ctx is not None and ctx.enable and getattr(ctx.cfg, "moe_bmm", False):
         g = spamm_bmm_linear(buf, w1.astype(cdt), ctx)
@@ -95,21 +97,31 @@ def _grouped_ffn(buf, w1, w3, w2, act, spamm_cfg):
         h = (jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)) * u
         return spamm_bmm_linear(h, w2.astype(cdt), ctx)
 
-    def one(b, w1e, w3e, w2e):
-        g = maybe_spamm_matmul(b, w1e.astype(cdt), ctx)
-        u = maybe_spamm_matmul(b, w3e.astype(cdt), ctx)
+    tctx = ctx if ctx is not None and ctx.is_collecting else None
+    rows = []
+
+    def ffn(b, w1e, w3e, w2e):
+        g = maybe_spamm_matmul(b, w1e.astype(cdt), ctx, site="expert_w1")
+        u = maybe_spamm_matmul(b, w3e.astype(cdt), ctx, site="expert_w3")
         h = (jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)) * u
-        return maybe_spamm_matmul(h, w2e.astype(cdt), ctx)
+        return maybe_spamm_matmul(h, w2e.astype(cdt), ctx, site="expert_w2")
 
-    return jax.vmap(one)(buf, w1, w3, w2)
+    def one(*operands):
+        y, values, rows[:] = collect_taps(tctx, ffn, *operands)
+        return y, values
+
+    y, values = jax.vmap(one)(buf, w1, w3, w2)
+    retap(tctx, values, rows)
+    return y
 
 
-def _shared_ffn(params, x, act, spamm_cfg):
+def _shared_ffn(params, x, act, ctx):
     cdt = x.dtype
-    g = maybe_spamm_matmul(x, params["w1"].astype(cdt), spamm_cfg)
-    u = maybe_spamm_matmul(x, params["w3"].astype(cdt), spamm_cfg)
+    g = maybe_spamm_matmul(x, params["w1"].astype(cdt), ctx, site="shared_w1")
+    u = maybe_spamm_matmul(x, params["w3"].astype(cdt), ctx, site="shared_w3")
     h = (jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)) * u
-    out = maybe_spamm_matmul(h, params["w2"].astype(cdt), spamm_cfg)
+    out = maybe_spamm_matmul(h, params["w2"].astype(cdt), ctx,
+                             site="shared_w2")
     gate = jax.nn.sigmoid((x.astype(jnp.float32) @ params["gate"]))
     return out * gate.astype(cdt)
 
@@ -160,7 +172,17 @@ def moe_block(
             "gate": P(None, None),
         }
 
+    ctx = as_context(spamm_cfg)
+    tctx = ctx if ctx is not None and ctx.is_collecting else None
+    rows = []
+
     def local(p, xc):
+        # the expert GEMMs trace inside shard_map: they tap into a buffer
+        # of this trace and leave it as an output, one row per shard
+        (y, aux), values, rows[:] = collect_taps(tctx, ffn_local, p, xc)
+        return y, aux, None if values is None else values[None]
+
+    def ffn_local(p, xc):
         bl, sl, _ = xc.shape
         xt = xc.reshape(bl * sl, d)
         se, st, sg, pos, keep, aux = _dispatch(xt, p["router"], cfg, capacity)
@@ -174,7 +196,7 @@ def moe_block(
         if cfg.impl == "tp":
             buf = jnp.zeros((e_pad, capacity, d), cdt)
             buf = buf.at[se, pos].set(xt[st], mode="drop")  # OOB pos dropped
-            out = _grouped_ffn(buf, p["w1"], p["w3"], p["w2"], act, spamm_cfg)
+            out = _grouped_ffn(buf, p["w1"], p["w3"], p["w2"], act, ctx)
             y = jnp.zeros((bl * sl, d), jnp.float32)
             y = y.at[st].add(
                 out[se, jnp.minimum(pos, capacity - 1)].astype(jnp.float32)
@@ -192,7 +214,7 @@ def moe_block(
             buf = buf.at[jnp.where(owned, le, e_loc), pos].set(
                 xt[st], mode="drop"   # foreign experts + OOB pos dropped
             )
-            out = _grouped_ffn(buf, p["w1"], p["w3"], p["w2"], act, spamm_cfg)
+            out = _grouped_ffn(buf, p["w1"], p["w3"], p["w2"], act, ctx)
             lec = jnp.clip(le, 0, e_loc - 1)
             y = jnp.zeros((bl * sl, d), jnp.float32)
             y = y.at[st].add(
@@ -203,7 +225,7 @@ def moe_block(
             y = jax.lax.psum(y, model_axis)  # disjoint expert contributions
 
         if "shared" in p:
-            ysh = _shared_ffn(p["shared"], xt, act, spamm_cfg)
+            ysh = _shared_ffn(p["shared"], xt, act, ctx)
             if cfg.impl == "tp":
                 # shared ffn is ff-sharded too → its partial went into... no:
                 # computed fully here with sharded w → psum needed
@@ -217,16 +239,9 @@ def moe_block(
         local,
         mesh=mesh,
         in_specs=(w_specs, P(batch_axes, None, None)),
-        out_specs=(P(batch_axes, None, None), P(batch_axes)),
+        out_specs=(P(batch_axes, None, None), P(batch_axes),
+                   P(tuple(mesh.axis_names))),
     )
-    # expert GEMMs tap inside the shard_map trace: suspend any open trace
-    # buffer so their tracers can't leak into an outer-trace carry (these
-    # taps report through the callback path instead)
-    sctx = as_context(spamm_cfg)
-    saved = sctx.suspend_trace_buffer() if sctx is not None else None
-    try:
-        y, aux = fn(params, x)
-    finally:
-        if sctx is not None:
-            sctx.resume_trace_buffer(saved)
+    y, aux, values = fn(params, x)
+    retap(tctx, values, rows)
     return y, jnp.mean(aux)

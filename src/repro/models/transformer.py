@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
-from repro.core.module import SpammContext, maybe_spamm_matmul
+from repro.core.module import (SpammContext, StepStats, collect_taps,
+                               maybe_spamm_matmul)
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -267,11 +268,29 @@ def layer_params(key, cfg: ModelConfig, dtype, kind: str, model_axis_size: int):
 
 
 def _tap_ctx(spamm_cfg) -> Optional[SpammContext]:
-    """The SpammContext behind what the stack threads, for label bracketing
-    (set_layer/swap_layer). None when taps can't be labeled — a raw
-    SpammConfig means maybe_spamm_matmul builds throwaway contexts, so
-    there is no shared object to label through."""
-    return spamm_cfg if isinstance(spamm_cfg, SpammContext) else None
+    """The SpammContext whose taps a stack collects: None when there is
+    nothing to collect (no gating, or a raw SpammConfig — maybe_spamm_matmul
+    then builds throwaway contexts, so there is no shared buffer)."""
+    if isinstance(spamm_cfg, SpammContext) and spamm_cfg.enable:
+        return spamm_cfg
+    return None
+
+
+def _stack_stats(parts) -> Optional[StepStats]:
+    """The step's `StepStats` from its layers' taps. Each part is `(values
+    (num, n, 2), rows, per, base)`: the ys of a scan whose body ran `per`
+    layers (or one unrolled layer, num 1), `rows` the body's static
+    `(sub-layer, site, cost)` labels captured when it was traced, `base`
+    the first layer's index."""
+    if not parts or parts[0][0] is None:
+        return None
+    vals, layout = [], []
+    for values, rows, per, base in parts:
+        vals.append(values.reshape(-1, 2))
+        layout += [(base + it * per + i, site, cost)
+                   for it in range(values.shape[0])
+                   for i, site, cost in rows]
+    return StepStats(jnp.concatenate(vals), tuple(layout))
 
 
 def _ffn(p, h, cfg: ModelConfig, ctx: NetCtx, spamm_cfg, frozen=None,
@@ -281,21 +300,12 @@ def _ffn(p, h, cfg: ModelConfig, ctx: NetCtx, spamm_cfg, frozen=None,
     MoE blocks keep the traced gating path (their expert buffers live
     inside shard_map; frozen plans cover the dense attention/MLP GEMMs)."""
     if cfg.moe is not None:
-        # MoE taps fire inside shard_map: an enclosing scan's layer-index
-        # tracer must not be closed over there, so the label is cleared for
-        # the block (those taps report layer=-1, like every shard_map tap).
-        tctx = _tap_ctx(spamm_cfg)
-        prev = tctx.swap_layer(None) if tctx is not None else None
-        try:
-            return moe_mod.moe_block(
-                p["moe"], h, cfg.moe, cfg.act,
-                mesh=ctx.mesh, batch_axes=ctx.batch_axes,
-                model_axis=ctx.model_axis,
-                spamm_cfg=None if require_frozen else spamm_cfg,
-            )
-        finally:
-            if tctx is not None:
-                tctx.swap_layer(prev)
+        return moe_mod.moe_block(
+            p["moe"], h, cfg.moe, cfg.act,
+            mesh=ctx.mesh, batch_axes=ctx.batch_axes,
+            model_axis=ctx.model_axis,
+            spamm_cfg=None if require_frozen else spamm_cfg,
+        )
     return mlp(p["mlp"], h, cfg.act, spamm_cfg, frozen,
                require_frozen), jnp.float32(0.0)
 
@@ -476,34 +486,23 @@ def stack_fwd(
     """Run all layers (train/loss path, no caches). Returns (x, aux), or
     (x, aux, (frac_sum, gemm_count, layer_frac_sums, layer_gemm_counts))
     with `collect_spamm_stats` — the last two are (num_layers,) f32 arrays
-    of per-layer fraction sums / gated-GEMM counts (the per-layer
-    attribution the grad path cannot get from callbacks).
+    of per-layer fraction sums / gated-GEMM counts.
 
-    The stats ride the scan carry/ys as traced values (SpammContext's trace
-    buffer), NOT io_callbacks — callbacks are dropped under
-    grad-of-custom_vjp, dataflow is not, so the train step can export the
-    same per-GEMM fractions the serving engine taps. MoE expert GEMMs trace
-    inside shard_map and are excluded (see moe_block)."""
+    The stats ride the scan carry/ys as traced values (the context's trace
+    buffer, opened per layer), which survive grad-of-custom_vjp and remat,
+    so the train step exports the same per-GEMM fractions the serving
+    engine's steps return."""
     kind = stack_kinds(cfg)
-    collect = (collect_spamm_stats and spamm_cfg is not None
-               and spamm_cfg.enable)
+    tctx = _tap_ctx(spamm_cfg) if collect_spamm_stats else None
 
     def tapped_layer(p, h, k):
         """layer_fwd with its gated-GEMM taps captured as traced values."""
-        if not collect:
-            h, a, _ = layer_fwd(p, h, cfg, pcfg, ctx, positions, k,
-                                spamm_cfg=spamm_cfg)
+        (h, a, _), tv, _ = collect_taps(tctx, layer_fwd, p, h, cfg, pcfg,
+                                        ctx, positions, k,
+                                        spamm_cfg=spamm_cfg)
+        if tv is None:
             return h, a, jnp.float32(0.0), jnp.float32(0.0)
-        spamm_cfg.begin_trace_buffer()
-        try:
-            h, a, _ = layer_fwd(p, h, cfg, pcfg, ctx, positions, k,
-                                spamm_cfg=spamm_cfg)
-        finally:
-            fracs = spamm_cfg.drain_trace_buffer()
-        vs = jnp.float32(0.0)
-        for f in fracs:
-            vs = vs + f
-        return h, a, vs, jnp.float32(len(fracs))
+        return h, a, jnp.sum(tv[:, 0]), jnp.float32(tv.shape[0])
 
     zero = jnp.float32(0.0)
 
@@ -532,7 +531,7 @@ def stack_fwd(
             aux, vs, vc = aux + a, vs + s, vc + c
             lvs.append(s[None])
             lvc.append(c[None])
-        if collect:
+        if tctx is not None:
             return x, aux, (vs, vc, jnp.concatenate(lvs),
                             jnp.concatenate(lvc))
         return x, aux
@@ -555,7 +554,7 @@ def stack_fwd(
             ls.append(s)
             lc.append(c)
         lvs, lvc = jnp.stack(ls), jnp.stack(lc)
-    return (x, aux, (vs, vc, lvs, lvc)) if collect else (x, aux)
+    return (x, aux, (vs, vc, lvs, lvc)) if tctx is not None else (x, aux)
 
 
 def stack_prefill(
@@ -570,7 +569,7 @@ def stack_prefill(
     spamm_cfg=None,
     frozen=None,
 ):
-    """Forward + collect caches. Returns (x, cache_pytree).
+    """Forward + collect caches. Returns (x, cache_pytree, stats).
 
     `spamm_cfg` is the SpammContext the serving engine threads so prefill
     GEMMs run through the plan/execute pipeline like the train forward.
@@ -579,9 +578,9 @@ def stack_prefill(
     ride the layer scan as a second xs); {} / missing keys fall back to the
     traced gate.
 
-    When `spamm_cfg` is a SpammContext, each layer's index rides the scan
-    as an extra xs and is fed to `set_layer` — the taps inside the scanned
-    body then report per-layer labels at execution time."""
+    `stats` is the step's `StepStats` (None without an enabled
+    SpammContext): each layer's gated-GEMM taps ride the scan as ys and are
+    labeled by layer and site."""
     kind = stack_kinds(cfg)
     s = x.shape[1]
     fz = frozen or {}
@@ -601,57 +600,53 @@ def stack_prefill(
             c = dict(c, k=tail_k, v=tail_v)
         return c
 
+    def layer(p, h, k, f):
+        (h, _, c), tv, rows = collect_taps(
+            tctx, layer_fwd, p, h, cfg, pcfg, ctx, positions, k,
+            spamm_cfg=spamm_cfg, collect_cache=True, frozen=f)
+        return h, trim(c), tv, rows
+
     if kind == "hybrid":
         n_groups, gkinds, tail = hybrid_pattern(cfg)
         glen = len(gkinds)
+        grows = []
 
-        def gbody(h, pfg):
-            p, f, g = pfg
-            caches = {}
+        def gbody(h, pf):
+            p, f = pf
+            caches, tvs, rows = {}, [], []
             for i, k in enumerate(gkinds):
-                if tctx is not None:
-                    tctx.set_layer(g * glen + i)
-                h, _, c = layer_fwd(p[f"l{i}"], h, cfg, pcfg, ctx, positions, k,
-                                    spamm_cfg=spamm_cfg, collect_cache=True,
-                                    frozen=f.get(f"l{i}"))
-                caches[f"l{i}"] = trim(c)
-            return h, caches
+                h, caches[f"l{i}"], tv, r = layer(p[f"l{i}"], h, k,
+                                                  f.get(f"l{i}"))
+                tvs.append(tv)
+                rows += [(i,) + y for y in r]
+            grows[:] = rows
+            return h, (caches, None if tctx is None else
+                       jnp.concatenate(tvs))
 
-        try:
-            x, gcaches = jax.lax.scan(
-                gbody, x, (params["groups"], fz.get("groups", {}),
-                           jnp.arange(n_groups)))
-            tcaches = {}
-            for i, k in enumerate(tail):
-                if tctx is not None:
-                    tctx.set_layer(n_groups * glen + i)
-                x, _, c = layer_fwd(params["tail"][f"l{i}"], x, cfg, pcfg, ctx,
-                                    positions, k, spamm_cfg=spamm_cfg,
-                                    collect_cache=True,
-                                    frozen=fz.get("tail", {}).get(f"l{i}"))
-                tcaches[f"l{i}"] = trim(c)
-        finally:
-            if tctx is not None:
-                tctx.set_layer(None)
-        return x, {"groups": gcaches, "tail": tcaches}
+        x, (gcaches, gtv) = jax.lax.scan(
+            gbody, x, (params["groups"], fz.get("groups", {})))
+        parts = [(gtv, grows, glen, 0)]
+        tcaches = {}
+        for i, k in enumerate(tail):
+            x, tcaches[f"l{i}"], tv, r = layer(
+                params["tail"][f"l{i}"], x, k,
+                fz.get("tail", {}).get(f"l{i}"))
+            parts.append((None if tv is None else tv[None],
+                          [(0,) + y for y in r], 1, n_groups * glen + i))
+        return (x, {"groups": gcaches, "tail": tcaches},
+                _stack_stats(parts))
 
-    def body(h, pfl):
-        p, f, li = pfl
-        if tctx is not None:
-            tctx.set_layer(li)
-        h, _, c = layer_fwd(p, h, cfg, pcfg, ctx, positions, kind,
-                            spamm_cfg=spamm_cfg, collect_cache=True,
-                            frozen=f)
-        return h, trim(c)
+    rows = []
 
-    try:
-        x, caches = jax.lax.scan(body, x, (params["layers"],
-                                           fz.get("layers", {}),
-                                           jnp.arange(cfg.num_layers)))
-    finally:
-        if tctx is not None:
-            tctx.set_layer(None)
-    return x, {"layers": caches}
+    def body(h, pf):
+        p, f = pf
+        h, c, tv, r = layer(p, h, kind, f)
+        rows[:] = [(0,) + y for y in r]
+        return h, (c, tv)
+
+    x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
+                                             fz.get("layers", {})))
+    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])
 
 
 def stack_prefill_chunk(
@@ -672,7 +667,7 @@ def stack_prefill_chunk(
     Attention ("attn") stacks only — ssm/hybrid recurrent state cannot
     resume from a position offset without threading the whole state chain.
 
-    Layer labels ride the scan like `stack_prefill`'s."""
+    Returns (x, cache, stats), the stats as `stack_prefill`'s."""
     kind = stack_kinds(cfg)
     if kind != "attn":
         raise NotImplementedError(
@@ -681,23 +676,20 @@ def stack_prefill_chunk(
             f"checkpoint at a chunk boundary)")
     fz = frozen or {}
     tctx = _tap_ctx(spamm_cfg)
+    rows = []
 
     def body(h, pcf):
-        p, c, f, li = pcf
-        if tctx is not None:
-            tctx.set_layer(li)
-        h, nc = layer_prefill_chunk(p, h, c, positions, cfg, pcfg, ctx,
-                                    spamm_cfg=spamm_cfg, frozen=f)
-        return h, nc
+        p, c, f = pcf
+        (h, nc), tv, r = collect_taps(tctx, layer_prefill_chunk, p, h, c,
+                                      positions, cfg, pcfg, ctx,
+                                      spamm_cfg=spamm_cfg, frozen=f)
+        rows[:] = [(0,) + y for y in r]
+        return h, (nc, tv)
 
-    try:
-        x, caches = jax.lax.scan(body, x, (params["layers"], cache["layers"],
-                                           fz.get("layers", {}),
-                                           jnp.arange(cfg.num_layers)))
-    finally:
-        if tctx is not None:
-            tctx.set_layer(None)
-    return x, {"layers": caches}
+    x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
+                                             cache["layers"],
+                                             fz.get("layers", {})))
+    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])
 
 
 def stack_decode(
@@ -716,59 +708,54 @@ def stack_decode(
     compiled work-list, sites without fall back to dense (require_frozen in
     `layer_decode`) — per-step re-tracing of the gate is never paid.
 
-    Layer labels ride the scan like `stack_prefill`'s."""
+    Returns (x, cache, stats), the stats as `stack_prefill`'s."""
     kind = stack_kinds(cfg)
     fz = frozen or {}
     tctx = _tap_ctx(spamm_cfg)
 
+    def layer(p, h, c, k, f):
+        return collect_taps(tctx, layer_decode, p, h, c, pos, cfg, pcfg,
+                            ctx, k, spamm_cfg=spamm_cfg, frozen=f)
+
     if kind == "hybrid":
         n_groups, gkinds, tail = hybrid_pattern(cfg)
         glen = len(gkinds)
+        grows = []
 
-        def gbody(h, pcfg_):
-            p, c, f, g = pcfg_
-            newc = {}
+        def gbody(h, pcf):
+            p, c, f = pcf
+            newc, tvs, rows = {}, [], []
             for i, k in enumerate(gkinds):
-                if tctx is not None:
-                    tctx.set_layer(g * glen + i)
-                h, nc = layer_decode(p[f"l{i}"], h, c[f"l{i}"], pos, cfg, pcfg,
-                                     ctx, k, spamm_cfg=spamm_cfg,
-                                     frozen=f.get(f"l{i}"))
-                newc[f"l{i}"] = nc
-            return h, newc
+                (h, newc[f"l{i}"]), tv, r = layer(p[f"l{i}"], h, c[f"l{i}"],
+                                                  k, f.get(f"l{i}"))
+                tvs.append(tv)
+                rows += [(i,) + y for y in r]
+            grows[:] = rows
+            return h, (newc, None if tctx is None else jnp.concatenate(tvs))
 
-        try:
-            x, gcaches = jax.lax.scan(
-                gbody, x, (params["groups"], cache["groups"],
-                           fz.get("groups", {}), jnp.arange(n_groups)))
-            tcaches = {}
-            for i, k in enumerate(tail):
-                if tctx is not None:
-                    tctx.set_layer(n_groups * glen + i)
-                x, nc = layer_decode(params["tail"][f"l{i}"], x,
-                                     cache["tail"][f"l{i}"],
-                                     pos, cfg, pcfg, ctx, k,
-                                     spamm_cfg=spamm_cfg,
-                                     frozen=fz.get("tail", {}).get(f"l{i}"))
-                tcaches[f"l{i}"] = nc
-        finally:
-            if tctx is not None:
-                tctx.set_layer(None)
-        return x, {"groups": gcaches, "tail": tcaches}
+        x, (gcaches, gtv) = jax.lax.scan(
+            gbody, x, (params["groups"], cache["groups"],
+                       fz.get("groups", {})))
+        parts = [(gtv, grows, glen, 0)]
+        tcaches = {}
+        for i, k in enumerate(tail):
+            (x, tcaches[f"l{i}"]), tv, r = layer(
+                params["tail"][f"l{i}"], x, cache["tail"][f"l{i}"], k,
+                fz.get("tail", {}).get(f"l{i}"))
+            parts.append((None if tv is None else tv[None],
+                          [(0,) + y for y in r], 1, n_groups * glen + i))
+        return (x, {"groups": gcaches, "tail": tcaches},
+                _stack_stats(parts))
+
+    rows = []
 
     def body(h, pcf):
-        p, c, f, li = pcf
-        if tctx is not None:
-            tctx.set_layer(li)
-        h, nc = layer_decode(p, h, c, pos, cfg, pcfg, ctx, kind,
-                             spamm_cfg=spamm_cfg, frozen=f)
-        return h, nc
+        p, c, f = pcf
+        (h, nc), tv, r = layer(p, h, c, kind, f)
+        rows[:] = [(0,) + y for y in r]
+        return h, (nc, tv)
 
-    try:
-        x, caches = jax.lax.scan(body, x, (params["layers"], cache["layers"],
-                                           fz.get("layers", {}),
-                                           jnp.arange(cfg.num_layers)))
-    finally:
-        if tctx is not None:
-            tctx.set_layer(None)
-    return x, {"layers": caches}
+    x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
+                                             cache["layers"],
+                                             fz.get("layers", {})))
+    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])

@@ -4,20 +4,21 @@
 benchmark) shares:
 
   * `registry` — `MetricsRegistry` of counters/gauges/histograms with label
-    sets (`layer`, `phase`, `site`, `dtype`); the `SpammContext` taps and
-    the engine's latency measurements feed it. Export with
+    sets (`layer`, `phase`, `site`, `dtype`); the gating stats the compiled
+    steps return and the engine's latency measurements feed it. Export with
     `write_metrics(path)` (Prometheus text) or `registry.snapshot()` (JSON,
     rides `benchmarks.report.write_bench_json(metrics=...)`).
   * `tracer` — `SpanTracer` host-side spans (freeze, plan-store I/O,
-    prefill, decode steps, reshard probe/re-cut, cache permute); export
-    with `write_trace(path)` (Chrome-trace/Perfetto JSON).
+    wave, prefill, decode steps, reshard probe/re-cut, cache permute), each
+    also a profiler host event; export with `write_trace(path)`
+    (Chrome-trace/Perfetto JSON).
   * `residual` — `CostResidualTracker` pairing cost-model predictions with
     measured wall-clock per phase.
 
-Pass `obs=False` to an instrumented component for a hard-off bundle: spans
-and blocking latency measurements are skipped and the cost-prediction taps
-never embed in the traced graphs, so the uninstrumented path is the exact
-pre-PR computation (`benchmarks/obs_overhead.py` holds the <2% line).
+Pass `obs=False` to an instrumented component for a hard-off bundle: no
+spans, no latency reads, no registry feed and no cost channel. The gating
+stats ride the compiled steps as outputs either way, so the compiled
+programs are the same with the bundle on or off.
 """
 from __future__ import annotations
 

@@ -1,12 +1,13 @@
 """Metrics registry: counters, gauges, and explicit-bucket histograms with
 label sets, Prometheus-text rendering, and JSON-able snapshots.
 
-This is the host-side sink the SpAMM telemetry feeds: `SpammContext` taps
-(labeled per phase/layer/site), engine latency (TTFT, per-decode-step),
-`ReshardController` history, and train-loop step durations all land here.
+This is the host-side sink the SpAMM telemetry feeds: the gating stats the
+compiled steps return (labeled per phase/layer/site), engine latency (TTFT,
+per-decode-step), `ReshardController` history, and train-loop step
+durations all land here.
 Deliberately dependency-free and tiny — a handful of dicts behind one lock —
-because it sits on the serving hot path: `observe()`/`inc()` must cost less
-than the `io_callback` that delivered the sample.
+because the engine feeds it once per wave from the host, where its cost
+counts against the wave (`observe_many` bins a whole series at once).
 
 Metric naming follows the Prometheus conventions the dump targets: counters
 end in `_total`, histograms expose `<name>_bucket{le=...}` (cumulative),
@@ -94,6 +95,13 @@ class _Metric:
                 f"{self.name}: labels {sorted(labels)} != declared "
                 f"{sorted(self.labelnames)}") from None
 
+    def _values_key(self, values: Sequence) -> Tuple[str, ...]:
+        """`_key` for label values given in `labelnames` order."""
+        if len(values) != len(self.labelnames):
+            raise ValueError(f"{self.name}: label values {tuple(values)} "
+                             f"!= declared {self.labelnames}")
+        return tuple(map(str, values))
+
     def series(self) -> Dict[Tuple[str, ...], object]:
         with self._lock:
             return dict(self._series)
@@ -110,6 +118,16 @@ class Counter(_Metric):
         key = self._key(labels)
         with self._lock:
             self._series[key] = self._series.get(key, 0.0) + float(amount)
+
+    def inc_many(self, series: Dict[Tuple, float]):
+        """`inc` many series at once: `series` maps label values (in
+        `labelnames` order) to amounts."""
+        keys = [self._values_key(v) for v in series]
+        if any(a < 0 for a in series.values()):
+            raise ValueError(f"{self.name}: counter increment must be >= 0")
+        with self._lock:
+            for key, amount in zip(keys, series.values()):
+                self._series[key] = self._series.get(key, 0.0) + float(amount)
 
     def value(self, **labels) -> float:
         return float(self._series.get(self._key(labels), 0.0))
@@ -178,6 +196,23 @@ class Histogram(_Metric):
             s.count += 1
             if s.recent is not None:
                 s.recent.append(v)
+
+    def merge(self, series: Dict[Tuple, tuple]):
+        """Add samples binned elsewhere, many series at once: `series` maps
+        label values (in `labelnames` order) to `(counts, total)`, where
+        `counts[i]` (an int) samples fell in bucket `i` as `observe` files
+        them (`len(buckets) + 1` entries, the last +Inf) and `total` is their
+        sum. The raw samples are gone, so a `keep_recent` histogram
+        refuses it."""
+        if self.keep_recent:
+            raise ValueError(f"{self.name}: merge keeps no recent samples")
+        keys = [self._values_key(v) for v in series]
+        with self._lock:
+            for key, (counts, total) in zip(keys, series.values()):
+                s = self._get(key)
+                s.counts = [a + b for a, b in zip(s.counts, counts)]
+                s.sum += float(total)
+                s.count += sum(counts)
 
     def count(self, **labels) -> int:
         s = self._series.get(self._key(labels))

@@ -3,20 +3,20 @@
 The PR 7 cost model (`core.cost.predict_time_s`) drives autotuning and
 re-shard probes, but nothing told us when its calibrated coefficients drift
 from reality (new machine, stale `CostProfile`, changed XLA version). This
-channel closes the loop: each executed frozen-path GEMM taps its in-trace
-predicted call time (`cost.predict_plan_time_s` — same roofline arithmetic,
-embedded next to the gate so it sees the EXECUTED work-list, not a planning
-estimate), the engine pairs the per-phase prediction sums with the measured
-host wall-clock of that phase, and the log2(measured/predicted) ratio lands
-in a histogram.
+channel closes the loop: each executed frozen-path GEMM is priced by the
+roofline model on the EXECUTED work-list (`cost.predict_plan_static` at
+trace time, finished on the host from the valid fraction and bytes the
+step returns — `cost.finish_plan_time_s`), the engine pairs the per-phase
+prediction sums with the measured host wall-clock of that phase, and the
+log2(measured/predicted) ratio lands in a histogram.
 
 Interpretation: a calibrated profile on its own machine should concentrate
 mass near 0 (within ±0.5 ≈ 1.4x); a persistent shift means re-run
 `benchmarks/autotune.py --calibrate`. Granularity is per phase per wave
-(prefill total, decode-step total), NOT per kernel: the taps are unordered
-io_callbacks, so individual GEMMs cannot be paired with sub-step wall-clock
-without serializing the step. The per-phase sum is exactly the quantity the
-autotuner's argmin integrates, so it is also the right one to validate.
+(prefill total, decode-step total), NOT per kernel: the host measures whole
+steps, so individual GEMMs cannot be paired with sub-step wall-clock. The
+per-phase sum is exactly the quantity the autotuner's argmin integrates, so
+it is also the right one to validate.
 """
 from __future__ import annotations
 

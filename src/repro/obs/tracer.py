@@ -4,8 +4,14 @@ Spans are wall-clock (`time.perf_counter_ns`) intervals around HOST-side
 phases of a run: plan freeze, plan-store I/O, compiled prefill, each decode
 step, reshard probe / re-cut, cache permute. They deliberately measure the
 dispatch+block window (the engine blocks on the step output anyway for its
-lockstep loop), not device kernel time — per-kernel attribution comes from
-the labeled taps, spans answer "where did the wall-clock of this wave go".
+lockstep loop), not device kernel time.
+
+Every span is also a profiler host event: it opens a
+`jax.profiler.TraceAnnotation` of the same name at its start and closes it
+at its end, so while a profiler trace is collecting the program's spans sit
+on the device trace's clock (outside one, the annotation records nothing).
+Library code that has no `SpanTracer` (`core/plan.py`, `core/spamm.py`)
+opens profiler-only spans through `annotate`.
 
 Export is the Chrome trace-event JSON format ("traceEvents", `ph: "X"`
 complete events, microsecond timestamps), loadable in Perfetto / chrome
@@ -13,12 +19,51 @@ about://tracing.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from contextlib import contextmanager
 from typing import Optional
+
+
+def annotate(name: str):
+    """A profiler-only span: a `jax.profiler.TraceAnnotation` (a context
+    manager) named `name`, recorded while a profiler trace is collecting.
+    While JAX traces (under jit, vmap or grad) it is a no-op context: the
+    span would time the trace, not the work."""
+    import jax  # deferred: the registry side of repro.obs needs no jax
+
+    if not jax.core.trace_ctx.is_top_level():
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+class OpenSpan:
+    """A span opened now and closed by a later `end()`: for intervals whose
+    natural end is a blocking point outside one `with` block (the engine's
+    decode loop blocks on step t's output at the top of iteration t+1)."""
+
+    __slots__ = ("_tracer", "name", "args", "t0_ns", "_ann")
+
+    def __init__(self, tracer: Optional["SpanTracer"], name: str, args: dict):
+        self._tracer, self.name, self.args = tracer, name, args
+        self._ann = None
+        if tracer is not None:
+            self._ann = annotate(name)
+            self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+
+    def end(self, **args) -> int:
+        """Close the span (once); returns its end, `perf_counter_ns`."""
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+            self._tracer._complete(self.name, self.t0_ns, t1,
+                                   {**self.args, **args})
+        return t1
 
 
 class SpanTracer:
@@ -40,40 +85,11 @@ class SpanTracer:
             if len(self.events) < self.max_events:
                 self.events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, **args):
-        """Time a host-side phase; extra kwargs become viewer-visible args."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter_ns()
-            ev = {
-                "name": name,
-                "ph": "X",
-                "ts": (t0 - self._epoch_ns) / 1e3,   # µs
-                "dur": (t1 - t0) / 1e3,
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-            }
-            if args:
-                ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-            self._emit(ev)
-
-    def add_complete(self, name: str, t0_ns: int, t1_ns: int, **args):
-        """Record a span from explicit perf_counter_ns endpoints — for code
-        whose natural end-of-interval is a later blocking point (the
-        engine's decode loop blocks on step t's output at the top of
-        iteration t+1, so the span closes there)."""
-        if not self.enabled:
-            return
+    def _complete(self, name: str, t0_ns: int, t1_ns: int, args: dict):
         ev = {
             "name": name,
             "ph": "X",
-            "ts": (t0_ns - self._epoch_ns) / 1e3,
+            "ts": (t0_ns - self._epoch_ns) / 1e3,   # µs
             "dur": (t1_ns - t0_ns) / 1e3,
             "pid": os.getpid(),
             "tid": threading.get_ident(),
@@ -81,6 +97,24 @@ class SpanTracer:
         if args:
             ev["args"] = {k: _jsonable(v) for k, v in args.items()}
         self._emit(ev)
+
+    @contextmanager
+    def span(self, name: str, **args):
+        """Time a host-side phase; extra kwargs become viewer-visible args."""
+        if not self.enabled:
+            yield
+            return
+        s = OpenSpan(self, name, args)
+        try:
+            yield
+        finally:
+            s.end()
+
+    def begin(self, name: str, **args) -> OpenSpan:
+        """Open a span that a later `OpenSpan.end()` closes. A disabled
+        tracer returns a span that records nothing (its `end()` still
+        reads the clock)."""
+        return OpenSpan(self if self.enabled else None, name, args)
 
     def instant(self, name: str, **args):
         """Zero-duration marker (e.g. 'reshard committed')."""

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.core import cost as _cost
 from repro.core import module as spmod
 from repro.core import schedule as _schedule
 from repro.core.plan import _bucket
@@ -39,6 +40,33 @@ from repro.obs import (FRACTION_BUCKETS, Histogram, LATENCY_BUCKETS_S,
                        Observability)
 
 # queue-depth / occupancy histograms bucket on a request-count ladder
+# The engine's compiled steps keep a two-output form, (cache, logits) for
+# prefill and chunk and (logits, cache) for decode, which the benchmark's
+# fault-injection tests wrap. The model step's third output, its gating
+# stats, rides the returned cache under this key until `_keep_stats` takes
+# it out on the host.
+_STATS_KEY = "spamm_stats"
+
+
+def _two_outputs(step, cache_in: Optional[int] = None):
+    """The engine's two-output form of a model step, whose gating stats (the
+    step's third output, when not None) join its returned cache under
+    `_STATS_KEY`. A cache that still holds them entering the step as
+    argument `cache_in` is refused when the step traces."""
+    def two(*args):
+        if cache_in is not None and _STATS_KEY in args[cache_in]:
+            raise ValueError("a step's gating stats were fed back into a "
+                             "step with its cache")
+        first, second, stats = step(*args)
+        if stats is None:
+            return first, second
+        if isinstance(first, dict):
+            return dict(first, **{_STATS_KEY: stats}), second
+        return first, dict(second, **{_STATS_KEY: stats})
+
+    return two
+
+
 COUNT_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
 
 
@@ -156,26 +184,28 @@ class Engine:
     partition's predicted imbalance.
 
     Telemetry (`obs`, a `repro.obs.Observability` bundle): the engine feeds
-    three sinks. (1) The METRICS REGISTRY gets labeled per-execution samples
-    from the context's `Tap` events — valid-fraction histograms and
-    GEMM/byte counters keyed (phase, layer, site[, dtype]) — plus TTFT and
-    per-decode-step latency histograms, wave/token counters, plan-cache and
-    plan-store hit/miss counters, and the `ReshardController`'s probe
-    history; `Observability.write_metrics` dumps it in Prometheus text
-    form. (2) The SPAN TRACER records host wall-clock spans (freeze,
-    plan_assembly, prefill, decode_step, reshard_probe, cache_permute,
-    wave) exportable as Chrome-trace JSON for Perfetto. (3) The
-    COST-RESIDUAL channel pairs each phase's roofline-predicted seconds
-    (summed over the wave's executed gated GEMMs via in-graph
-    `cost.predict_plan_time_s` taps) with measured wall-clock into a
-    log2-ratio histogram — the live calibration check on the cost model
-    the autotuner and the re-sharder both lean on. `obs=False` is the
-    hard-off A/B baseline: no spans, no latency reads, and the cost taps
-    never embed, so the traced graphs are exactly the pre-telemetry ones
-    (benchmarks/obs_overhead.py holds the instrumented engine to <2%
-    overhead and bit-identical tokens against it). Labels ride the existing
-    callbacks as static partial args or traced operands — jit cache keys
-    and `trace_counts` are unchanged by instrumentation.
+    three sinks. (1) The METRICS REGISTRY gets labeled samples of the gating
+    stats every compiled step returns as an output (`module.StepStats`:
+    per layer and site, the executed GEMM's valid fraction and bytes moved)
+    — valid-fraction histograms and GEMM/byte counters keyed (phase, layer,
+    site[, dtype]) — plus TTFT and per-decode-step latency histograms,
+    wave/token counters, plan-cache and plan-store hit/miss counters, and
+    the `ReshardController`'s probe history; `Observability.write_metrics`
+    dumps it in Prometheus text form. The step stats stay on the device
+    until the wave's last token and are fetched once (`wave_close`): no
+    host callback and no extra sync inside a step. (2) The SPAN TRACER
+    records host wall-clock spans (freeze, plan_assembly, wave, wave_open,
+    prefill, prefill_chunk, decode_step, wave_close, reshard_probe,
+    cache_permute), each also a profiler host event, exportable as
+    Chrome-trace JSON for Perfetto. (3) The COST-RESIDUAL channel pairs
+    each phase's roofline-predicted seconds (static terms captured at trace
+    time, finished on the host from the returned stats) with measured
+    wall-clock into a log2-ratio histogram — the live calibration check on
+    the cost model the autotuner and the re-sharder both lean on.
+    `obs=False` turns off spans, latency reads, the registry feed and the
+    cost channel; the gating stats in `Request.out["spamm"]` are the same
+    either way. Stats ride the steps as outputs with static labels, so jit
+    cache keys and `trace_counts` are unchanged by instrumentation.
     """
 
     def __init__(self, cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
@@ -227,8 +257,8 @@ class Engine:
                     f"membership and the gate")
         # `obs`: an Observability bundle to share (CLI passes one so the
         # exported dump covers the whole run), None for a private enabled
-        # bundle, False for hard-off (no spans, no latency blocks, no cost
-        # taps in the traced graphs — the uninstrumented A/B baseline)
+        # bundle, False for hard-off (no spans, no latency reads, no
+        # registry feed, no cost channel)
         self.obs = Observability.ensure(obs, process_name="repro-engine")
         if isinstance(plan_store, str):
             from repro.plans.store import PlanStore  # deferred: optional dep
@@ -289,7 +319,6 @@ class Engine:
             # arm the cost-prediction tap channel BEFORE the first trace:
             # coefficients resolve once, host-side, from the tune profile
             # (or the nominal table) at the config's resolved backend
-            from repro.core import cost as _cost
             from repro.kernels.ops import resolve_backend
 
             scfg = self.spamm_ctx.cfg
@@ -366,19 +395,20 @@ class Engine:
         cfg, pcfg = self.cfg, self.pcfg
         chunkable = stack_kinds(cfg) == "attn"
         if not self._sharded:
-            self._prefill = jax.jit(self._counted(
+            self._prefill = jax.jit(self._counted(_two_outputs(
                 M.make_prefill_step(cfg, pcfg, self.ctx,
-                                    spamm_cfg=self.spamm_ctx), "prefill"))
-            self._decode = jax.jit(self._counted(M.make_decode_step(
-                cfg, pcfg, self.ctx,
-                spamm_cfg=self.spamm_ctx if self._freeze else None),
-                "decode"))
+                                    spamm_cfg=self.spamm_ctx)), "prefill"))
+            self._decode = jax.jit(self._counted(_two_outputs(
+                M.make_decode_step(
+                    cfg, pcfg, self.ctx,
+                    spamm_cfg=self.spamm_ctx if self._freeze else None),
+                cache_in=2), "decode"))
             # chunked prefill shares the "prefill" trace counter: the
             # jit-cache-bound guard counts every prefill-side trace
             self._chunk = None if not chunkable else jax.jit(self._counted(
-                M.make_prefill_chunk_step(cfg, pcfg, self.ctx,
-                                          spamm_cfg=self.spamm_ctx),
-                "prefill"))
+                _two_outputs(M.make_prefill_chunk_step(
+                    cfg, pcfg, self.ctx, spamm_cfg=self.spamm_ctx),
+                    cache_in=2), "prefill"))
             return
         from jax.sharding import PartitionSpec as P
 
@@ -399,21 +429,22 @@ class Engine:
             return jax.tree.map(lambda t: t[None], tree)
 
         def pre_body(params, batch, frozen):
-            cache, logits = inner_pre(params, batch, unstack(frozen))
-            return restack(cache), logits
+            cache, logits, stats = inner_pre(params, batch, unstack(frozen))
+            return restack(cache), logits, restack(stats)
 
         def dec_body(params, inp, cache, pos, frozen):
-            logits, cache = inner_dec(params, inp, unstack(cache), pos,
-                                      unstack(frozen))
-            return logits, restack(cache)
+            logits, cache, stats = inner_dec(params, inp, unstack(cache), pos,
+                                             unstack(frozen))
+            return logits, restack(cache), restack(stats)
 
         mesh = self._spamm_mesh
         self._prefill = jax.jit(jax.shard_map(
-            self._counted(pre_body, "prefill"), mesh=mesh,
+            self._counted(_two_outputs(pre_body), "prefill"), mesh=mesh,
             in_specs=(P(), P("rows"), P("rows")),
             out_specs=(P("rows"), P("rows"))))
         self._decode = jax.jit(jax.shard_map(
-            self._counted(dec_body, "decode"), mesh=mesh,
+            self._counted(_two_outputs(dec_body, cache_in=2), "decode"),
+            mesh=mesh,
             in_specs=(P(), P("rows"), P("rows"), P(), P("rows")),
             out_specs=(P("rows"), P("rows"))))
         self._chunk = None
@@ -423,13 +454,14 @@ class Engine:
 
             def chunk_body(params, batch, cache, positions, last_idx,
                            frozen):
-                cache, logits = inner_chunk(params, batch, unstack(cache),
-                                            positions, last_idx,
-                                            unstack(frozen))
-                return restack(cache), logits
+                cache, logits, stats = inner_chunk(
+                    params, batch, unstack(cache), positions, last_idx,
+                    unstack(frozen))
+                return restack(cache), logits, restack(stats)
 
             self._chunk = jax.jit(jax.shard_map(
-                self._counted(chunk_body, "prefill"), mesh=mesh,
+                self._counted(_two_outputs(chunk_body, cache_in=2),
+                              "prefill"), mesh=mesh,
                 in_specs=(P(), P("rows"), P("rows"), P("rows"), P("rows"),
                           P("rows")),
                 out_specs=(P("rows"), P("rows"))))
@@ -750,56 +782,93 @@ class Engine:
 
         return jax.tree_util.tree_map_with_path(grow, cache)
 
-    def _spamm_stats(self, taps, hits0: int, misses0: int,
+    def _spamm_stats(self, steps, hits0: int, misses0: int,
                      store0: Optional[tuple], reshard0: Optional[tuple],
-                     byte_taps=(), cost_taps=(), ttft_s=None,
-                     decode_lat=()):
-        """Per-wave gating stats dict from the drained `module.Tap` events
-        and the plan-cache/plan-store counter DELTAS across this wave
-        (every counter in the dict is per-wave: after first population a
-        warm wave reports 0/0 store traffic, never stale lifetime totals).
-        With re-sharding on, `resharded`/`reshard_probes` are the wave's
-        event deltas and `partition_imbalance` the live partition's
-        predicted imbalance at the last probe. `byte_taps` (the context's
-        bytes-moved channel, frozen-path GEMMs only) reports SUMS per phase:
-        bandwidth adds up across GEMMs where fractions average. In
-        pod-sharded mode the taps fire PER SHARD (io_callback runs on every
-        mesh device), so `gated_gemms` counts scale by mesh size and the
-        fractions average over shards — pad tiles included, which is the
-        honest number: pad steps are part of each shard's bucket.
+                     ttft_s=None, decode_lat=()):
+        """Per-wave gating stats dict from the wave's `steps` — `(phase,
+        StepStats)` as the compiled steps returned them, still on the
+        device: fetched here in ONE transfer — and the plan-cache/plan-store
+        counter DELTAS across this wave (every counter in the dict is
+        per-wave: after first population a warm wave reports 0/0 store
+        traffic, never stale lifetime totals). With re-sharding on,
+        `resharded`/`reshard_probes` are the wave's event deltas and
+        `partition_imbalance` the live partition's predicted imbalance at
+        the last probe. Bytes moved are SUMS per phase: bandwidth adds up
+        across GEMMs where fractions average. In pod-sharded mode every
+        shard returns its own rows, so `gated_gemms` counts scale by mesh
+        size and the fractions average over shards — pad tiles included,
+        which is the honest number: pad steps are part of each shard's
+        bucket.
 
-        Labeled channels (new in the telemetry subsystem):
-
-        - `per_layer`: {layer: {site: {...}}} breakdown of the same taps —
-          fractions average and counts/bytes sum within each (layer, site)
-          cell, so summing `gated_gemms` over cells reproduces the wave
-          aggregate exactly. Taps without a layer label (layer < 0: eager
-          callers, MoE shard_map interiors) stay out of the breakdown but
-          remain in the aggregates.
+        - `per_layer`: {layer: {site: {...}}} breakdown of the same values
+          — fractions average and counts/bytes sum within each (layer,
+          site) cell, so summing `gated_gemms` over cells reproduces the
+          wave aggregate exactly.
         - `latency`: host wall-clock — `ttft_s` (wave start to first token
           materialized) and decode-step stats (mean/p50/p95 over the wave's
           measured inter-token gaps; p50/p95 are bucket-interpolated from
           a wave-local histogram with the registry's latency ladder).
         - `cost_residual`: per phase, the roofline-predicted seconds summed
           over this wave's executed gated GEMMs (÷ mesh size when sharded:
-          taps fire per shard, shards run concurrently) paired with the
-          measured wall-clock, plus log2(measured/predicted). Only present
-          when the cost channel is armed (engine obs enabled) and both
-          sides are positive.
+          shards run concurrently) paired with the measured wall-clock, plus
+          log2(measured/predicted). Only present when the cost channel is
+          armed (engine obs enabled) and both sides are positive.
         """
+        host = jax.device_get([st.values for _, st in steps])
+        coeffs = self.spamm_ctx.cost_coeffs
+        nbk = len(FRACTION_BUCKETS) + 1
+        # (phase, layer, site) -> ([fraction sum, count, bytes sum, counted
+        # bytes], the fractions' bucket counts on FRACTION_BUCKETS)
+        cells: dict = {}
+        pred = {"prefill": 0.0, "decode": 0.0}
+        groups: dict = {}
+        for (phase, st), v in zip(steps, host):
+            groups.setdefault((phase, st.layout), []).append(
+                np.asarray(v, np.float64).reshape(-1, len(st.layout), 2))
+        for (phase, layout), vs in groups.items():
+            v = np.concatenate(vs)           # (executions, rows, 2)
+            f, nb = v[..., 0], v[..., 1]
+            keys = {}
+            cid = np.asarray([keys.setdefault((phase, layer, site), len(keys))
+                              for layer, site, _ in layout], np.int64)
+            c = len(keys)
+            counted = np.isfinite(nb)
+            agg = np.stack([
+                np.bincount(cid, f.sum(0), c),
+                np.bincount(cid, None, c) * f.shape[0],
+                np.bincount(cid, np.where(counted, nb, 0.0).sum(0), c),
+                np.bincount(cid, counted.sum(0), c)], -1)
+            hist = np.bincount(
+                (cid * nbk + np.searchsorted(FRACTION_BUCKETS, f)).ravel(),
+                None, c * nbk).reshape(c, nbk)
+            for key, a, h in zip(keys, agg.tolist(), hist.tolist()):
+                if key in cells:     # the phase ran at another shape too
+                    a = [x + y for x, y in zip(cells[key][0], a)]
+                    h = [x + y for x, y in zip(cells[key][1], h)]
+                cells[key] = (a, h)
+            priced = [j for j, row in enumerate(layout) if row[2] is not None]
+            if coeffs is not None and priced:
+                static = np.asarray([layout[j][2] for j in priced]).T
+                pred[phase] += float(np.sum(_cost.finish_plan_time_s(
+                    static, f[:, priced], nb[:, priced], coeffs)))
+
+        def totals(decode: bool):
+            a = np.sum([x[0] for (ph, _, _), x in cells.items()
+                        if (ph == "decode") == decode] or [[0.0] * 4], 0)
+            return (float(a[0] / a[1]) if a[1] else None, int(a[1]),
+                    float(a[2]) if a[3] else None)
+
+        vf, gg, nb = totals(False)
+        dvf, dgg, dnb = totals(True)
         cache = self.spamm_ctx.cache
-        pre = [t.value for t in taps if t.phase != "decode"]
-        dec = [t.value for t in taps if t.phase == "decode"]
-        pre_b = [t.value for t in byte_taps if t.phase != "decode"]
-        dec_b = [t.value for t in byte_taps if t.phase == "decode"]
         stats = {
-            "valid_fraction": float(np.mean(pre)) if pre else None,
-            "gated_gemms": len(pre),
-            "decode_valid_fraction": float(np.mean(dec)) if dec else None,
-            "decode_gated_gemms": len(dec),
+            "valid_fraction": vf,
+            "gated_gemms": gg,
+            "decode_valid_fraction": dvf,
+            "decode_gated_gemms": dgg,
             "compute_dtype": getattr(self.spamm_ctx.cfg, "dtype", "float32"),
-            "gemm_bytes_moved": float(np.sum(pre_b)) if pre_b else None,
-            "decode_gemm_bytes_moved": float(np.sum(dec_b)) if dec_b else None,
+            "gemm_bytes_moved": nb,
+            "decode_gemm_bytes_moved": dnb,
             "plan_cache_hits": cache.hits - hits0,
             "plan_cache_misses": cache.misses - misses0,
         }
@@ -813,31 +882,19 @@ class Engine:
             stats["partition_imbalance"] = rs.live_imbalance
         # -- per-(layer, site) breakdown ------------------------------------
         acc: dict = {}
-        for t in taps:
-            if t.layer < 0:
-                continue
-            a = acc.setdefault((t.layer, t.site or ""),
-                               [0.0, 0, 0.0, 0, 0.0])
-            if t.phase == "decode":
-                a[2] += t.value
-                a[3] += 1
-            else:
-                a[0] += t.value
-                a[1] += 1
-        for t in byte_taps:
-            if t.layer < 0:
-                continue
-            a = acc.setdefault((t.layer, t.site or ""),
-                               [0.0, 0, 0.0, 0, 0.0])
-            a[4] += t.value
+        for (phase, layer, site), (a, _) in cells.items():
+            acc.setdefault((layer, site), {})[phase == "decode"] = a
         per_layer: dict = {}
-        for (layer, site), a in sorted(acc.items()):
+        zero = [0.0] * 4
+        for (layer, site), by in sorted(acc.items()):
+            pre, dec = by.get(False, zero), by.get(True, zero)
+            nbytes = pre[2] + dec[2]
             per_layer.setdefault(layer, {})[site] = {
-                "valid_fraction": a[0] / a[1] if a[1] else None,
-                "gated_gemms": a[1],
-                "decode_valid_fraction": a[2] / a[3] if a[3] else None,
-                "decode_gated_gemms": a[3],
-                "gemm_bytes_moved": a[4] if a[4] else None,
+                "valid_fraction": pre[0] / pre[1] if pre[1] else None,
+                "gated_gemms": int(pre[1]),
+                "decode_valid_fraction": dec[0] / dec[1] if dec[1] else None,
+                "decode_gated_gemms": int(dec[1]),
+                "gemm_bytes_moved": nbytes if nbytes else None,
             }
         stats["per_layer"] = per_layer
         # -- latency ---------------------------------------------------------
@@ -854,32 +911,26 @@ class Engine:
                 lat["decode_p95_s"] = h.quantile(0.95)
             stats["latency"] = lat
         # -- cost residual ---------------------------------------------------
-        if cost_taps:
+        if coeffs is not None:
             ndev = self._ndev if self._sharded else 1
-            pred_pre = sum(t.value for t in cost_taps
-                           if t.phase != "decode") / ndev
-            pred_dec = sum(t.value for t in cost_taps
-                           if t.phase == "decode") / ndev
             meas_dec = float(np.sum(decode_lat)) if decode_lat else 0.0
             cres = {}
-            for phase, pred, meas in (("prefill", pred_pre, ttft_s or 0.0),
-                                      ("decode", pred_dec, meas_dec)):
-                if pred > 0.0 and meas > 0.0:
-                    r = self.obs.residual.record(phase, pred, meas)
-                    cres[phase] = {"predicted_s": pred, "measured_s": meas,
+            for phase, meas in (("prefill", ttft_s or 0.0),
+                                ("decode", meas_dec)):
+                p = pred[phase] / ndev
+                if p > 0.0 and meas > 0.0:
+                    r = self.obs.residual.record(phase, p, meas)
+                    cres[phase] = {"predicted_s": p, "measured_s": meas,
                                    "log2_ratio": r}
             if cres:
                 stats["cost_residual"] = cres
         # -- registry feed ---------------------------------------------------
         if self.obs.enabled:
-            for t in taps:
-                lab = dict(phase=t.phase, layer=t.layer, site=t.site or "")
-                self._m_vf.observe(t.value, **lab)
-                self._m_gemms.inc(**lab)
             dtype = stats["compute_dtype"]
-            for t in byte_taps:
-                self._m_bytes.inc(t.value, phase=t.phase, layer=t.layer,
-                                  site=t.site or "", dtype=dtype)
+            self._m_vf.merge({k: (h, a[0]) for k, (a, h) in cells.items()})
+            self._m_gemms.inc_many({k: a[1] for k, (a, _) in cells.items()})
+            self._m_bytes.inc_many({k + (dtype,): a[2]
+                                    for k, (a, _) in cells.items() if a[3]})
             self._m_cache.inc(stats["plan_cache_hits"], result="hit")
             self._m_cache.inc(stats["plan_cache_misses"], result="miss")
             if store0 is not None:
@@ -928,10 +979,8 @@ class Engine:
 
         Host timing uses the lockstep loop's OWN blocking points: the loop
         top's `np.asarray(cur)` blocks on the previous step's output, so the
-        engine records `perf_counter_ns` at dispatch and closes the span
-        retroactively at the next block (`SpanTracer.add_complete`) — zero
-        added device syncs, which is how the instrumented engine stays
-        within the obs_overhead benchmark's budget.
+        engine opens a step's span at dispatch (`SpanTracer.begin`) and
+        closes it at that next block — no added device sync.
         """
         assert requests, "empty batch"
         plens = [len(r.prompt) for r in requests]
@@ -975,38 +1024,31 @@ class Engine:
         one-shot prefill for a chunk loop at one static shard shape."""
         b = len(requests)
         plen = len(requests[0].prompt)
-        toks = np.stack([r.prompt for r in requests]).astype(np.int32)
-        collect = self.spamm_ctx is not None and self.spamm_ctx.enable
-        obs_on = self.obs.enabled
-        t_wave0 = time.perf_counter_ns() if obs_on else 0
-        pend = None          # (name, t0_ns) of a dispatched, un-blocked span
-        ttft_s = None
-        decode_lat: list = []
-        spamm_meta = None
-        store0 = None
-        reshard0 = None
-        if collect:
-            hits0 = self.spamm_ctx.cache.hits
-            misses0 = self.spamm_ctx.cache.misses
-            if self.plan_store is not None:
-                store0 = (self.plan_store.hits, self.plan_store.misses)
-            if self._resharder is not None:
-                reshard0 = (self._resharder.resharded, self._resharder.probes)
-        # frozen-plan assembly counts into this wave's store deltas (it is
-        # where first population / warm-start loading happens)
-        if self._sharded:
-            self._begin_wave(b, plen)
-            frozen_pre = self._sharded_frozen_for(plen)
-            frozen_dec = self._sharded_frozen_for(1)
-        else:
-            frozen_pre = self._frozen_for(b * plen)
-            frozen_dec = self._frozen_for(b) if self._freeze else {}
-        tile = self.spamm_ctx.cfg.tile if collect else 0
-        if collect:
-            self.spamm_ctx.begin_stats()
-        try:
-            if collect:
-                self.spamm_ctx.set_phase("prefill")
+        tracer = self.obs.tracer
+        # the wave's span opens in the function that does its work, so that
+        # the profiler's event of this function covers it and an idle gap
+        # inside the wave reads `wave`
+        with self.obs.span("wave", batch=b, prompt_len=plen):
+            t_wave0 = time.perf_counter_ns()
+            opening = tracer.begin("wave_open")
+            toks = np.stack([r.prompt for r in requests]).astype(np.int32)
+            collect = self.spamm_ctx is not None and self.spamm_ctx.enable
+            obs_on = self.obs.enabled
+            pend = None          # OpenSpan of a dispatched, un-blocked step
+            ttft_s = None
+            decode_lat: list = []
+            steps: list = []  # (phase, StepStats) of the wave, on the device
+            deltas = self._counters() if collect else None
+            # frozen-plan assembly counts into this wave's store deltas (it is
+            # where first population / warm-start loading happens)
+            if self._sharded:
+                self._begin_wave(b, plen)
+                frozen_pre = self._sharded_frozen_for(plen)
+                frozen_dec = self._sharded_frozen_for(1)
+            else:
+                frozen_pre = self._frozen_for(b * plen)
+                frozen_dec = self._frozen_for(b) if self._freeze else {}
+            tile = self.spamm_ctx.cfg.tile if collect else 0
             outs = [[] for _ in range(b)]
             self._maybe_reshard(requests, outs)
             if self._sharded:
@@ -1018,15 +1060,17 @@ class Engine:
                 toks_in = toks[self._shard["perm"]]
             else:
                 toks_in = toks
-            if obs_on:
-                pend = ("prefill", time.perf_counter_ns())
             if chunk:
+                opening.end()
+                pend = tracer.begin("prefill")
                 cache, logits = self._sharded_chunk_prefill(
-                    toks_in, plen, chunk)
+                    toks_in, plen, chunk, steps)
             else:
-                cache, logits = self._prefill(
-                    self.params, {"tokens": jnp.asarray(toks_in)},
-                    frozen_pre)
+                batch = {"tokens": jnp.asarray(toks_in)}
+                opening.end()
+                pend = tracer.begin("prefill")
+                cache, logits = self._prefill(self.params, batch, frozen_pre)
+                cache = self._keep_stats(cache, "prefill", steps)
                 if collect:
                     if self._sharded:
                         self._note_gm(self._shard["wmax_g"] * plen,
@@ -1040,38 +1084,34 @@ class Engine:
             cur = jnp.argmax(logits, -1).astype(jnp.int32)
             pos = plen
             budget = max(r.max_new_tokens for r in requests)
-            if collect:
-                self.spamm_ctx.set_phase("decode")
             for t in range(budget):
                 vis = np.asarray(cur)   # blocks on the previous step
                 if pend is not None:
-                    t1 = time.perf_counter_ns()
-                    name, t0_ns = pend
+                    t1 = pend.end(step=t)
+                    if obs_on:
+                        if pend.name == "prefill":
+                            ttft_s = (t1 - t_wave0) / 1e9
+                            self._m_ttft.observe(ttft_s)
+                        else:
+                            dt = (t1 - pend.t0_ns) / 1e9
+                            decode_lat.append(dt)
+                            self._m_decode_s.observe(dt)
                     pend = None
-                    self.obs.tracer.add_complete(name, t0_ns, t1, step=t)
-                    if name == "prefill":
-                        ttft_s = (t1 - t_wave0) / 1e9
-                        self._m_ttft.observe(ttft_s)
-                    else:
-                        dt = (t1 - t0_ns) / 1e9
-                        decode_lat.append(dt)
-                        self._m_decode_s.observe(dt)
                 if self._sharded:
                     # pad slots mirror their strip's last real group; the
                     # kept-slot table reads each request exactly once
                     vis = vis[self._shard["real_slots"]]
                 for i, r in enumerate(requests):
                     if not done[i]:
-                        outs[i].append(int(vis[i]))
-                        if (r.eos_id is not None and int(vis[i]) == r.eos_id) or \
-                           len(outs[i]) >= r.max_new_tokens:
+                        tok = int(vis[i])
+                        outs[i].append(tok)
+                        if tok == r.eos_id or len(outs[i]) >= r.max_new_tokens:
                             done[i] = True
                 if done.all() or pos >= self.max_len - 1:
                     break
-                if obs_on:
-                    # the decode-step interval opens HERE so reshard stalls
-                    # (probe + cache permute) land inside the step's latency
-                    pend = ("decode_step", time.perf_counter_ns())
+                # the decode-step interval opens HERE so reshard stalls
+                # (probe + cache permute) land inside the step's latency
+                pend = tracer.begin("decode_step")
                 cache, cur = self._maybe_reshard(requests, outs, cache, cur)
                 if self._sharded:
                     frozen_dec = self._sharded_frozen_for(1)
@@ -1079,6 +1119,7 @@ class Engine:
                     self.params, cur[:, None], cache, jnp.int32(pos),
                     frozen_dec
                 )
+                cache = self._keep_stats(cache, "decode", steps)
                 if collect:
                     if self._sharded:
                         self._note_gm(self._shard["wmax_g"], self._ndev)
@@ -1086,48 +1127,59 @@ class Engine:
                         self._note_gm(-(-b // tile))
                 cur = jnp.argmax(logits, -1).astype(jnp.int32)
                 pos += 1
-        finally:
-            if collect:
-                # unordered io_callbacks are NOT flushed by output readiness
-                # — effects_barrier is the documented flush; the finally
-                # closes the collect window even on a failed step so the
-                # context's telemetry can't be left collecting forever
-                jax.effects_barrier()
-                byte_taps = self.spamm_ctx.drain_byte_stats()
-                cost_taps = self.spamm_ctx.drain_cost_stats()
-                taps = self.spamm_ctx.end_stats()
-                self.spamm_ctx.set_phase("prefill")
             if pend is not None:
                 # loop left by budget exhaustion with a step still in
                 # flight: close its span at wall-clock now (no forced
-                # block), but keep it out of the latency histogram —
-                # only fully-blocked intervals are measurements
-                self.obs.tracer.add_complete(pend[0], pend[1],
-                                             time.perf_counter_ns())
-                pend = None
-        if collect:
-            spamm_meta = self._spamm_stats(taps, hits0, misses0, store0,
-                                           reshard0, byte_taps, cost_taps,
-                                           ttft_s, decode_lat)
-        results = [np.asarray(o, np.int32) for o in outs]
-        if obs_on:
-            self.obs.tracer.add_complete("wave", t_wave0,
-                                         time.perf_counter_ns(),
-                                         batch=b, prompt_len=plen)
-            self._m_waves.inc()
-            self._m_tokens.inc(sum(len(o) for o in results))
-        for r, toks_out in zip(requests, results):
-            r.out = {"tokens": toks_out, "spamm": spamm_meta}
+                # block), but keep it out of the latency histogram — only
+                # fully-blocked intervals are measurements
+                pend.end()
+            return self._close_wave(requests, outs, steps, deltas, ttft_s,
+                                    decode_lat)
+
+    def _counters(self) -> tuple:
+        """The plan-cache, plan-store and re-shard counters at a wave's
+        start, for the wave's deltas (`_spamm_stats`)."""
+        cache = self.spamm_ctx.cache
+        store = ((self.plan_store.hits, self.plan_store.misses)
+                 if self.plan_store is not None else None)
+        rs = self._resharder
+        reshard = (rs.resharded, rs.probes) if rs is not None else None
+        return cache.hits, cache.misses, store, reshard
+
+    def _close_wave(self, requests, outs, steps, deltas, ttft_s,
+                    decode_lat) -> List[np.ndarray]:
+        """The wave's `wave_close`: one fetch of its gating stats, their
+        aggregation and the registry feed, and each request's `out`.
+        `deltas` holds the counters read at the wave's start (None
+        without SpAMM)."""
+        with self.obs.span("wave_close"):
+            spamm_meta = (None if deltas is None else self._spamm_stats(
+                steps, *deltas, ttft_s, decode_lat))
+            results = [np.asarray(o, np.int32) for o in outs]
+            if self.obs.enabled:
+                self._m_waves.inc()
+                self._m_tokens.inc(sum(len(o) for o in results))
+            for r, toks_out in zip(requests, results):
+                r.out = {"tokens": toks_out, "spamm": spamm_meta}
         return results
 
+    def _keep_stats(self, cache, phase: str, steps: list):
+        """A step's returned cache without its gating stats, which join the
+        wave's `steps` (left on the device until the wave closes)."""
+        if _STATS_KEY not in cache:
+            return cache
+        steps.append((phase, cache[_STATS_KEY]))
+        return {k: v for k, v in cache.items() if k != _STATS_KEY}
+
     def _sharded_chunk_prefill(self, toks_in: np.ndarray, plen: int,
-                               chunk: int):
+                               chunk: int, steps: list):
         """Prefill the padded sharded wave in `chunk`-token chunks at ONE
         static shard shape. Pad slots replicate live rows (the clamp-pad
         idiom), so every chunk runs the identical program; a partial final
         chunk clamp-pads its token tail and carries sentinel positions
         (>= max_len) there, whose drop-mode cache writes vanish. Returns
-        (stacked full-length linear cache, final-chunk logits)."""
+        (stacked full-length linear cache, final-chunk logits); each
+        chunk's gating stats join `steps`."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -1155,6 +1207,7 @@ class Engine:
             cache, logits = self._chunk(
                 self.params, {"tokens": jnp.asarray(tk)}, cache,
                 jnp.asarray(pos), jnp.asarray(last), frozen_ck)
+            cache = self._keep_stats(cache, "prefill", steps)
             if collect:
                 self._note_gm(self._shard["wmax_g"] * chunk, self._ndev)
             if self.obs.enabled:
@@ -1175,8 +1228,6 @@ class Engine:
         are never read. Termination per slot matches the lockstep wave
         exactly (EOS / max_new_tokens / pos >= max_len - 1 at emit time)."""
         b = len(requests)
-        collect = self.spamm_ctx is not None and self.spamm_ctx.enable
-        obs_on = self.obs.enabled
         cap = min(b, self._max_slots) if self._max_slots else b
         nslots = _bucket(cap, 1)
         if self._max_slots and nslots > self._max_slots:
@@ -1184,42 +1235,36 @@ class Engine:
             # max_slots that would run up to 2x the capped slot pool, so
             # floor to the largest power of two that honors the cap
             nslots = _floor_pow2(self._max_slots)
-        tile = self.spamm_ctx.cfg.tile if collect else 0
-        t_wave0 = time.perf_counter_ns() if obs_on else 0
-        ttft_s = None
-        decode_lat: list = []
-        spamm_meta = None
-        store0 = None
-        reshard0 = None
-        if collect:
-            hits0 = self.spamm_ctx.cache.hits
-            misses0 = self.spamm_ctx.cache.misses
-            if self.plan_store is not None:
-                store0 = (self.plan_store.hits, self.plan_store.misses)
-            if self._resharder is not None:
-                reshard0 = (self._resharder.resharded,
-                            self._resharder.probes)
-        frozen_ck = self._frozen_for(nslots * chunk)
-        frozen_dec = self._frozen_for(nslots) if self._freeze else {}
-        self._first = None
-        cache = self._pad_cache(
-            M.init_cache(self.cfg, self.pcfg, nslots, self.max_len),
-            full=True)
-        outs: List[list] = [[] for _ in range(b)]
-        queue = list(range(b))
-        slot_req = [-1] * nslots       # request index per slot, -1 when idle
-        mode = ["idle"] * nslots       # idle | prefill | decode
-        cursor = [0] * nslots          # prompt tokens already fed
-        pos = [0] * nslots             # tokens materialized in the cache
-        pending: List[Optional[int]] = [None] * nslots
-        cur = np.zeros(nslots, np.int32)
-        if collect:
-            self.spamm_ctx.begin_stats()
-        try:
+        # the wave's span opens here, as `_generate_wave`'s does
+        with self.obs.span("wave", batch=b, slots=nslots, chunk=chunk):
+            t_wave0 = time.perf_counter_ns()
+            opening = self.obs.tracer.begin("wave_open")
+            collect = self.spamm_ctx is not None and self.spamm_ctx.enable
+            obs_on = self.obs.enabled
+            tile = self.spamm_ctx.cfg.tile if collect else 0
+            ttft_s = None
+            decode_lat: list = []
+            steps: list = []  # (phase, StepStats) of the wave, on the device
+            deltas = self._counters() if collect else None
+            frozen_ck = self._frozen_for(nslots * chunk)
+            frozen_dec = self._frozen_for(nslots) if self._freeze else {}
+            self._first = None
+            cache = self._pad_cache(
+                M.init_cache(self.cfg, self.pcfg, nslots, self.max_len),
+                full=True)
+            outs: List[list] = [[] for _ in range(b)]
+            queue = list(range(b))
+            slot_req = [-1] * nslots  # request index per slot, -1 when idle
+            mode = ["idle"] * nslots  # idle | prefill | decode
+            cursor = [0] * nslots     # prompt tokens already fed
+            pos = [0] * nslots        # tokens materialized in the cache
+            pending: List[Optional[int]] = [None] * nslots
+            cur = np.zeros(nslots, np.int32)
+            opening.end()
             while queue or any(m != "idle" for m in mode):
                 if obs_on:
                     self._m_queue.observe(len(queue))
-                # -- admission: queued requests claim idle slots ----------
+                # -- admission: queued requests claim idle slots --------------
                 for s in range(nslots):
                     if mode[s] == "idle" and queue:
                         slot_req[s] = queue.pop(0)
@@ -1229,9 +1274,8 @@ class Engine:
                         if obs_on:
                             self._m_admit.inc()
                 if obs_on:
-                    self._m_occupancy.observe(
-                        sum(m != "idle" for m in mode))
-                # -- one chunk of prefill over the prefilling slots -------
+                    self._m_occupancy.observe(sum(m != "idle" for m in mode))
+                # -- one chunk of prefill over the prefilling slots -----------
                 if any(m == "prefill" for m in mode):
                     tk = np.zeros((nslots, chunk), np.int32)
                     posc = np.full((nslots, chunk), self.max_len, np.int32)
@@ -1240,8 +1284,7 @@ class Engine:
                     for s in range(nslots):
                         if mode[s] != "prefill":
                             continue
-                        pr = np.asarray(requests[slot_req[s]].prompt,
-                                        np.int32)
+                        pr = np.asarray(requests[slot_req[s]].prompt, np.int32)
                         n = min(len(pr) - cursor[s], chunk)
                         tk[s, :n] = pr[cursor[s]:cursor[s] + n]
                         if n < chunk:
@@ -1251,17 +1294,14 @@ class Engine:
                         if cursor[s] >= len(pr):
                             last[s] = n - 1
                             fin.append(s)
-                    if collect:
-                        self.spamm_ctx.set_phase("prefill")
-                    t0 = time.perf_counter_ns() if obs_on else 0
-                    cache, logits = self._chunk(
-                        self.params, {"tokens": jnp.asarray(tk)}, cache,
-                        jnp.asarray(posc), jnp.asarray(last), frozen_ck)
-                    step_tok = np.asarray(
-                        jnp.argmax(logits, -1).astype(jnp.int32))
+                    with self.obs.span("prefill_chunk"):
+                        cache, logits = self._chunk(
+                            self.params, {"tokens": jnp.asarray(tk)}, cache,
+                            jnp.asarray(posc), jnp.asarray(last), frozen_ck)
+                        cache = self._keep_stats(cache, "prefill", steps)
+                        step_tok = np.asarray(
+                            jnp.argmax(logits, -1).astype(jnp.int32))
                     if obs_on:
-                        t1 = time.perf_counter_ns()
-                        self.obs.tracer.add_complete("prefill_chunk", t0, t1)
                         self._m_chunks.inc()
                     if collect:
                         self._note_gm(-(-(nslots * chunk) // tile))
@@ -1273,7 +1313,7 @@ class Engine:
                     if fin and ttft_s is None and obs_on:
                         ttft_s = (time.perf_counter_ns() - t_wave0) / 1e9
                         self._m_ttft.observe(ttft_s)
-                # -- emit pending tokens; finished slots free -------------
+                # -- emit pending tokens; finished slots free -----------------
                 for s in range(nslots):
                     if mode[s] != "decode" or pending[s] is None:
                         continue
@@ -1286,25 +1326,23 @@ class Engine:
                             or pos[s] >= self.max_len - 1):
                         mode[s] = "idle"
                         slot_req[s] = -1
-                # -- one decode step over the decoding slots --------------
+                # -- one decode step over the decoding slots ------------------
                 dec = [s for s in range(nslots) if mode[s] == "decode"]
                 if dec:
                     posv = np.full(nslots, self.max_len, np.int32)
                     for s in dec:
                         cur[s] = outs[slot_req[s]][-1]
                         posv[s] = pos[s]
-                    if collect:
-                        self.spamm_ctx.set_phase("decode")
-                    t0 = time.perf_counter_ns() if obs_on else 0
+                    step = self.obs.tracer.begin("decode_step")
                     logits, cache = self._decode(
                         self.params, jnp.asarray(cur)[:, None], cache,
                         jnp.asarray(posv), frozen_dec)
+                    cache = self._keep_stats(cache, "decode", steps)
                     step_tok = np.asarray(
                         jnp.argmax(logits, -1).astype(jnp.int32))
+                    t1 = step.end()
                     if obs_on:
-                        t1 = time.perf_counter_ns()
-                        dt = (t1 - t0) / 1e9
-                        self.obs.tracer.add_complete("decode_step", t0, t1)
+                        dt = (t1 - step.t0_ns) / 1e9
                         decode_lat.append(dt)
                         self._m_decode_s.observe(dt)
                     if collect:
@@ -1313,24 +1351,5 @@ class Engine:
                     for s in dec:
                         pending[s] = int(step_tok[s])
                         pos[s] += 1
-        finally:
-            if collect:
-                jax.effects_barrier()
-                byte_taps = self.spamm_ctx.drain_byte_stats()
-                cost_taps = self.spamm_ctx.drain_cost_stats()
-                taps = self.spamm_ctx.end_stats()
-                self.spamm_ctx.set_phase("prefill")
-        if collect:
-            spamm_meta = self._spamm_stats(taps, hits0, misses0, store0,
-                                           reshard0, byte_taps, cost_taps,
-                                           ttft_s, decode_lat)
-        results = [np.asarray(o, np.int32) for o in outs]
-        if obs_on:
-            self.obs.tracer.add_complete(
-                "wave", t_wave0, time.perf_counter_ns(), batch=b,
-                slots=nslots, chunk=chunk)
-            self._m_waves.inc()
-            self._m_tokens.inc(sum(len(o) for o in results))
-        for r, toks_out in zip(requests, results):
-            r.out = {"tokens": toks_out, "spamm": spamm_meta}
-        return results
+            return self._close_wave(requests, outs, steps, deltas, ttft_s,
+                                    decode_lat)

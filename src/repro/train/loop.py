@@ -106,8 +106,8 @@ def train(
 
     # one context for the whole run, so train steps export the SAME gating
     # stats the serving engine attaches to Request.out["spamm"] — carried as
-    # step METRICS (loss_fn threads them through the scan carry; callbacks
-    # would be dropped under grad)
+    # step METRICS (loss_fn threads them through the scan carry as values,
+    # which survive grad)
     spamm_ctx = spmod.as_context(spamm_cfg)
     collect_spamm = spamm_ctx is not None and spamm_ctx.enable
     step_fn = jax.jit(M.make_train_step(cfg, pcfg, ctx, opt, spamm_cfg=spamm_ctx))
@@ -148,13 +148,11 @@ def train(
             raise RuntimeError(f"injected failure at step {step}")
         batch = data.batch_at(step)
         t0 = time.perf_counter()
-        t0_ns = time.perf_counter_ns()
-        params, opt_state, metrics = step_fn(
-            params, opt_state, batch, jnp.int32(step)
-        )
-        loss = float(metrics["loss"])
-        obs.tracer.add_complete("train_step", t0_ns, time.perf_counter_ns(),
-                                step=step)
+        with obs.span("train_step", step=step):
+            params, opt_state, metrics = step_fn(
+                params, opt_state, batch, jnp.int32(step)
+            )
+            loss = float(metrics["loss"])
         if resharder is not None and resharder.due(step):
             with obs.span("reshard_probe", step=step):
                 probe_reshard(step, batch)

@@ -107,8 +107,8 @@ def test_engine_first_logits_pick_first_tokens():
 def test_engine_spamm_telemetry_on_request_out():
     """With SpAMM enabled, every request's `out` metadata carries the wave's
     gating stats (valid_fraction over the gated prefill GEMMs, plan-cache
-    deltas) — surfaced through the jitted, scan-over-layers prefill via the
-    context's io_callback taps."""
+    deltas) — returned by the jitted, scan-over-layers prefill as a step
+    output (the layer scan's ys)."""
     from repro.configs import SpammConfig
 
     cfg = get_config("musicgen-large").reduced()

@@ -84,7 +84,7 @@ def test_decode_matches_forward(arch):
     else:
         b1 = {"tokens": inp["tokens"][:, : S - 1]}
         last = inp["tokens"][:, S - 1 : S]
-    cache, _ = prefill(params, b1)
+    cache, _, _ = prefill(params, b1)
 
     def grow_kv(path, t):
         keys = [getattr(k, "key", None) for k in path]
@@ -95,7 +95,7 @@ def test_decode_matches_forward(arch):
         return t
 
     cache = jtu.tree_map_with_path(grow_kv, cache)
-    lg_dec, _ = decode(params, last, cache, jnp.int32(S - 1))
+    lg_dec, _, _ = decode(params, last, cache, jnp.int32(S - 1))
     rel = float(jnp.max(jnp.abs(lg_dec - lg_ref))) / (
         float(jnp.max(jnp.abs(lg_ref))) + 1e-9)
     assert rel < 5e-4, rel

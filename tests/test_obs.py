@@ -1,15 +1,17 @@
-"""Telemetry subsystem — ISSUE 9 tentpole coverage.
+"""Telemetry subsystem: registry, spans, gating stats and cost residual.
 
 Fast units pin the host-side sinks (registry semantics + Prometheus
-round-trip, span tracer + Chrome-trace export, cost-residual tracker) and
+round-trip, span tracer + Chrome-trace export, spans as profiler host
+events, cost-residual tracker) and
 the zero-graph-cost identity `predict_plan_static` + `finish_plan_time_s`
 == `predict_plan_time_s` that lets the cost channel arm without changing
 the traced graphs. Integration tests drive real engine waves / train steps
 and assert the labeled-tap contract: per-(layer, site) cells exist under
 `lax.scan`-stacked layers (dense and hybrid stacks) and under `grad`
 (custom_vjp fwd path), their sums reproduce the existing per-wave
-aggregates EXACTLY, and the instrumentation changes neither tokens nor
-trace counts (`obs=False` A/B). The 4-fake-device sharded contract runs in
+aggregates EXACTLY, the instrumentation changes neither tokens, trace
+counts nor the returned stats (`obs=False` A/B), and no compiled serving
+step holds a host callback. The 4-fake-device sharded contract runs in
 a subprocess (device count locks at first jax init), mirroring
 tests/test_sharded_engine.py.
 """
@@ -138,15 +140,18 @@ def test_tracer_spans_and_chrome_export(tmp_path):
     tr = SpanTracer(process_name="repro-test")
     with tr.span("freeze", n=3):
         pass
-    tr.add_complete("decode_step", 1_000, 4_000, step=0)
+    step = tr.begin("decode_step", step=0)
+    t1 = step.end(tokens=8)
+    step.end()                                     # a second end records nothing
     tr.instant("reshard_committed")
     assert tr.span_names() == {"freeze", "decode_step", "reshard_committed"}
     doc = tr.chrome_trace()
     evs = doc["traceEvents"]
     assert evs[0]["ph"] == "M" and evs[0]["args"]["name"] == "repro-test"
-    dec = next(e for e in evs if e["name"] == "decode_step")
-    assert dec["ph"] == "X" and dec["dur"] == pytest.approx(3.0)  # µs
-    assert dec["args"] == {"step": 0}
+    dec = [e for e in evs if e["name"] == "decode_step"]
+    assert len(dec) == 1 and dec[0]["ph"] == "X"
+    assert dec[0]["dur"] == pytest.approx((t1 - step.t0_ns) / 1e3)  # µs
+    assert dec[0]["args"] == {"step": 0, "tokens": 8}
     path = tr.export(str(tmp_path / "trace.json"))
     with open(path) as f:
         assert json.load(f) == doc                 # valid Perfetto JSON
@@ -154,7 +159,7 @@ def test_tracer_spans_and_chrome_export(tmp_path):
     off = SpanTracer(enabled=False)
     with off.span("x"):
         pass
-    off.add_complete("y", 0, 1)
+    off.begin("y").end()
     assert off.events == []                        # hard-off records nothing
     with maybe_span(None, "z"):                    # None-tracer helper
         pass
@@ -193,7 +198,8 @@ def test_cost_residual_tracker_records_log2_ratio():
 
 def test_predict_plan_static_finish_matches_in_trace_prediction():
     """The telemetry taps price a GEMM as predict_plan_static (host, trace
-    time) + finish_plan_time_s (host, callback time). The split must equal
+    time) + finish_plan_time_s (host, from the step's returned stats). The
+    split must equal
     predict_plan_time_s on the same plan EXACTLY — this identity is what
     lets armed and unarmed contexts trace identical graphs."""
     a = jnp.asarray(exponential_decay(128, lam=0.8, seed=0))
@@ -326,7 +332,16 @@ def test_engine_registry_and_spans_cross_check():
     assert reg.counter("serve_waves_total").value() == 1
     # spans cover the wave's host phases and export as valid Chrome JSON
     names = eng.obs.tracer.span_names()
-    assert {"freeze", "prefill", "decode_step", "wave"} <= names
+    assert {"freeze", "prefill", "decode_step", "wave", "wave_open",
+            "wave_close"} <= names
+    # wave_open ends before the prefill opens; wave_close starts after the
+    # last decode step ends; both lie inside the wave
+    ev = {e["name"]: e for e in eng.obs.tracer.events}
+    end = {k: e["ts"] + e["dur"] for k, e in ev.items()}
+    assert ev["wave"]["ts"] <= ev["wave_open"]["ts"]
+    assert end["wave_open"] <= ev["prefill"]["ts"]
+    assert end["decode_step"] <= ev["wave_close"]["ts"]
+    assert end["wave_close"] <= end["wave"]
     json.dumps(eng.obs.tracer.chrome_trace())
     # Prometheus dump of a real run round-trips through the CI parser
     back = parse_prometheus(reg.render_prometheus())
@@ -336,20 +351,243 @@ def test_engine_registry_and_spans_cross_check():
 
 def test_engine_obs_false_is_bit_identical_and_silent():
     """obs=False is the A/B baseline: same tokens, same trace counts, no
-    spans, no latency/cost channels — the exact pre-telemetry path."""
-    _, eng_i, reqs_i, outs_i = _run_wave(max_new=4, seed=3)
+    spans, no latency/cost channels. The gating stats ride the compiled
+    steps either way, so everything the steps return — counts per phase,
+    fractions, bytes, the per-layer cells — is the same with obs on and
+    off; only the host-clock channels (latency, and the cost residual
+    whose measured side is that clock) need obs."""
+    cfg, eng_i, reqs_i, outs_i = _run_wave(max_new=4, seed=3)
     _, eng_b, reqs_b, outs_b = _run_wave(max_new=4, seed=3, obs=False)
     for a, b in zip(outs_i, outs_b):
         np.testing.assert_array_equal(a, b)
     assert eng_b.trace_counts == eng_i.trace_counts == \
         {"prefill": 1, "decode": 1}
-    sp_b = reqs_b[0].out["spamm"]
+    sp_i, sp_b = reqs_i[0].out["spamm"], reqs_b[0].out["spamm"]
     assert "latency" not in sp_b and "cost_residual" not in sp_b
     assert eng_b.obs.tracer.events == []
     assert eng_b.obs.registry.metrics() != eng_i.obs.registry.metrics()
-    # the uninstrumented wave still reports the tap-backed gating stats
-    assert sp_b["gated_gemms"] == reqs_i[0].out["spamm"]["gated_gemms"]
+    host_clock = {"latency", "cost_residual"}
+    assert {k: v for k, v in sp_i.items() if k not in host_clock} == sp_b
+    # every frozen GEMM site of every layer reports once per execution:
+    # one prefill, max_new - 1 decode steps
+    sites = len(sp_b["per_layer"][0])
+    assert sites == 6                              # wq wk wv wo w1 w2
+    assert sp_b["gated_gemms"] == cfg.num_layers * sites
+    assert sp_b["decode_gated_gemms"] == cfg.num_layers * sites * 3
     _assert_cells_sum_to_aggregates(sp_b)
+
+
+def test_step_stats_aggregation_matches_a_per_tap_loop():
+    """The engine bins a wave's returned step stats in numpy; a plain loop
+    over every (execution, row) value is the reference: same aggregates,
+    same per-layer cells, same registry series. The steps mix two shapes
+    of one phase (two layouts), a cell that several rows feed (MoE experts
+    share a site), shard-stacked executions and taps without a byte count
+    (NaN)."""
+    from repro.core.module import StepStats
+
+    cfg = get_config("musicgen-large").reduced()
+    params = M.init_params(cfg, PCFG, jax.random.key(0))
+    eng = Engine(cfg, PCFG, make_ctx(make_host_mesh()), params, max_len=32,
+                 spamm_cfg=SpammConfig(enable=True, tau=0.05, tile=4,
+                                       backend="jnp"))
+    rng = np.random.default_rng(0)
+    static = (1e-5, 64.0, 4, 1)
+    lay_a = ((0, "wq", static), (0, "expert_w1", None),
+             (0, "expert_w1", None), (1, "wq", static))
+    lay_b = ((0, "wq", (2e-5, 32.0, 4, 1)), (1, "wq", static))
+
+    def values(shape, nan_rows=()):
+        v = rng.random(shape + (2,)).astype(np.float32)
+        v[..., 1] *= 1e4
+        for r in nan_rows:
+            v[..., r, 1] = np.nan
+        return v
+
+    steps = [("prefill", StepStats(values((len(lay_a),), (1, 2)), lay_a)),
+             ("prefill", StepStats(values((len(lay_b),)), lay_b)),
+             ("decode", StepStats(values((3, len(lay_a)), (1,)), lay_a)),
+             ("decode", StepStats(values((3, len(lay_a)), (1,)), lay_a))]
+    st = eng._spamm_stats(steps, *eng._counters(), ttft_s=1.0,
+                          decode_lat=[0.1, 0.2])
+
+    ref: dict = {}          # (phase, layer, site) -> [fractions], [bytes]
+    for phase, s in steps:
+        v = np.asarray(s.values, np.float64).reshape(-1, len(s.layout), 2)
+        for row in v:
+            for (layer, site, _), (f, nb) in zip(s.layout, row):
+                c = ref.setdefault((phase, layer, site), ([], []))
+                c[0].append(f)
+                c[1].append(nb)
+    for decode, (vf, n, nb) in ((False, ("valid_fraction", "gated_gemms",
+                                         "gemm_bytes_moved")),
+                                (True, ("decode_valid_fraction",
+                                        "decode_gated_gemms",
+                                        "decode_gemm_bytes_moved"))):
+        fs = [f for k, c in ref.items() if (k[0] == "decode") == decode
+              for f in c[0]]
+        bs = [b for k, c in ref.items() if (k[0] == "decode") == decode
+              for b in c[1] if np.isfinite(b)]
+        assert st[n] == len(fs)
+        assert st[vf] == pytest.approx(np.mean(fs), rel=1e-12)
+        assert st[nb] == pytest.approx(sum(bs), rel=1e-12)
+    for layer, sites in st["per_layer"].items():
+        for site, cell in sites.items():
+            for phase, vf, n in (("prefill", "valid_fraction", "gated_gemms"),
+                                 ("decode", "decode_valid_fraction",
+                                  "decode_gated_gemms")):
+                fs = ref.get((phase, layer, site), ([], []))[0]
+                assert cell[n] == len(fs)
+                if fs:
+                    assert cell[vf] == pytest.approx(np.mean(fs), rel=1e-12)
+    pred = sum(cost.finish_plan_time_s(row[2], f, nb, eng.spamm_ctx.cost_coeffs)
+               for phase, s in steps if phase == "prefill"
+               for v in np.asarray(s.values, np.float64).reshape(
+                   -1, len(s.layout), 2)
+               for row, (f, nb) in zip(s.layout, v) if row[2] is not None)
+    assert st["cost_residual"]["prefill"]["predicted_s"] == \
+        pytest.approx(pred, rel=1e-12)
+    # the registry's binned series equal a histogram fed sample by sample
+    vf = eng.obs.registry.histogram("spamm_valid_fraction", "",
+                                    labelnames=("phase", "layer", "site"))
+    for (phase, layer, site), (fs, _) in ref.items():
+        h = Histogram("ref", buckets=vf.buckets)
+        for f in fs:
+            h.observe(f)
+        lab = dict(phase=phase, layer=layer, site=site)
+        assert vf.count(**lab) == h.count()
+        assert vf.sum(**lab) == pytest.approx(h.sum(), rel=1e-12)
+        for q in (0.1, 0.5, 0.9):
+            assert vf.quantile(q, **lab) == pytest.approx(h.quantile(q))
+
+
+def _spamm_engine(arch: str, b: int):
+    """A reduced SpAMM engine, its params and an empty padded cache of b
+    rows."""
+    cfg = get_config(arch).reduced()
+    params = M.init_params(cfg, PCFG, jax.random.key(0))
+    eng = Engine(cfg, PCFG, make_ctx(make_host_mesh()), params, max_len=32,
+                 spamm_cfg=SpammConfig(enable=True, tau=0.05, tile=4,
+                                       backend="jnp"))
+    cache = eng._pad_cache(M.init_cache(cfg, PCFG, b, 32), full=True)
+    return eng, params, cache
+
+
+def _lowered_steps(arch: str):
+    """StableHLO text of a SpAMM engine's prefill, chunk and decode steps,
+    lowered with the engine's frozen plans."""
+    b, plen = 2, 16
+    eng, params, cache = _spamm_engine(arch, b)
+    toks = jnp.zeros((b, plen), jnp.int32)
+    pos = jnp.zeros((b, plen), jnp.int32)
+    return {
+        "prefill": lambda: eng._prefill.lower(
+            params, {"tokens": toks}, eng._frozen_for(b * plen)),
+        "chunk": lambda: eng._chunk.lower(
+            params, {"tokens": toks}, cache, pos, jnp.zeros(b, jnp.int32),
+            eng._frozen_for(b * plen)),
+        "decode": lambda: eng._decode.lower(
+            params, toks[:, :1], cache, jnp.int32(plen), eng._frozen_for(b)),
+    }
+
+
+@pytest.mark.parametrize("arch,step", [
+    ("musicgen-large", "prefill"), ("musicgen-large", "chunk"),
+    ("musicgen-large", "decode"), ("qwen2-moe-a2.7b", "prefill"),
+])
+def test_compiled_steps_hold_no_host_callback(arch, step):
+    """The gating telemetry leaves each compiled serving step as an output:
+    no step holds a host callback (an io_callback lowers to a
+    `*python_cpu_callback` custom call), MoE expert GEMMs included."""
+    text = _lowered_steps(arch)[step]().as_text()
+    assert "stablehlo" in text
+    assert "callback" not in text
+
+
+def test_step_refuses_a_cache_that_still_holds_gating_stats():
+    """The engine's steps carry their gating stats out in the returned
+    cache; a cache fed back into a step with them still inside is refused
+    when the step traces, not carried along."""
+    from repro.serving import engine as engine_mod
+
+    eng, params, cache = _spamm_engine("musicgen-large", 2)
+    _, out = eng._decode(params, jnp.zeros((2, 1), jnp.int32), cache,
+                         jnp.int32(0), eng._frozen_for(2))
+    assert engine_mod._STATS_KEY in out
+    with pytest.raises(ValueError, match="fed back"):
+        eng._decode(params, jnp.zeros((2, 1), jnp.int32), out, jnp.int32(1),
+                    eng._frozen_for(2))
+    steps = []
+    kept = eng._keep_stats(out, "decode", steps)
+    assert engine_mod._STATS_KEY not in kept and len(steps) == 1
+    eng._decode(params, jnp.zeros((2, 1), jnp.int32), kept, jnp.int32(1),
+                eng._frozen_for(2))
+
+
+def test_spans_are_profiler_host_events(tmp_path):
+    """While a profiler trace collects, a SpanTracer span and a span closed
+    later by `end()` are host events of the same names that contain the
+    annotations opened inside them — the device trace's clock names the
+    program's spans."""
+    tr = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("outer_span"):
+            with jax.profiler.TraceAnnotation("inner_a"):
+                jnp.ones(4).block_until_ready()
+        pend = tr.begin("later_span")
+        with jax.profiler.TraceAnnotation("inner_b"):
+            jnp.ones(4).block_until_ready()
+        pend.end(step=1)
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    for outer, inner in (("outer_span", "inner_a"), ("later_span", "inner_b")):
+        (o0, o1), = ev[outer]
+        (i0, i1), = ev[inner]
+        assert o0 <= i0 < i1 <= o1
+    assert tr.span_names() == {"outer_span", "later_span"}
+
+
+def _host_events(trace_dir) -> dict:
+    """{name: [(start_ns, end_ns)]} of the host planes of the trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (int(e.start_ns), int(e.end_ns)))
+    return out
+
+
+def test_plan_spans_fire_on_the_host_path_only(tmp_path):
+    """`spamm_plan` (with its child `spamm_norms`) spans a concrete plan()
+    and `spamm_execute` the execute dispatch of spamm(); tracing plan()
+    under jit opens none of them."""
+    from repro.core.spamm import spamm
+
+    a = jnp.asarray(exponential_decay(128, lam=0.8, seed=0))
+    b = jnp.asarray(exponential_decay(128, lam=0.8, seed=1))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(spamm(a, b, 0.05, tile=32, backend="jnp"))
+        jax.jit(lambda x, y: pl.plan(x, y, 0.05, tile=32,
+                                     backend="jnp").valid_fraction)(a, b)
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    (p0, p1), = ev["spamm_plan"]
+    (n0, n1), = ev["spamm_norms"]
+    (x0, x1), = ev["spamm_execute"]
+    assert p0 <= n0 < n1 <= p1 <= x0 < x1
 
 
 def test_engine_per_layer_on_hybrid_arch():
@@ -449,11 +687,21 @@ cells = [c for sites in sp["per_layer"].values() for c in sites.values()]
 assert sum(c["gated_gemms"] for c in cells) == sp["gated_gemms"]
 assert sum(c["decode_gated_gemms"] for c in cells) == \
     sp["decode_gated_gemms"]
-# taps fire once per mesh device: counts are divisible by the shard count
+# every shard returns its own rows: counts are divisible by the shard count
 assert sp["gated_gemms"] % 4 == 0, sp["gated_gemms"]
 
 # telemetry adds no traces in sharded mode either
 assert eng.trace_counts == {"prefill": 1, "decode": 1}, eng.trace_counts
+
+# the per-shard stats are step outputs: no compiled step holds a callback
+b = eng._shard["perm"].size          # the live wave's padded slots
+cache = jax.tree.map(lambda t: jnp.broadcast_to(t[None], (4,) + t.shape),
+                     eng._pad_cache(M.init_cache(cfg, pcfg, b // 4, 64)))
+texts = [eng._prefill.lower(params, {"tokens": jnp.zeros((b, plen), jnp.int32)},
+                            eng._sharded_frozen_for(plen)).as_text(),
+         eng._decode.lower(params, jnp.zeros((b, 1), jnp.int32), cache,
+                           jnp.int32(plen), eng._sharded_frozen_for(1)).as_text()]
+assert all("stablehlo" in t and "callback" not in t for t in texts)
 
 # latency + cost channels populated; reshard history published to registry
 assert sp["latency"]["ttft_s"] > 0.0
