@@ -181,14 +181,14 @@ def kernel_phase():
     w = p.work
     steps = (w.step_i, w.step_j, w.step_k, w.step_flags)
 
-    c = spamm_mm.spamm_mm_worklist(a, b, *steps, tile=TILE)
+    c = spamm_mm.spamm_mm_worklist(a, b, *steps, tile=TILE, kb=p.kb)
     with hi:
         c_ref = ref.spamm_matmul_ref(a, b, None, TILE, mask=mask)
     e = rel_err(c, c_ref)
     check(e <= TOL_F32, f"spamm_mm_worklist f32  rel err {e:.3e} <= {TOL_F32}")
 
     a16, b16 = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
-    c = spamm_mm.spamm_mm_worklist(a16, b16, *steps, tile=TILE)
+    c = spamm_mm.spamm_mm_worklist(a16, b16, *steps, tile=TILE, kb=p.kb)
     with hi:
         c_ref = ref.spamm_matmul_ref(a16.astype(jnp.float32),
                                      b16.astype(jnp.float32), None, TILE,
@@ -198,7 +198,15 @@ def kernel_phase():
 
     aq, sa = quantize.quantize_tiles(a, TILE)
     bq, sb = quantize.quantize_tiles(b, TILE)
-    c = spamm_mm.spamm_mm_worklist_int8(aq, bq, sa, sb, *steps, tile=TILE)
+    # the int8 kernel takes one tile product a step: the same work-list at
+    # kb = 1
+    gm, gn, gk = mask.shape
+    w1, _ = P.compact_from_triples(*np.nonzero(np.asarray(mask)), gm=gm,
+                                   gn=gn, gk=gk)
+    c = spamm_mm.spamm_mm_worklist_int8(
+        aq, bq, sa, sb,
+        *(jnp.asarray(t) for t in (w1.step_i, w1.step_j, w1.step_k,
+                                   w1.step_flags)), tile=TILE)
     with hi:
         c_ref = ref.spamm_matmul_ref(quantize.dequantize_tiles(aq, sa, TILE),
                                      quantize.dequantize_tiles(bq, sb, TILE),

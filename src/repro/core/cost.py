@@ -19,6 +19,9 @@ bytes/flops computation per kernel, calibrated per machine:
     kernels (`benchmarks/kernels_micro.py`-style timings: get-norm sweeps +
     work-list executes across τ) by non-negative least squares, and
     `CostProfile` persists them as JSON keyed by backend × device kind.
+  * **k-blocking** (`choose_kb`) — how many k-tiles one work-list grid
+    step covers: the argmin of predicted kernel time over the blocked
+    tables' steps, block fetches and dots, per plan, from its own triples.
   * **Tuner** (`tune`, `tune_weight`) — per-weight argmin of predicted call
     time over `block_n` × pyramid `levels` × bucket floor. The hardcoded
     defaults are always in the search space, so the tuned pick is never
@@ -40,6 +43,7 @@ from typing import Mapping, NamedTuple, Optional, Sequence
 import numpy as np
 
 from repro.kernels import quantize as kquant
+from repro.kernels.common import VMEM_BUDGET, worklist_vmem_bytes
 
 COST_SCHEMA_VERSION = 1
 
@@ -92,10 +96,12 @@ class CostCoeffs(NamedTuple):
 
 # Nominal fallbacks per backend when no calibration profile is attached.
 # interpret's per-step overhead dominates everything (the kernel body runs
-# step-by-step under emulation); pallas numbers are v5e-litepod-ish; jnp is
-# a single fused XLA CPU einsum. Calibration replaces these.
+# step-by-step under emulation); pallas numbers are v5e-litepod-ish, its
+# per-step cost the 0.31–0.57 µs a one-tile work-list step took on a v5e in
+# the serving and decay benchmarks; jnp is a single fused XLA CPU einsum.
+# Calibration replaces these.
 DEFAULT_COEFFS = {
-    "pallas": CostCoeffs(8.0e11, 2.0e14, 2.0e-7, 5.0e-6, 1.0e10),
+    "pallas": CostCoeffs(8.0e11, 2.0e14, 3.5e-7, 5.0e-6, 1.0e10),
     "interpret": CostCoeffs(2.0e9, 1.0e10, 4.0e-5, 3.0e-4, 2.0e8),
     "jnp": CostCoeffs(2.0e10, 5.0e10, 5.0e-7, 5.0e-5, 2.0e8),
 }
@@ -188,15 +194,19 @@ class CostProfile:
 # analytic per-kernel counts
 # ---------------------------------------------------------------------------
 
-def gemm_bytes(valid_tiles, pairs, tile: int, block_n: int, dtype):
-    """GEMM bytes the executed work-list moves — per real step one
-    (tile, tile) A block and one (tile, tile·block_n) B block at the
+def gemm_bytes(valid_tiles, pairs, tile: int, block_n: int, dtype, *,
+               kb: int = 1, steps=None):
+    """GEMM bytes the executed work-list moves — per executed step one
+    (tile, kb·tile) A block and one (kb·tile, tile·block_n) B block at the
     compute dtype's itemsize, plus one f32 (tile, tile·block_n) output
-    flush per active output pair. `SpammPlan.bytes_moved()` delegates here;
-    accepts python floats or jnp arrays (pure arithmetic)."""
+    flush per active output pair. `steps` counts the executed steps; at
+    kb = 1 every surviving tile product is one, so it defaults to
+    `valid_tiles`. `SpammPlan.bytes_moved()` delegates here; accepts python
+    floats or jnp arrays (pure arithmetic)."""
     isize = kquant.dtype_itemsize(dtype)
     t2 = float(tile * tile)
-    return (valid_tiles * (t2 * (1 + block_n) * isize)
+    steps = valid_tiles if steps is None else steps
+    return (steps * (t2 * kb * (1 + block_n) * isize)
             + pairs * (t2 * block_n * 4.0))
 
 
@@ -276,8 +286,10 @@ def predict_counts(
     levels: int = 0,
     bucket_min: int = 16,
     mode: str = "eager",
+    kb: int = 1,
 ) -> KernelCounts:
-    """Analytic call counts for (gm, gk) × (gk, gn) normmaps gated at `tau`.
+    """Analytic call counts for (gm, gk) × (gk, gn) normmaps gated at `tau`,
+    on a work-list of `kb` k-tiles a step (`block_steps`).
 
     The gate here IS `core.plan.gate_mask`'s (any-member super-column
     grouping ≡ max-norm test, fp32 multiply monotone), so on the real
@@ -305,14 +317,16 @@ def predict_counts(
     mask = na[:, None, :] * np.swapaxes(nbmax, 0, 1)[None] >= tau
     v = int(mask.sum())
     pairs = int(mask.any(-1).sum())
+    steps = block_steps(*np.nonzero(mask), kb)[0].size
     if mode == "frozen":
-        if tau > 0.0:
-            adm = int((nbmax > 0.0).sum())
-        else:
-            adm = gk * gnb
-        steps_grid = bucket(gm * adm, bucket_min)
+        adm = (nbmax > 0.0) if tau > 0.0 else np.ones_like(nbmax, bool)
+        kk, jj = np.nonzero(adm)
+        order = np.lexsort((kk, jj))      # pair-major, as `for_rows` emits
+        adm_steps = block_steps(np.zeros_like(jj), jj[order], kk[order],
+                                kb)[0].size
+        steps_grid = bucket(gm * adm_steps, bucket_min)
     elif mode == "eager":
-        steps_grid = bucket(v, bucket_min)
+        steps_grid = bucket(steps, bucket_min)
     else:
         raise ValueError(f"mode {mode!r} not in ('eager', 'frozen')")
     norm_bytes = float(gm * tile) * (gk * tile) * 4.0
@@ -323,14 +337,15 @@ def predict_counts(
     gate_ops = (0.0 if mode == "frozen" else
                 _descent_gate_ops(na, nb, tau, levels))
     if mode == "frozen":
-        # the traced activation gate is one product-compare per grid step
-        gate_ops = float(steps_grid)
+        # the traced activation gate is one product-compare per k-tile of
+        # each grid step
+        gate_ops = float(steps_grid * kb)
     return KernelCounts(
         steps_real=v,
         steps_grid=steps_grid,
         pairs=pairs,
         gemm_bytes=float(gemm_bytes(float(v), float(pairs), tile, block_n,
-                                    dtype)),
+                                    dtype, kb=kb, steps=float(steps))),
         flops=float(gemm_flops(float(v), tile, block_n)),
         norm_bytes=norm_bytes + lv_bytes,
         gate_ops=gate_ops,
@@ -349,6 +364,103 @@ def predict_time_s(counts: KernelCounts, coeffs: CostCoeffs) -> float:
             + counts.gate_ops / coeffs.gate_ops_per_s)
 
 
+# k-tiles one work-list grid step may cover (`kernels.spamm_mm.KB_MAX` caps
+# them: the step's sub-tile mask shares the int32 flags)
+KB_CHOICES = (1, 2, 4, 8, 16)
+
+
+def block_steps(ii, jj, kk, kb: int):
+    """The k-blocked step view of surviving triples given in (i, j)-grouped
+    ascending-k order without duplicates: one step per (i, j, k // kb) that
+    holds a triple, in the same order. Returns numpy (step_i, step_j,
+    step_kblock, bits): `bits` has bit c set where k-tile kblock·kb + c
+    survives. At kb = 1 the steps are the triples themselves."""
+    ii = np.asarray(ii)
+    jj = np.asarray(jj)
+    kk = np.asarray(kk)
+    kblk = kk // kb
+    new = np.ones(ii.size, bool)
+    if ii.size:
+        new[1:] = ((ii[1:] != ii[:-1]) | (jj[1:] != jj[:-1])
+                   | (kblk[1:] != kblk[:-1]))
+    starts = np.flatnonzero(new)
+    sub = np.left_shift(1, (kk % kb)).astype(np.int32)
+    bits = (np.bitwise_or.reduceat(sub, starts) if starts.size
+            else np.zeros(0, np.int32))
+    return ii[starts], jj[starts], kblk[starts], bits
+
+
+def kb_candidates(gk: int, *, tile: int, block_n: int, dtype) -> list:
+    """The k-block widths a work-list over `gk` k-tiles may use: whole
+    k-blocks only, blocks within the kernel's VMEM budget, and 1 alone for
+    int8 (its kernel is one tile product a step)."""
+    dtype = kquant.canonical_dtype(dtype)
+    if dtype == "int8":
+        return [1]
+    isize = kquant.dtype_itemsize(dtype)
+    return [kb for kb in KB_CHOICES
+            if kb == 1 or (gk % kb == 0 and worklist_vmem_bytes(
+                tile, kb, block_n, isize) <= VMEM_BUDGET)]
+
+
+def kb_counts(ii, jj, kk, kb: int, *, tile: int, block_n: int, dtype,
+              bucket_min: int = 16, changes=None) -> KernelCounts:
+    """The kernel's work over triples (as `block_steps` takes them) at `kb`
+    k-tiles a step: the blocked grid, the A and B blocks it fetches (each
+    only where its index changes between consecutive steps), the output
+    flushes and the surviving tile dots. `changes` passes in the triples'
+    (row, column) change masks when several kb are priced."""
+    kk = np.asarray(kk)
+    v = int(kk.size)
+    if v == 0:
+        return KernelCounts(0, bucket(0, bucket_min), 0, 0.0, 0.0, 0.0, 0.0)
+    if changes is None:
+        ii, jj = np.asarray(ii), np.asarray(jj)
+        changes = (ii[1:] != ii[:-1], jj[1:] != jj[:-1])
+    ic, jc = changes
+    # a new step starts where the pair or the k-block changes; the A block
+    # (i, k-block) and the B block (k-block, j) change only at such starts
+    kc = (kk[1:] // kb) != (kk[:-1] // kb)
+    pc = ic | jc
+    steps = 1 + int(np.count_nonzero(pc | kc))
+    a_f = 1 + int(np.count_nonzero(ic | kc))
+    b_f = 1 + int(np.count_nonzero(jc | kc))
+    pairs = 1 + int(np.count_nonzero(pc))
+    isize = kquant.dtype_itemsize(kquant.canonical_dtype(dtype))
+    blk = float(tile * kb * tile) * isize
+    return KernelCounts(
+        steps_real=v,
+        steps_grid=bucket(steps, bucket_min),
+        pairs=pairs,
+        gemm_bytes=(a_f * blk + b_f * blk * block_n
+                    + pairs * float(tile * tile * block_n) * 4.0),
+        flops=float(gemm_flops(float(v), tile, block_n)),
+        norm_bytes=0.0,
+        gate_ops=0.0,
+    )
+
+
+def choose_kb(ii, jj, kk, *, gk: int, tile: int, block_n: int, dtype,
+              coeffs: CostCoeffs, bucket_min: int = 16) -> int:
+    """k-tiles per work-list grid step for these surviving triples: the
+    argmin of `predict_time_s` over `kb_candidates`. A wider step pays the
+    fixed per-step cost once for kb tile dots, but fetches whole k-blocks
+    whether or not every k-tile survives; ties keep the narrower step, so
+    kb = 1 wins wherever blocking buys nothing. O(V) per candidate: it
+    runs on the host between a product's gate and its kernel."""
+    ii, jj = np.asarray(ii), np.asarray(jj)
+    changes = (ii[1:] != ii[:-1], jj[1:] != jj[:-1])
+    best, best_t = 1, None
+    for kb in kb_candidates(gk, tile=tile, block_n=block_n, dtype=dtype):
+        t = predict_time_s(kb_counts(ii, jj, kk, kb, tile=tile,
+                                     block_n=block_n, dtype=dtype,
+                                     bucket_min=bucket_min,
+                                     changes=changes), coeffs)
+        if best_t is None or t < best_t:
+            best, best_t = kb, t
+    return best
+
+
 def predict_plan_time_s(plan, coeffs: CostCoeffs):
     """Predicted wall-clock of ONE executed work-list call, computed from a
     (possibly traced) `SpammPlan`'s own fields — the in-trace twin of
@@ -363,13 +475,13 @@ def predict_plan_time_s(plan, coeffs: CostCoeffs):
     gm, gk = plan.norm_a.shape
     if plan.work is not None and plan.work.step_i is not None:
         # frozen/work-list plans: the grid length is the static step-table
-        # shape; one traced gate product-compare per grid step
+        # shape; one traced gate product-compare per k-tile of a grid step
         steps_grid = float(plan.work.step_i.shape[0])
     else:
         # dense-bitmap plans have no static grid; approximate with the
         # (possibly traced) surviving-step count
         steps_grid = plan.valid_tiles * 1.0
-    gate_ops = steps_grid
+    gate_ops = steps_grid * plan.kb
     norm_bytes = float(gm * plan.tile) * (gk * plan.tile) * 4.0
     lv_bytes, lvl = 0.0, (gm, gk)
     for _ in range(plan.levels):
@@ -413,7 +525,7 @@ def predict_plan_static(plan, coeffs: CostCoeffs):
     const_s = (coeffs.base_overhead_s
                + steps_grid * coeffs.step_overhead_s
                + (norm_bytes + lv_bytes) / coeffs.bytes_per_s
-               + steps_grid / coeffs.gate_ops_per_s)
+               + steps_grid * plan.kb / coeffs.gate_ops_per_s)
     return (const_s, float(gmm * gnb * gkk), plan.tile, plan.block_n)
 
 

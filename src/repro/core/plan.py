@@ -188,6 +188,7 @@ class NormPyramid:
 STEP_INIT = kmm.STEP_INIT
 STEP_ACC = kmm.STEP_ACC
 STEP_FLUSH = kmm.STEP_FLUSH
+STEP_SUB = kmm.STEP_SUB
 
 
 class SpammWork(NamedTuple):
@@ -204,11 +205,15 @@ class SpammWork(NamedTuple):
     Step view (what drives `spamm_mm_worklist`'s 1-D grid; built once here
     so repeated `execute` calls pay nothing — None on plans for backends
     with no ragged executor, which keep an eager bitmap/kidx instead):
-      step_i/step_j/step_k  (S,) int32 — per-grid-step block ids, S = V
-                            padded to a bucket (padding repeats the last
-                            real triple so Pallas revisits, no re-fetch)
-      step_flags            (S,) int32 — STEP_INIT/ACC/FLUSH bits; padding
-                            steps carry no bits (no accumulate, no flush)
+      step_i/step_j/step_k  (S,) int32 — per-grid-step block ids, S = the
+                            steps padded to a bucket (padding repeats the
+                            last real step so Pallas revisits, no
+                            re-fetch); step_k is a k-block of the plan's
+                            `kb` k-tiles (kb = 1: one step per triple)
+      step_flags            (S,) int32 — STEP_INIT/ACC/FLUSH bits, and at
+                            kb > 1 the surviving k-tiles from bit STEP_SUB;
+                            padding steps carry no bits (no accumulate, no
+                            flush)
 
     A NamedTuple of arrays, hence a pytree: plans carrying work pass
     through jit (shapes are static per plan instance).
@@ -238,9 +243,37 @@ _bucket = kcost.bucket
 bucket_ladder = kcost.bucket_ladder
 
 
+def _sort_triples(ii, jj, kk, *, gn: int, gk: int, block_n: int = 1,
+                  assume_sorted: bool = False):
+    """Surviving triples as int32 (ii, jb, kk) in (i, j)-grouped ascending-k
+    order without duplicates, jb at super-column granularity (member
+    columns of one super-column fold into it)."""
+    assert gn % block_n == 0, (gn, block_n)
+    gnb = gn // block_n
+    ii = np.asarray(ii, np.int64).ravel()
+    kk = np.asarray(kk, np.int64).ravel()
+    jb = np.asarray(jj, np.int64).ravel()
+    if block_n > 1:
+        jb = jb // block_n
+    # one fused-key sort instead of a 3-key lexsort (~2× on the hot path);
+    # int64 keys cannot overflow for any grid whose bitmap would fit memory
+    key = (ii * gnb + jb) * gk + kk
+    if not assume_sorted:
+        key = np.sort(key)
+    if block_n > 1 and key.size:
+        # member columns of one super-column collapse to the same (i, jb, k)
+        keep = np.ones(key.size, bool)
+        keep[1:] = key[1:] != key[:-1]
+        key = key[keep]
+    pair = key // gk
+    return ((pair // gnb).astype(np.int32), (pair % gnb).astype(np.int32),
+            (key % gk).astype(np.int32))
+
+
 def compact_from_triples(ii, jj, kk, *, gm: int, gn: int, gk: int,
                          block_n: int = 1, steps: bool = True,
-                         assume_sorted: bool = False, bucket_min: int = 16):
+                         assume_sorted: bool = False, bucket_min: int = 16,
+                         kb: int = 1):
     """kidx/nvalid straight from surviving (i, j, k) triples — §3.3
     map_offset compaction WITHOUT materializing or sorting the dense
     (gm, gn, gk) bitmap.
@@ -269,51 +302,45 @@ def compact_from_triples(ii, jj, kk, *, gm: int, gn: int, gk: int,
     bucket_min is the power-of-two bucket floor of the per-step tables
     (`core.cost.bucket(v, bucket_min)`): the autotuner raises it per weight
     to cut jit recompiles when successive calls straddle bucket boundaries.
+
+    kb > 1 builds the step tables k-blocked: one step per (i, j, k // kb)
+    that holds a triple, `step_k` its k-block, and the flags' sub-tile bits
+    (`kernels.spamm_mm.STEP_SUB`) its surviving k-tiles. The pair view stays
+    per k-tile. kb = 1 is one step per triple.
     """
-    assert gn % block_n == 0, (gn, block_n)
     gnb = gn // block_n
-    ii = np.asarray(ii, np.int64).ravel()
-    kk = np.asarray(kk, np.int64).ravel()
-    jb = np.asarray(jj, np.int64).ravel()
-    if block_n > 1:
-        jb = jb // block_n
-    # one fused-key sort instead of a 3-key lexsort (~2× on the hot path);
-    # int64 keys cannot overflow for any grid whose bitmap would fit memory
-    key = (ii * gnb + jb) * gk + kk
-    if not assume_sorted:
-        key = np.sort(key)
-    if block_n > 1 and key.size:
-        # member columns of one super-column collapse to the same (i, jb, k)
-        keep = np.ones(key.size, bool)
-        keep[1:] = key[1:] != key[:-1]
-        key = key[keep]
-    kk = (key % gk).astype(np.int32)
-    pair = key // gk
-    jb = (pair % gnb).astype(np.int32)
-    ii = (pair // gnb).astype(np.int32)
+    ii, jb, kk = _sort_triples(ii, jj, kk, gn=gn, gk=gk, block_n=block_n,
+                               assume_sorted=assume_sorted)
     v = ii.size
     nvalid = np.zeros((gm, gnb), np.int32)
     step_i = step_j = step_k = step_flags = None
     if steps:
-        s = _bucket(v, bucket_min)
+        si, sj, sk, bits = kcost.block_steps(ii, jb, kk, kb)
+        n = si.size
+        s = _bucket(n, bucket_min)
         step_i = np.zeros(s, np.int32)
         step_j = np.zeros(s, np.int32)
         step_k = np.zeros(s, np.int32)
         step_flags = np.zeros(s, np.int32)
     if v:
         newpair = np.ones(v, bool)
-        newpair[1:] = pair[1:] != pair[:-1]
+        newpair[1:] = (ii[1:] != ii[:-1]) | (jb[1:] != jb[:-1])
         starts = np.flatnonzero(newpair).astype(np.int32)
         rows, cols = ii[starts], jb[starts]
         offsets = np.append(starts, np.int32(v)).astype(np.int32)
         nvalid[rows, cols] = np.diff(offsets)
         if steps:
-            step_i[:v], step_j[:v], step_k[:v] = ii, jb, kk
-            step_i[v:], step_j[v:], step_k[v:] = ii[-1], jb[-1], kk[-1]
-            flags = np.full(v, STEP_ACC, np.int32)
-            flags[starts] |= STEP_INIT
-            flags[np.append(starts[1:], v) - 1] |= STEP_FLUSH
-            step_flags[:v] = flags
+            step_i[:n], step_j[:n], step_k[:n] = si, sj, sk
+            step_i[n:], step_j[n:], step_k[n:] = si[-1], sj[-1], sk[-1]
+            first = np.ones(n, bool)  # each pair's first step
+            first[1:] = (si[1:] != si[:-1]) | (sj[1:] != sj[:-1])
+            firsts = np.flatnonzero(first)
+            flags = np.full(n, STEP_ACC, np.int32)
+            flags[firsts] |= STEP_INIT
+            flags[np.append(firsts[1:], n) - 1] |= STEP_FLUSH
+            if kb > 1:
+                flags |= bits << STEP_SUB
+            step_flags[:n] = flags
     else:
         rows = cols = np.zeros(0, np.int32)
         offsets = np.zeros(1, np.int32)
@@ -390,16 +417,17 @@ class SpammPlan:
 
     Static metadata (aux): tile, block_n, backend (resolved name), levels
     (pyramid coarsening steps the mask was gated with; 0 = flat — the mask is
-    bit-identical either way, `levels` only records how it was built), and
+    bit-identical either way, `levels` only records how it was built),
     compute_dtype ("float32" | "bfloat16" | "int8" — the precision `execute`
     feeds the kernel; the plan's τ is already quantization-widened and its
-    normmaps describe the quantized operand view, see kernels/quantize.py).
+    normmaps describe the quantized operand view, see kernels/quantize.py),
+    and kb — the k-tiles one step of `work` covers (1 without step tables).
     """
 
     def __init__(self, tau, norm_a, norm_b, mask, kidx, nvalid, valid_tiles,
                  work=None, a_scale=None, b_scale=None, *, tile: int,
                  block_n: int, backend: str, levels: int = 0,
-                 compute_dtype: str = "float32"):
+                 compute_dtype: str = "float32", kb: int = 1):
         self.tau = tau
         self.norm_a = norm_a
         self.norm_b = norm_b
@@ -415,6 +443,7 @@ class SpammPlan:
         self.backend = backend
         self.levels = levels
         self.compute_dtype = compute_dtype
+        self.kb = kb
 
     # -- pytree protocol ----------------------------------------------------
     @property
@@ -434,13 +463,13 @@ class SpammPlan:
                     self.kidx, self.nvalid, self.valid_tiles, self.work,
                     self.a_scale, self.b_scale)
         return children, (self.tile, self.block_n, self.backend, self.levels,
-                          self.compute_dtype)
+                          self.compute_dtype, self.kb)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        tile, block_n, backend, levels, compute_dtype = aux
+        tile, block_n, backend, levels, compute_dtype, kb = aux
         return cls(*children, tile=tile, block_n=block_n, backend=backend,
-                   levels=levels, compute_dtype=compute_dtype)
+                   levels=levels, compute_dtype=compute_dtype, kb=kb)
 
     # -- derived quantities -------------------------------------------------
     @property
@@ -467,10 +496,10 @@ class SpammPlan:
         if self._mask is None:
             gm, gnb, gk = self.grid
             w = self.work
-            real = (w.step_flags & STEP_ACC) != 0
+            k, real = _step_subtiles(w.step_k, w.step_flags, self.kb)
             self._mask = (
                 jnp.zeros((gm, gnb, gk), bool)
-                .at[w.step_i, w.step_j, w.step_k].max(real)
+                .at[w.step_i[:, None], w.step_j[:, None], k].max(real)
             )
         return self._mask
 
@@ -483,10 +512,19 @@ class SpammPlan:
     def valid_fraction(self) -> jax.Array:
         return self.valid_tiles / self.total_tiles
 
+    @property
+    def steps(self) -> jax.Array:
+        """Executed work-list steps: those that hold a surviving k-tile
+        (== valid_tiles at kb = 1)."""
+        if self.kb == 1:
+            return self.valid_tiles
+        return jnp.sum((self.work.step_flags & STEP_ACC) != 0,
+                       dtype=jnp.int32)
+
     def bytes_moved(self):
         """Analytic GEMM bytes the executed work-list moves at this plan's
-        compute dtype: per real step one (tile, tile) A block and one
-        (tile, tile·block_n) B block at `compute_dtype` itemsize, plus one
+        compute dtype: per executed step one (tile, kb·tile) A block and one
+        (kb·tile, tile·block_n) B block at `compute_dtype` itemsize, plus one
         f32 (tile, tile·block_n) output flush per active output pair. The
         mixed-precision bandwidth lever in one number (ROADMAP: cut decode
         GEMM bytes ~2× on the same work-list); int8 scale tables are a few
@@ -503,14 +541,17 @@ class SpammPlan:
         # interesting grid does
         return kcost.gemm_bytes(
             self.valid_tiles.astype(jnp.float32), pairs.astype(jnp.float32),
-            self.tile, self.block_n, self.compute_dtype)
+            self.tile, self.block_n, self.compute_dtype, kb=self.kb,
+            steps=jnp.asarray(self.steps).astype(jnp.float32))
 
     def info(self) -> dict:
         """The info dict `kernels.ops.spamm_matmul` has always returned.
 
         `nvalid` is the per-(i, j) valid-k count (the paper's validNum). The
         compacted copy is reused when the planner built one; traced bitmap
-        plans get the same counts summed from the mask.
+        plans get the same counts summed from the mask. `kb` is the k-tiles
+        a kernel step covers, and `block_fill` the share of the steps'
+        kb-tile k-blocks that survive the gate (1.0 with no step).
         """
         nvalid = self.nvalid
         if nvalid is None:
@@ -522,7 +563,26 @@ class SpammPlan:
             "valid_tiles": self.valid_tiles,
             "total_tiles": self.total_tiles,
             "valid_fraction": self.valid_fraction,
+            "kb": self.kb,
+            "block_fill": _fill(self.valid_tiles, self.steps, self.kb),
         }
+
+
+def _fill(valid, steps, kb: int) -> jax.Array:
+    """Surviving tile products over the `steps · kb` a blocked work-list
+    covers (1.0 where it covers none)."""
+    steps = jnp.asarray(steps, jnp.float32)
+    return jnp.where(steps > 0, valid / jnp.maximum(steps * kb, 1.0), 1.0)
+
+
+def _step_subtiles(step_k, flags, kb: int):
+    """((S, kb) k-tile, (S, kb) survives) of a step table: at kb = 1 the
+    ACC bit marks the step's one k-tile; above it the sub-tile bits do."""
+    if kb == 1:
+        return step_k[:, None], ((flags & STEP_ACC) != 0)[:, None]
+    c = jnp.arange(kb, dtype=step_k.dtype)
+    return (step_k[:, None] * kb + c,
+            ((flags[:, None] >> (STEP_SUB + c)) & 1) != 0)
 
 
 # ---------------------------------------------------------------------------
@@ -731,7 +791,8 @@ def _side_pyramid(norm, x, levels: int, tile: int, bk, use_mxu: bool,
 def _frozen_step_flags(fp, active: jax.Array) -> jax.Array:
     """Traced INIT/ACC/FLUSH flags over a FrozenPlan's static step tables.
 
-    `active` is the traced per-step activation gate (already AND step_real).
+    `active` is the traced per-step activation gate (already AND step_real):
+    at kb > 1 a step is active when any of its k-tiles passes.
     Pure static-shape cumsum/gather arithmetic: INIT fires on a segment's
     first active step, FLUSH on its last; a segment with NO active step gets
     one forced INIT|FLUSH (no ACC) at its final step so its visited output
@@ -806,23 +867,33 @@ def _plan_frozen(a, fp, *, norm_a=None, use_mxu_norm: bool = False
             f"activation grid, got ({gm}, {gk}) — rebuild with "
             f"for_rows({gm})")
     tau = jnp.asarray(fp.tau, jnp.float32)
-    # the traced activation gate: exact flat τ-test per frozen step (the
-    # super-column max commutes with the gate — fp32 multiply is monotone
-    # in each non-negative factor), restricted to real (non-padding) steps
-    pa = norm_a[fp.step_i, fp.step_k]
-    pb = fp.nbmax[fp.step_k, fp.step_j]
-    active = fp.step_real & (pa * pb >= tau)
+    # the traced activation gate: exact flat τ-test per k-tile of each
+    # frozen step (the super-column max commutes with the gate — fp32
+    # multiply is monotone in each non-negative factor), restricted to real
+    # (non-padding) steps. A k-tile whose weight tile no activation can pass
+    # fails the same test, so k-blocks need no admissibility table.
+    kb = fp.kb
+    ks = fp.step_k[:, None] * kb + jnp.arange(kb, dtype=jnp.int32)
+    pa = norm_a[fp.step_i[:, None], ks]
+    pb = fp.nbmax[ks, fp.step_j[:, None]]
+    sub = fp.step_real[:, None] & (pa * pb >= tau)      # (S, kb)
+    active = jnp.any(sub, axis=1)
     flags = _frozen_step_flags(fp, active)
+    if kb > 1:
+        bits = sub.astype(jnp.int32) << (
+            STEP_SUB + jnp.arange(kb, dtype=jnp.int32))
+        flags = flags | jnp.sum(bits, axis=1, dtype=jnp.int32)
     work = SpammWork(rows=None, cols=None, offsets=None, klist=None,
                      step_i=fp.step_i, step_j=fp.step_j, step_k=fp.step_k,
                      step_flags=flags)
+    counts = jnp.sum(sub, axis=1, dtype=jnp.int32)
     nvalid = jnp.zeros((gm, fp.gnb), jnp.int32).at[fp.step_i, fp.step_j].add(
-        active.astype(jnp.int32))
-    valid_tiles = jnp.sum(active, dtype=jnp.int32)
+        counts)
+    valid_tiles = jnp.sum(counts, dtype=jnp.int32)
     return SpammPlan(tau, norm_a, fp.norm_b, None, None, nvalid, valid_tiles,
                      work, a_scale, getattr(fp, "b_scale", None),
                      tile=tile, block_n=fp.block_n, backend=bk.name,
-                     levels=fp.num_levels, compute_dtype=dtype)
+                     levels=fp.num_levels, compute_dtype=dtype, kb=kb)
 
 
 def plan(
@@ -1013,16 +1084,21 @@ def plan(
             # per-step tables only for backends that will execute the ragged
             # kernel; bitmap/dense-kidx backends never read them
             steps = bk.matmul_worklist is not None
-            if triples_grouped:
+            if not triples_grouped:
                 # the chunked nonzero scan emits triples in row-major (sorted
-                # fused-key) order with grouping already applied — skip the sort
-                work_np, nvalid_np = compact_from_triples(
-                    *triples, gm=gm, gn=gnb, gk=gk, block_n=1, steps=steps,
-                    assume_sorted=True, bucket_min=bucket_min)
-            else:
-                work_np, nvalid_np = compact_from_triples(
-                    *triples, gm=gm, gn=gn, gk=gk, block_n=block_n,
-                    steps=steps, bucket_min=bucket_min)
+                # fused-key) order with grouping already applied; the
+                # descent's need the sort
+                triples = _sort_triples(*triples, gn=gn, gk=gk,
+                                        block_n=block_n)
+            kb = 1
+            if steps:  # k-tiles per kernel step, priced on the triples
+                kb = kcost.choose_kb(
+                    *triples, gk=gk, tile=tile, block_n=block_n,
+                    dtype=compute_dtype, bucket_min=bucket_min,
+                    coeffs=kcost.CostProfile().coeffs(bk.name))
+            work_np, nvalid_np = compact_from_triples(
+                *triples, gm=gm, gn=gnb, gk=gk, block_n=1, steps=steps,
+                assume_sorted=True, bucket_min=bucket_min, kb=kb)
             valid_tiles = jnp.int32(int(work_np.klist.size))
             nvalid = jnp.asarray(nvalid_np)
             # dense kidx only for dense-grid kernels with no ragged entry point
@@ -1046,10 +1122,11 @@ def plan(
             valid_tiles = jnp.sum(mask, dtype=jnp.int32)
             kidx, nvalid = _maybe_compact(mask, bk.name)
             work = None
+            kb = 1
         return SpammPlan(tau, norm_a, norm_b, mask, kidx, nvalid, valid_tiles,
                          work, a_scale, b_scale, tile=tile, block_n=block_n,
                          backend=bk.name, levels=(want if hier else 0),
-                         compute_dtype=compute_dtype)
+                         compute_dtype=compute_dtype, kb=kb)
 
 
 def execute(p: SpammPlan, a: jax.Array, b: jax.Array, *, out_dtype=None):
@@ -1101,7 +1178,7 @@ def execute(p: SpammPlan, a: jax.Array, b: jax.Array, *, out_dtype=None):
     if p.work is not None and bk.matmul_worklist is not None:
         # ragged path: Σnvalid grid steps, dense mask never materialized
         return bk.matmul_worklist(a, b, p.work, p.tile, p.block_n,
-                                  out_dtype or jnp.float32)
+                                  out_dtype or jnp.float32, p.kb)
     return bk.matmul(a, b, p.mask, p.kidx, p.nvalid, p.tile, p.block_n,
                      out_dtype or jnp.float32)
 

@@ -14,6 +14,10 @@ refusal deep inside a jitted step:
   table, the int8 scale tables) live in SMEM, which holds 1 MiB on v5e. At
   8192³ and tile 128 the work-list's four step tables alone need 4 MiB;
   splitting such a work-list across several kernel calls is a ROADMAP item.
+* VMEM: the work-list kernel's blocks grow with the k-tiles one grid step
+  covers (`kb`). Its double-buffered blocks plus the f32 accumulator are
+  held to half of v5e's default scoped VMEM (16 MiB), so the planners'
+  choice of `kb` and the kernel agree on what fits.
 """
 from __future__ import annotations
 
@@ -26,6 +30,9 @@ SMEM_BYTES = 1 << 20
 # short by 1.1 KiB, and 63488 entries (32 KiB under 1 MiB) compiled; no
 # size between the two was tried, so tables in that gap are refused here
 SMEM_RESERVE = 32 << 10
+# half of v5e's 16 MiB default scoped VMEM: room for the compiler's own
+# buffers beside the work-list kernel's pipeline
+VMEM_BUDGET = 8 << 20
 
 
 def check_tile(tile: int) -> None:
@@ -48,6 +55,28 @@ def check_smem(name: str, *tables) -> None:
             f"compiler); this product has too many "
             f"surviving tile products for one kernel call (raise tau or the "
             f"tile, or split the operands)")
+
+
+def worklist_vmem_bytes(tile: int, kb: int, block_n: int, in_itemsize: int,
+                        out_itemsize: int = 4) -> int:
+    """VMEM the work-list kernel holds at one k-block width `kb`: the
+    double-buffered A (tile, kb·tile) and B (kb·tile, tile·block_n) blocks,
+    the double-buffered zero-seed and output blocks, and the f32
+    accumulator."""
+    tn = tile * block_n
+    a = tile * kb * tile * in_itemsize
+    b = kb * tile * tn * in_itemsize
+    seed_out = 2 * tile * tn * out_itemsize
+    return 2 * (a + b + seed_out) + tile * tn * 4
+
+
+def check_vmem(name: str, nbytes: int) -> None:
+    """Raise if a kernel's blocks need more than `VMEM_BUDGET`."""
+    if nbytes > VMEM_BUDGET:
+        raise ValueError(
+            f"{name}: its blocks need {nbytes} bytes of VMEM, over the "
+            f"{VMEM_BUDGET}-byte budget (half of a v5e core's default scoped "
+            f"VMEM); cover fewer k-tiles per grid step or a narrower block_n")
 
 
 def mesh_vma(*xs) -> frozenset:

@@ -63,10 +63,11 @@ class Backend:
       ⇒ the planner falls back to norms() + the jnp pooling oracle, so
       third-party backends registered before this entry point keep working.
     matmul_worklist(a, b, work, tile, block_n,
-                    out_dtype)                     → (M, N) out_dtype
+                    out_dtype, kb)                 → (M, N) out_dtype
       the ragged execution path: `work` is a `repro.core.plan.SpammWork`
-      (flattened per-(i, j) work-list with padded per-step tables) and the
-      grid is Σnvalid steps, not gm·gn·gk. None ⇒ the executor falls back
+      (flattened per-(i, j) work-list with padded per-step tables, each
+      step covering `kb` k-tiles) and the grid is the work-list's steps,
+      not gm·gn·gk. None ⇒ the executor falls back
       to `matmul` with the dense mask/kidx, so third-party backends keep
       working unchanged. bf16 execution needs NO separate entry point: the
       executor passes bf16 operands straight into `matmul_worklist`/`matmul`
@@ -154,10 +155,10 @@ def _pallas_matmul(interpret):
 
 
 def _pallas_matmul_worklist(interpret):
-    def matmul_worklist(a, b, work, tile, block_n, out_dtype):
+    def matmul_worklist(a, b, work, tile, block_n, out_dtype, kb=1):
         return _spamm_mm.spamm_mm_worklist(
             a, b, work.step_i, work.step_j, work.step_k, work.step_flags,
-            tile=tile, block_n=block_n, out_dtype=out_dtype,
+            tile=tile, block_n=block_n, kb=kb, out_dtype=out_dtype,
             interpret=interpret,
         )
 

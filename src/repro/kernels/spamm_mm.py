@@ -24,11 +24,15 @@ steps out.
 
 `spamm_mm_worklist` (ragged, the paper-faithful "iterate only valid
 products" form): a 1-D grid over the plan's flattened work-list — one grid
-step per surviving (i, j, k) triple, Σnvalid steps padded to a bucket
-instead of gm·gn·gk. Four scalar-prefetch tables (step_i/step_j/step_k/
-step_flags, built once by `repro.core.plan.compact_from_triples`) drive the
-BlockSpec index_maps; per-step flag bits init/accumulate/flush the VMEM
-accumulator at (i, j)-group boundaries. Output tiles with no valid product
+step per k-block of `kb` consecutive k-tiles that holds a surviving
+(i, j, k) triple, padded to a bucket instead of gm·gn·gk. Four
+scalar-prefetch tables (step_i/step_j/step_k/step_flags, built once by
+`repro.core.plan.compact_from_triples`) drive the BlockSpec index_maps;
+per-step flag bits init/accumulate/flush the VMEM accumulator at (i,
+j)-group boundaries and name the step's surviving k-tiles. A grid step has
+a fixed cost (about 0.35 µs on a v5e) that one 128³ tile dot does not
+cover, so the planners cover up to 16 k-tiles a step where the gate keeps
+them (`repro.core.cost.choose_kb`). Output tiles with no valid product
 are never visited — the out buffer aliases a zeros array so they stay
 exactly zero. Heavily-pruned products therefore stop paying masked-out grid
 steps entirely: execution cost is proportional to valid work, which is the
@@ -77,7 +81,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import check_smem, check_tile, mesh_vma, varying
+from repro.kernels.common import (check_smem, check_tile, check_vmem,
+                                  mesh_vma, varying, worklist_vmem_bytes)
 
 
 def _mxu_dot(a, b):
@@ -181,13 +186,19 @@ def spamm_mm(
 
 # step_flags bits (see repro.core.plan.compact_from_triples, which builds the
 # tables): INIT zeroes the accumulator (first step of an (i, j) group), ACC
-# performs the dot (every real step; bucket-padding steps have no bits set),
-# FLUSH writes the accumulator to the output tile (last step of a group).
+# marks a step with work (every real step; bucket-padding steps have no bits
+# set), FLUSH writes the accumulator to the output tile (last step of a
+# group). A step that covers kb > 1 k-tiles carries which of them survive
+# the gate in bits STEP_SUB .. STEP_SUB + kb - 1 (bit STEP_SUB + c: k-tile
+# c of the step's k-block); at kb = 1 ACC alone says it.
 STEP_INIT, STEP_ACC, STEP_FLUSH = 1, 2, 4
+STEP_SUB = 3
+KB_MAX = 16  # the sub-tile mask must fit the int32 flags beside bits 0-2
 
 
 def _spamm_mm_worklist_kernel(
-    si_ref, sj_ref, sk_ref, fl_ref, zero_ref, a_ref, b_ref, o_ref, acc_ref
+    si_ref, sj_ref, sk_ref, fl_ref, zero_ref, a_ref, b_ref, o_ref, acc_ref,
+    *, kb: int, tile: int,
 ):
     del zero_ref  # only aliased into o_ref so unvisited tiles stay zero
     s = pl.program_id(0)
@@ -197,12 +208,43 @@ def _spamm_mm_worklist_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # paper Alg. 2 line 19, taken literally: every grid step IS a valid
-    # product (bucket-padding steps revisit the last real blocks — free — and
-    # carry no flag bits, so they neither accumulate nor flush).
-    @pl.when((f & STEP_ACC) != 0)
-    def _compute():
-        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
+    # paper Alg. 2 line 19, taken literally: every grid step holds valid
+    # products (bucket-padding steps revisit the last real blocks — free —
+    # and carry no flag bits, so they neither accumulate nor flush)
+    if kb == 1:
+        @pl.when((f & STEP_ACC) != 0)
+        def _compute():
+            acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
+    else:
+        # one tile dot per surviving k-tile, in ascending k: the same f32
+        # additions in the same order as kb one-tile steps, so C is
+        # bit-identical to the kb = 1 kernel on the same gate. A full
+        # k-block runs unrolled and branch-free, which lets its dots
+        # overlap (0.19 µs a dot on a v5e against 0.27 through the
+        # per-k-tile branch); a partial one loops, which halves the
+        # kernel's compile time against unrolling that branch too
+        full = ((1 << kb) - 1) << STEP_SUB
+        bits = f & full
+
+        @pl.when(bits == full)
+        def _all():
+            acc = acc_ref[...]
+            for c in range(kb):
+                cols = slice(c * tile, (c + 1) * tile)
+                acc = acc + _mxu_dot(a_ref[:, cols], b_ref[cols, :])
+            acc_ref[...] = acc
+
+        @pl.when(bits != full)
+        def _some():
+            def one(c, carry):
+                @pl.when(((f >> (STEP_SUB + c)) & 1) != 0)
+                def _():
+                    at = pl.multiple_of(c * tile, tile)
+                    acc_ref[...] += _mxu_dot(a_ref[:, pl.ds(at, tile)],
+                                             b_ref[pl.ds(at, tile), :])
+                return carry
+
+            jax.lax.fori_loop(0, kb, one, 0)
 
     @pl.when((f & STEP_FLUSH) != 0)
     def _flush():
@@ -211,7 +253,7 @@ def _spamm_mm_worklist_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("tile", "out_dtype", "interpret", "block_n"),
+    static_argnames=("tile", "out_dtype", "interpret", "block_n", "kb"),
 )
 def spamm_mm_worklist(
     a: jax.Array,
@@ -223,61 +265,64 @@ def spamm_mm_worklist(
     *,
     tile: int = 64,
     block_n: int = 1,
+    kb: int = 1,
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged masked matmul: 1-D grid over the compacted work-list.
 
     a: (M, K); b: (K, N). step_i/step_j/step_k/step_flags: (S,) int32 tables,
-    one entry per surviving (i, j, k) product in (i, j)-grouped ascending-k
-    order, S = Σnvalid padded to a bucket (padding entries repeat the last
-    real triple with flags 0). Built by `repro.core.plan.compact_from_triples`
-    straight from the planner's surviving triples.
+    one entry per grid step in (i, j)-grouped ascending-k order, padded to a
+    bucket (padding entries repeat the last real step with flags 0). Built
+    by `repro.core.plan.compact_from_triples` straight from the planner's
+    surviving triples, or by `repro.plans.frozen` for frozen plans.
+
+    `kb` k-tiles per grid step: `step_k` is then a k-block id, the step
+    multiplies the (tile, kb·tile) A block by the (kb·tile, tile·block_n) B
+    block one tile dot at a time, and the flags' sub-tile bits (STEP_SUB)
+    say which of the kb tile dots survive. K must split into whole k-blocks.
 
     `step_j` is a super-column id when block_n > 1 (each grid step computes a
     (tile, tile·block_n) output block). The grid has length S, NOT gm·gn·gk —
     pruned products cost nothing, and output tiles with no valid k stay zero
     via the aliased zero-initialized output. f32 accumulation in ascending-k
-    order makes the result bit-identical to `spamm_mm` on the same mask.
-    Returns C: (M, N) in out_dtype.
+    order makes the result bit-identical to `spamm_mm` on the same mask, at
+    every kb. Returns C: (M, N) in out_dtype.
     """
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
-    assert m % tile == 0 and k % tile == 0 and n % (tile * block_n) == 0, (
-        a.shape, b.shape, tile, block_n)
+    assert m % tile == 0 and k % (tile * kb) == 0 and n % (tile * block_n) == 0, (
+        a.shape, b.shape, tile, kb, block_n)
+    assert 1 <= kb <= KB_MAX, kb
     s = step_i.shape[0]
     assert step_j.shape == step_k.shape == step_flags.shape == (s,)
     if not interpret:
         check_tile(tile)
         check_smem("spamm_mm_worklist", step_i, step_j, step_k, step_flags)
+        check_vmem("spamm_mm_worklist", worklist_vmem_bytes(
+            tile, kb, block_n, a.dtype.itemsize, jnp.dtype(out_dtype).itemsize))
 
+    tk, tn = tile * kb, tile * block_n
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(s,),
         in_specs=[
             # zero output seed — same index map as the output so the aliased
             # HBM buffer is simply revisited
-            pl.BlockSpec(
-                (tile, tile * block_n),
-                lambda s, si, sj, sk, fl: (si[s], sj[s]),
-            ),
-            pl.BlockSpec(
-                (tile, tile), lambda s, si, sj, sk, fl: (si[s], sk[s])
-            ),
-            pl.BlockSpec(
-                (tile, tile * block_n),
-                lambda s, si, sj, sk, fl: (sk[s], sj[s]),
-            ),
+            pl.BlockSpec((tile, tn), lambda s, si, sj, sk, fl: (si[s], sj[s])),
+            # A's block index holds across the consecutive j of a row tile
+            # when K is one k-block, and Pallas then skips its re-fetch
+            pl.BlockSpec((tile, tk), lambda s, si, sj, sk, fl: (si[s], sk[s])),
+            pl.BlockSpec((tk, tn), lambda s, si, sj, sk, fl: (sk[s], sj[s])),
         ],
         out_specs=pl.BlockSpec(
-            (tile, tile * block_n), lambda s, si, sj, sk, fl: (si[s], sj[s])
-        ),
-        scratch_shapes=[pltpu.VMEM((tile, tile * block_n), jnp.float32)],
+            (tile, tn), lambda s, si, sj, sk, fl: (si[s], sj[s])),
+        scratch_shapes=[pltpu.VMEM((tile, tn), jnp.float32)],
     )
     vma = mesh_vma(a, b, step_i, step_j, step_k, step_flags)
     return pl.pallas_call(
-        _spamm_mm_worklist_kernel,
+        functools.partial(_spamm_mm_worklist_kernel, kb=kb, tile=tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
         # index 4 counts the scalar-prefetch tables: the zeros operand seeds

@@ -13,9 +13,11 @@ into two pytrees that compiled prefill/decode consume as *data*:
     what `PlanStore` serializes and `WeightPlanCache` memoizes.
   * `FrozenPlan` — `FrozenWeight.for_rows(gm)`: the artifact specialized to
     an activation row grid, carrying the `SpammWork`-style step tables
-    (pair-major, ascending k, bucket-padded) plus the per-step segment
-    index tables that let a *traced* activation gate compute the
-    INIT/ACC/FLUSH flags with static shapes. Passed as a jit argument, it
+    (pair-major, ascending k, bucket-padded, each step a k-block of `kb`
+    k-tiles chosen by the cost model from the weight's admissible pairs)
+    plus the per-step segment index tables that let a *traced* activation
+    gate compute the INIT/ACC/FLUSH flags with static shapes. Passed as a
+    jit argument, it
     makes the concrete work-list path the only executed path: the compiled
     graph contains the activation-side get-norm and an O(S) gather-compare —
     zero weight-side get-norm ops and zero dense-bitmap sorts.
@@ -35,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import cost as kcost
 from repro.core.cost import TunedParams
 from repro.core.plan import NormPyramid, _bucket, pad_to_tile
 from repro.kernels import ops as kops
@@ -104,6 +107,8 @@ class FrozenWeight:
         self.compute_dtype = compute_dtype
         self.tuned = tuned
         self._rows_cache: dict = {}
+        self._kb_cache: dict = {}     # width → chosen kb
+        self._blocks: dict = {}       # kb → one row tile's steps
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
@@ -230,8 +235,55 @@ class FrozenWeight:
             compute_dtype=compute_dtype, tuned=tuned,
         )
 
+    # -- k-blocking -----------------------------------------------------------
+    def _row_steps(self, kb: int):
+        """One row tile's steps at `kb` k-tiles a step: (j, k-block) over
+        the admissible pairs, pair-major, ascending k-block."""
+        hit = self._blocks.get(kb)
+        if hit is None:
+            kj_k = np.asarray(self.kj_k, np.int32)
+            kj_j = np.asarray(self.kj_j, np.int32)
+            hit = kcost.block_steps(np.zeros_like(kj_j), kj_j, kj_k, kb)[1:3]
+            self._blocks[kb] = hit
+        return hit
+
+    def real_steps(self, width: int, kb: int) -> int:
+        """Real (non-padding) steps of a `width`-row-tile plan at `kb`."""
+        return width * int(self._row_steps(kb)[0].size)
+
+    def block_fill(self, kb: int) -> float:
+        """Admissible tile products over the `steps · kb` the blocked
+        tables cover — the kernel's fill when every activation tile
+        passes (1.0 with no step)."""
+        n = self._row_steps(kb)[0].size
+        return self.num_kj / (n * kb) if n else 1.0
+
+    def choose_kb(self, width: int) -> int:
+        """k-tiles per kernel step for a `width`-row-tile plan: the cost
+        model's argmin (`core.cost.choose_kb`) over the admissible triples,
+        priced with an all-pass activation as `core.cost.tune` prices
+        frozen plans. 1 on backends without a work-list kernel."""
+        hit = self._kb_cache.get(width)
+        if hit is not None:
+            return hit
+        bk = kops.get_backend(self.backend)
+        kb = 1
+        if bk.matmul_worklist is not None and self.num_kj:
+            kj_k = np.asarray(self.kj_k, np.int32)
+            kj_j = np.asarray(self.kj_j, np.int32)
+            gk, _ = self.grid
+            kb = kcost.choose_kb(
+                np.repeat(np.arange(width, dtype=np.int32), kj_k.size),
+                np.tile(kj_j, width), np.tile(kj_k, width), gk=gk,
+                tile=self.tile, block_n=self.block_n,
+                dtype=self.compute_dtype, bucket_min=self.bucket_floor,
+                coeffs=kcost.CostProfile().coeffs(bk.name))
+        self._kb_cache[width] = kb
+        return kb
+
     # -- shape specialization -----------------------------------------------
-    def for_rows(self, gm: int, *, min_steps: int = 0) -> "FrozenPlan":
+    def for_rows(self, gm: int, *, min_steps: int = 0,
+                 kb: Optional[int] = None) -> "FrozenPlan":
         """Specialize to an activation row grid of `gm` tiles.
 
         Emits the step tables pair-major ((i, j) runs contiguous, k
@@ -240,18 +292,21 @@ class FrozenWeight:
         `bucket_floor`) — the floor is the autotuned per-weight bucket when
         present; pass a common `min_steps` when plans of several weights
         must stack into one scan input. Padding steps repeat the last real
-        triple with the `real` bit clear, so the traced gate can never
-        activate them. Cached per (gm, bucket).
+        step with the `real` bit clear, so the traced gate can never
+        activate them. Each step covers `kb` k-tiles (default: this
+        weight's `choose_kb(gm)`; pass a common `kb` when plans must
+        stack). Cached per (gm, bucket, kb).
 
         Shape-bucketed serving leans on this cache: the engine rounds its
         slot pool to a power of two (`cost.bucket`), so a sweep of
         arbitrary batch shapes resolves to at most
         `len(cost.bucket_ladder(max_batch, 1))` distinct `gm` values —
         O(buckets) specializations and jit traces, not O(shapes)."""
-        return self._specialize(gm, gm, min_steps)
+        return self._specialize(gm, gm, min_steps, kb)
 
     def slice_rows(self, lo: int, hi: int, *, gm: Optional[int] = None,
-                   min_steps: int = 0) -> "FrozenPlan":
+                   min_steps: int = 0, kb: Optional[int] = None
+                   ) -> "FrozenPlan":
         """The per-shard plan of row-tile strip [lo, hi) on a LOCAL grid of
         `gm` tiles (≥ the strip width; default = the width) — what a
         shard_map'd step consumes when a variable-width row partition
@@ -266,7 +321,8 @@ class FrozenWeight:
         step targets them (`real` is clear beyond the strip's steps), so
         pad rows do ZERO gated work — the per-shard work difference IS the
         load-balance mechanism. Pass a common `min_steps` bucket (computed
-        at the PADDED width) so per-shard plans of one weight stack; built
+        at the PADDED width) and a common `kb` (default: `choose_kb` at the
+        padded width) so per-shard plans of one weight stack; built
         host-side at re-shard time, never in-trace."""
         if not 0 <= lo <= hi:
             raise ValueError(f"bad row strip [{lo}, {hi})")
@@ -275,7 +331,8 @@ class FrozenWeight:
         if gm < width:
             raise ValueError(
                 f"local grid {gm} smaller than strip width {width}")
-        return self._specialize(width, gm, min_steps)
+        return self._specialize(width, gm, min_steps,
+                                self.choose_kb(gm) if kb is None else kb)
 
     def shard_by_offsets(self, offsets, *, width: Optional[int] = None,
                          min_steps: int = 0) -> "FrozenPlan":
@@ -288,8 +345,8 @@ class FrozenWeight:
         `width` fixes the common local grid (≥ the widest strip; default =
         the widest strip): the engine pins it per wave so every re-cut
         yields identical shapes (recompile-free swap). All shards share one
-        step bucket computed at the padded width, so their static metadata
-        is identical by construction."""
+        step bucket and one kb, both fixed at the padded width, so their
+        static metadata is identical by construction."""
         offs = np.asarray(offsets, np.int64)
         if offs.ndim != 1 or offs.shape[0] < 2 or offs[0] != 0 \
                 or np.any(np.diff(offs) < 1):
@@ -300,55 +357,33 @@ class FrozenWeight:
                 raise ValueError(
                     f"fixed width {width} < widest strip {wmax}")
             wmax = int(width)
-        bucket = _bucket(max(wmax * self.num_kj, min_steps),
+        kb = self.choose_kb(wmax)
+        bucket = _bucket(max(self.real_steps(wmax, kb), min_steps),
                          self.bucket_floor)
         shards = [
             self.slice_rows(int(offs[d]), int(offs[d + 1]), gm=wmax,
-                            min_steps=bucket)
+                            min_steps=bucket, kb=kb)
             for d in range(offs.shape[0] - 1)
         ]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *shards)
 
-    def _specialize(self, width: int, gm: int, min_steps: int) -> "FrozenPlan":
+    def _specialize(self, width: int, gm: int, min_steps: int,
+                    kb: Optional[int] = None) -> "FrozenPlan":
         """Shared body of `for_rows` (width == gm) and `slice_rows` (width ≤
         gm: real steps cover local tiles [0, width), tiles beyond are
         untargeted clamp padding)."""
         gk, gnb = self.grid
-        w = self.num_kj
-        s_real = width * w
+        kb = self.choose_kb(gm) if kb is None else kb
+        j, kblk = self._row_steps(kb)
+        s_real = width * j.size
         s = _bucket(max(s_real, min_steps), self.bucket_floor)
-        key = (width, gm, s)
+        key = (width, gm, s, kb)
         hit = self._rows_cache.get(key)
         if hit is not None:
             return hit
-        kj_k = np.asarray(self.kj_k, np.int32)
-        kj_j = np.asarray(self.kj_j, np.int32)
-        if s_real:
-            step_i = np.repeat(np.arange(width, dtype=np.int32), w)
-            step_j = np.tile(kj_j, width)
-            step_k = np.tile(kj_k, width)
-            pad = s - s_real
-            if pad:
-                step_i = np.concatenate([step_i, np.full(pad, step_i[-1])])
-                step_j = np.concatenate([step_j, np.full(pad, step_j[-1])])
-                step_k = np.concatenate([step_k, np.full(pad, step_k[-1])])
-        else:
-            step_i = np.zeros(s, np.int32)
-            step_j = np.zeros(s, np.int32)
-            step_k = np.zeros(s, np.int32)
-        step_real = np.zeros(s, bool)
-        step_real[:s_real] = True
-        # segment (= output pair) runs over the PADDED tables: padding
-        # repeats the last real (i, j), so it merges into the final run and
-        # the in-trace flag arithmetic needs no special cases
-        pair = step_i.astype(np.int64) * gnb + step_j
-        new = np.ones(s, bool)
-        new[1:] = pair[1:] != pair[:-1]
-        starts = np.flatnonzero(new)
-        counts = np.diff(np.append(starts, s))
-        ends = np.append(starts[1:], s) - 1
-        seg_first = np.repeat(starts, counts).astype(np.int32)
-        seg_last = np.repeat(ends, counts).astype(np.int32)
+        tables = _step_tables(
+            np.repeat(np.arange(width, dtype=np.int32), j.size),
+            np.tile(j, width), np.tile(kblk, width), s, gnb)
         # the FrozenPlan's tau is the GATE threshold: for low-precision
         # artifacts that is the quantization-widened τ' ≤ τ, so the traced
         # gate over quantized norms keeps a superset of the f32-gated set
@@ -357,19 +392,44 @@ class FrozenWeight:
             float(np.asarray(self.tau)), self.compute_dtype, self.tile)
         fp = FrozenPlan(
             jnp.asarray(gate_tau, jnp.float32), self.levels[0], self.nbmax,
-            jnp.asarray(step_i.astype(np.int32)),
-            jnp.asarray(step_j.astype(np.int32)),
-            jnp.asarray(step_k.astype(np.int32)),
-            jnp.asarray(step_real),
-            jnp.asarray(seg_first), jnp.asarray(seg_last),
+            *(jnp.asarray(t) for t in tables),
             self.b_scale,
             tile=self.tile, block_n=self.block_n, num_levels=self.num_levels,
             backend=self.backend, gm=gm, gk=gk, gnb=gnb,
             wshape=self.wshape, version=self.version,
-            compute_dtype=self.compute_dtype,
+            compute_dtype=self.compute_dtype, kb=kb,
         )
         self._rows_cache[key] = fp
         return fp
+
+
+def _step_tables(step_i, step_j, step_k, s: int, gnb: int):
+    """A FrozenPlan's step tables from its real steps, padded to `s`:
+    (step_i, step_j, step_k, step_real, seg_first, seg_last) numpy."""
+    s_real = step_i.size
+    if s_real:
+        pad = s - s_real
+        step_i, step_j, step_k = (
+            np.concatenate([t, np.full(pad, t[-1])]).astype(np.int32)
+            for t in (step_i, step_j, step_k))
+    else:
+        step_i = np.zeros(s, np.int32)
+        step_j = np.zeros(s, np.int32)
+        step_k = np.zeros(s, np.int32)
+    step_real = np.zeros(s, bool)
+    step_real[:s_real] = True
+    # segment (= output pair) runs over the PADDED tables: padding
+    # repeats the last real (i, j), so it merges into the final run and
+    # the in-trace flag arithmetic needs no special cases
+    pair = step_i.astype(np.int64) * gnb + step_j
+    new = np.ones(s, bool)
+    new[1:] = pair[1:] != pair[:-1]
+    starts = np.flatnonzero(new)
+    counts = np.diff(np.append(starts, s))
+    ends = np.append(starts[1:], s) - 1
+    seg_first = np.repeat(starts, counts).astype(np.int32)
+    seg_last = np.repeat(ends, counts).astype(np.int32)
+    return step_i, step_j, step_k, step_real, seg_first, seg_last
 
 
 @jax.tree_util.register_pytree_node_class
@@ -384,7 +444,9 @@ class FrozenPlan:
       nbmax        (gk, gnb) per-super-column max norms — the traced gate's
                    weight half
       step_i/j/k   (S,) int32 — pair-major ascending-k step tables over ALL
-                   weight-admissible (i, j, k); S = gm·W bucket-padded
+                   weight-admissible (i, j, k-block): one step per k-block
+                   of `kb` k-tiles that holds an admissible pair (kb = 1:
+                   one per pair, S = gm·W), bucket-padded
       step_real    (S,) bool — clear on bucket padding steps
       seg_first/seg_last (S,) int32 — index of the first/last step of each
                    step's (i, j) segment: what lets the traced activation
@@ -399,8 +461,9 @@ class FrozenPlan:
     FrozenWeight / in the store address).
 
     Static metadata (aux): tile, block_n, num_levels, backend, gm, gk, gnb,
-    wshape, version, compute_dtype. Leading batch dims on every child are
-    allowed (stacked per-layer plans riding a lax.scan — see `stack_plans`).
+    wshape, version, compute_dtype, kb. Leading batch dims on every child
+    are allowed (stacked per-layer plans riding a lax.scan — see
+    `stack_plans`).
     """
 
     def __init__(self, tau, norm_b, nbmax, step_i, step_j, step_k, step_real,
@@ -408,7 +471,7 @@ class FrozenPlan:
                  block_n: int, num_levels: int, backend: str, gm: int,
                  gk: int, gnb: int, wshape: Tuple[int, int],
                  version: int = PLAN_FORMAT_VERSION,
-                 compute_dtype: str = "float32"):
+                 compute_dtype: str = "float32", kb: int = 1):
         self.tau = tau
         self.norm_b = norm_b
         self.nbmax = nbmax
@@ -429,6 +492,7 @@ class FrozenPlan:
         self.wshape = tuple(wshape)
         self.version = version
         self.compute_dtype = compute_dtype
+        self.kb = kb
 
     def tree_flatten(self):
         children = (self.tau, self.norm_b, self.nbmax, self.step_i,
@@ -436,20 +500,56 @@ class FrozenPlan:
                     self.seg_last, self.b_scale)
         aux = (self.tile, self.block_n, self.num_levels, self.backend,
                self.gm, self.gk, self.gnb, self.wshape, self.version,
-               self.compute_dtype)
+               self.compute_dtype, self.kb)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         (tile, block_n, num_levels, backend, gm, gk, gnb, wshape, ver,
-         dtype) = aux
+         dtype, kb) = aux
         return cls(*children, tile=tile, block_n=block_n,
                    num_levels=num_levels, backend=backend, gm=gm, gk=gk,
-                   gnb=gnb, wshape=wshape, version=ver, compute_dtype=dtype)
+                   gnb=gnb, wshape=wshape, version=ver, compute_dtype=dtype,
+                   kb=kb)
 
     @property
     def num_steps(self) -> int:
         return self.step_i.shape[-1]
+
+    def _real_steps_at(self, kb: int):
+        """This (unstacked, concrete) plan's real steps re-cut to `kb`
+        k-tiles a step, kb dividing self.kb: each k-block splits into
+        self.kb // kb, kept where it holds a weight-admissible k-tile — the
+        rule `FrozenWeight.build` froze (τ > 0: a nonzero weight norm), so
+        the result equals the weight's own tables at `kb`."""
+        real = np.asarray(self.step_real)
+        si, sj, sk = (np.asarray(t)[real] for t in
+                      (self.step_i, self.step_j, self.step_k))
+        r = self.kb // kb
+        assert r * kb == self.kb, (self.kb, kb)
+        si, sj = np.repeat(si, r), np.repeat(sj, r)
+        sk = (sk[:, None] * r + np.arange(r)).reshape(-1)
+        if float(np.asarray(self.tau)) > 0.0:
+            ks = sk[:, None] * kb + np.arange(kb)
+            nb = np.asarray(self.nbmax)
+            keep = (nb[ks, sj[:, None]] > 0.0).any(1)
+            si, sj, sk = si[keep], sj[keep], sk[keep]
+        return si, sj, sk
+
+    def restep(self, kb: int, num_steps: int) -> "FrozenPlan":
+        """This plan at `kb` k-tiles a step (dividing its own), padded to
+        `num_steps` — how `stack_plans` brings layers to one kb and one
+        bucket. Host-side, on a concrete unstacked plan."""
+        if kb == self.kb and num_steps == self.num_steps:
+            return self
+        si, sj, sk = self._real_steps_at(kb)
+        if si.size > num_steps:
+            raise ValueError(f"{si.size} steps do not fit {num_steps}")
+        tables = _step_tables(si, sj, sk, num_steps, self.gnb)
+        children = list(self.tree_flatten()[0])
+        children[3:9] = [jnp.asarray(t) for t in tables]
+        aux = self.tree_flatten()[1][:-1] + (kb,)  # kb is aux's last
+        return FrozenPlan.tree_unflatten(aux, children)
 
 
 def freeze_weight(w, tau, *, tile: int = 64, block_n: int = 1,
@@ -466,11 +566,20 @@ def freeze_weight(w, tau, *, tile: int = 64, block_n: int = 1,
 
 def stack_plans(fps) -> FrozenPlan:
     """Stack per-layer FrozenPlans (same static metadata, same bucket — use
-    `for_rows(gm, min_steps=...)` with a common bucket) into ONE plan whose
-    children carry a leading layer dim: the shape lax.scan slices per step,
-    which is how frozen plans ride a scanned-layer prefill."""
+    `for_rows(gm, min_steps=..., kb=...)` with a common bucket and kb) into
+    ONE plan whose children carry a leading layer dim: the shape lax.scan
+    slices per step, which is how frozen plans ride a scanned-layer prefill.
+
+    Layers whose kb differ are first re-cut to the smallest of them and
+    padded to one bucket (`FrozenPlan.restep`): one kernel runs the scan."""
     fps = list(fps)
     assert fps, "stack_plans of nothing"
+    kb = min(fp.kb for fp in fps)
+    if any(fp.kb != kb for fp in fps):
+        steps = [fp._real_steps_at(kb)[0].size for fp in fps]
+        s = max(max(_bucket(n, 1) for n in steps),
+                max(fp.num_steps for fp in fps))
+        fps = [fp.restep(kb, s) for fp in fps]
     aux0 = fps[0].tree_flatten()[1]
     for fp in fps[1:]:
         assert fp.tree_flatten()[1] == aux0, (

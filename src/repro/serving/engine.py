@@ -569,21 +569,52 @@ class Engine:
             if isinstance(node, dict):
                 return {k: specialize(v) for k, v in node.items()}
             if isinstance(node, list):
-                # per-layer plans must share one step bucket to stack into a
-                # scan input; padding steps carry a clear `real` bit. Each
-                # weight's autotuned bucket floor participates in the max, so
-                # the common bucket honors every layer's tuned floor (the
-                # result is a power of two ≥ each floor, hence stable under
-                # every layer's own for_rows flooring).
-                bucket = max(_bucket(gm * fw.num_kj, fw.bucket_floor)
+                # per-layer plans must share one kb (the smallest any layer
+                # chose) and one step bucket to stack into a scan input;
+                # padding steps carry a clear `real` bit. Each weight's
+                # autotuned bucket floor participates in the max, so the
+                # common bucket honors every layer's tuned floor (the result
+                # is a power of two ≥ each floor, hence stable under every
+                # layer's own for_rows flooring).
+                kb = min(fw.choose_kb(gm) for fw in node)
+                bucket = max(_bucket(fw.real_steps(gm, kb), fw.bucket_floor)
                              for fw in node)
                 return stack_plans(
-                    [fw.for_rows(gm, min_steps=bucket) for fw in node])
+                    [fw.for_rows(gm, min_steps=bucket, kb=kb) for fw in node])
             return node.for_rows(gm)
 
         tree = specialize(self._fw_tree)
+        self._note_blocking(tree, gm)
         self._fp_cache[gm] = tree
         return tree
+
+    def _note_blocking(self, tree: dict, gm: int):
+        """Per gated-GEMM site of a freshly specialized plan tree: the k-tiles
+        a kernel step covers and the share of those steps' tile products
+        that the frozen tables hold (`spamm_kb`, `spamm_block_fill`)."""
+        if not self.obs.enabled:
+            return
+        reg = self.obs.registry
+        g_kb = reg.gauge("spamm_kb", labelnames=("site", "gm"),
+                         help="k-tiles one work-list kernel step covers")
+        g_fill = reg.gauge(
+            "spamm_block_fill", labelnames=("site", "gm"),
+            help="frozen tile products over the k-blocks the steps cover")
+
+        def walk(fws, fps, path):
+            if isinstance(fws, dict):
+                for k in fws:
+                    walk(fws[k], fps[k], path + (k,))
+                return
+            layers = fws if isinstance(fws, list) else [fws]
+            kb = fps.kb
+            fill = sum(fw.num_kj for fw in layers) / max(
+                sum(fw.real_steps(1, kb) for fw in layers) * kb, 1)
+            site = "/".join(path)
+            g_kb.set(kb, site=site, gm=str(gm))
+            g_fill.set(fill, site=site, gm=str(gm))
+
+        walk(self._fw_tree, tree, ())
 
     def _ensure_fw_tree(self):
         """Freeze the weight-side gating artifacts once (warm-started from
@@ -710,15 +741,17 @@ class Engine:
                 # same cross-layer common-bucket rule as `_frozen_for`, but
                 # computed at the PADDED width so every shard — and every
                 # future cut at this width — lands on one step count
-                bucket = max(_bucket(W * fw.num_kj, fw.bucket_floor)
+                kb = min(fw.choose_kb(W) for fw in node)
+                bucket = max(_bucket(fw.real_steps(W, kb), fw.bucket_floor)
                              for fw in node)
                 shards = [stack_plans([fw.slice_rows(
-                    int(offs[d]), int(offs[d + 1]), gm=W, min_steps=bucket)
-                    for fw in node]) for d in range(ndev)]
+                    int(offs[d]), int(offs[d + 1]), gm=W, min_steps=bucket,
+                    kb=kb) for fw in node]) for d in range(ndev)]
                 return jax.tree.map(lambda *xs: jnp.stack(xs), *shards)
             return node.shard_by_offsets(offs, width=W)
 
         tree = specialize(self._fw_tree)
+        self._note_blocking(tree, W)
         self._sfp_cache[key] = tree
         return tree
 

@@ -56,17 +56,20 @@ def test_predicted_bytes_equal_plan_bytes_moved(dtype, block_n, levels):
                 backend="interpret", compute_dtype=dtype)
     # the plan stores the WIDENED τ and the quantized-view normmaps — the
     # exact inputs the gate ran on, so the model must reproduce it exactly
+    # at the k-tiles a step the plan chose
     counts = cost.predict_counts(
         _flat(p.norm_a), _flat(p.norm_b), float(p.tau), tile=TILE,
-        block_n=block_n, dtype=dtype, levels=levels, mode="eager")
+        block_n=block_n, dtype=dtype, levels=levels, mode="eager", kb=p.kb)
     assert counts.steps_real == int(p.valid_tiles)
+    assert counts.steps_grid == p.work.step_i.shape[0]
     assert counts.gemm_bytes == pytest.approx(float(p.bytes_moved()), rel=0,
                                               abs=0.5)
     # and the formula itself is shared, not duplicated
     pairs = int(np.sum(np.asarray(p.nvalid) > 0))
     assert counts.pairs == pairs
     assert counts.gemm_bytes == cost.gemm_bytes(
-        counts.steps_real, pairs, TILE, block_n, dtype)
+        counts.steps_real, pairs, TILE, block_n, dtype, kb=p.kb,
+        steps=int(p.steps))
 
 
 def test_gemm_bytes_dtype_itemsize_aware():
@@ -83,7 +86,7 @@ def test_bucket_min_threads_through_plan():
     p16 = pl.plan(a, b, TAU, tile=TILE, backend="interpret")
     p256 = pl.plan(a, b, TAU, tile=TILE, backend="interpret",
                    bucket_min=256)
-    assert p16.work.step_i.shape[0] == cost.bucket(int(p16.valid_tiles))
+    assert p16.work.step_i.shape[0] == cost.bucket(int(p16.steps))
     assert p256.work.step_i.shape[0] == 256
     np.testing.assert_array_equal(np.asarray(pl.execute(p16, a, b)),
                                   np.asarray(pl.execute(p256, a, b)))

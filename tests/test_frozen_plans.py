@@ -493,3 +493,121 @@ def test_store_refuses_pre_dtype_legacy_root(tmp_path):
     st2.put(fw)
     # and a third open of the now-populated, marked root still succeeds
     assert len(PlanStore(str(fresh))) == 1
+
+
+# ---------------------------------------------------------------------------
+# k-blocked frozen plans: kb k-tiles a step, bit-identical to kb = 1
+# ---------------------------------------------------------------------------
+
+def _kb_operands():
+    """A (96, 256) activation whose middle row tile is zero (its segments
+    have no surviving k-tile) and a (256, 128) weight: gk = 8 at tile 32."""
+    a = np.asarray(_decay(96, 256, 50)).copy()
+    a[32:64] = 0.0
+    return jnp.asarray(a), _decay(256, 128, 51)
+
+
+def _run_frozen(x, w, fp):
+    @jax.jit
+    def run(x_, w_, f):
+        p = pl.plan(x_, frozen_weight=f)
+        return pl.execute(p, x_, w_), p.mask, p.nvalid, p.work.step_flags
+
+    return run(x, w, fp)
+
+
+def _check_empty_segments(fp, flags):
+    """Every segment with no active step still writes its tile: INIT|FLUSH
+    and no ACC on its last step."""
+    flags = np.asarray(flags)
+    last = np.asarray(fp.seg_last)
+    acc = (flags & pl.STEP_ACC) != 0
+    for e in np.unique(last):
+        seg = last == e
+        if not acc[seg].any():
+            assert flags[e] == pl.STEP_INIT | pl.STEP_FLUSH
+    return int((~np.array([acc[last == e].any()
+                           for e in np.unique(last)])).sum())
+
+
+@pytest.mark.parametrize("kb", [2, 4, 8])
+def test_frozen_kb_gates_and_multiplies_like_kb1(kb):
+    x, w = _kb_operands()
+    fw = FrozenWeight.build(w, TAU, tile=32, backend="interpret")
+    one, blk = fw.for_rows(3, kb=1), fw.for_rows(3, kb=kb)
+    assert (one.kb, blk.kb) == (1, kb)
+    assert blk.num_steps < one.num_steps
+    c1, m1, n1, _ = _run_frozen(x, w, one)
+    ck, mk, nk, fk = _run_frozen(x, w, blk)
+    np.testing.assert_array_equal(np.asarray(ck), np.asarray(c1))
+    np.testing.assert_array_equal(np.asarray(mk), np.asarray(m1))
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(n1))
+    assert _check_empty_segments(blk, fk) > 0  # the zero row tile's
+
+
+@pytest.mark.parametrize("kb", [2, 8])
+def test_frozen_kb_slice_rows_and_shards_like_kb1(kb):
+    """slice_rows at kb > 1 runs a shard's strip bit-identically to kb = 1,
+    and shard_by_offsets gives every shard one kb and one bucket."""
+    x, w = _kb_operands()
+    fw = FrozenWeight.build(w, TAU, tile=32, backend="interpret")
+    # strip [1, 3) on a local grid of 3 tiles, clamp-padded with its last
+    local = jnp.concatenate([x[32:96], x[64:96]])
+    c1 = _run_frozen(local, w, fw.slice_rows(1, 3, gm=3, kb=1))[0]
+    sl = fw.slice_rows(1, 3, gm=3, kb=kb)
+    ck, _, _, fk = _run_frozen(local, w, sl)
+    np.testing.assert_array_equal(np.asarray(ck), np.asarray(c1))
+    assert _check_empty_segments(sl, fk) > 0
+    sh = fw.shard_by_offsets(np.array([0, 1, 3]), width=3)
+    assert sh.kb == fw.choose_kb(3) and sh.kb > 1
+    for d, (lo, hi) in enumerate(((0, 1), (1, 3))):
+        part = jax.tree.map(lambda t: t[d], sh)
+        xs = x[lo * 32:hi * 32]
+        xs = jnp.concatenate([xs] + [xs[-32:]] * (3 - (hi - lo)))
+        want = _run_frozen(xs, w, fw.slice_rows(lo, hi, gm=3, kb=1))[0]
+        np.testing.assert_array_equal(
+            np.asarray(_run_frozen(xs, w, part)[0]), np.asarray(want))
+
+
+def test_stack_plans_brings_layers_to_the_smallest_kb():
+    """Layers whose kb differ stack at the smallest of them, on one bucket,
+    with each layer's tables exactly its weight's own at that kb; the scan
+    then gates and multiplies each layer as at kb = 1."""
+    x, _ = _kb_operands()
+    ws = [_decay(256, 128, s) for s in (60, 61)]
+    fws = [FrozenWeight.build(w_, TAU, tile=32, backend="interpret")
+           for w_ in ws]
+    st = stack_plans([fws[0].for_rows(3, kb=8), fws[1].for_rows(3, kb=2)])
+    assert st.kb == 2
+    for i, fw in enumerate(fws):
+        own = fw.for_rows(3, kb=2, min_steps=st.num_steps)
+        layer = jax.tree.map(lambda t: t[i], st)
+        for f in ("step_i", "step_j", "step_k", "step_real", "seg_first",
+                  "seg_last"):
+            np.testing.assert_array_equal(np.asarray(getattr(layer, f)),
+                                          np.asarray(getattr(own, f)))
+        want = _run_frozen(x, ws[i], fw.for_rows(3, kb=1))[0]
+        np.testing.assert_array_equal(
+            np.asarray(_run_frozen(x, ws[i], layer)[0]), np.asarray(want))
+
+
+def test_engine_reports_kb_and_block_fill_per_site():
+    """Freezing a row grid sets one `spamm_kb` and one `spamm_block_fill`
+    gauge per gated-GEMM site; at tau = 0 every k-block is full."""
+    cfg = get_config("musicgen-large").reduced()
+    ctx = make_ctx(make_host_mesh())
+    params = M.init_params(cfg, PCFG, jax.random.key(0))
+    sc = SpammConfig(enable=True, tau=0.0, tile=16, backend="interpret")
+    eng = _mk_engine(params, cfg, ctx, sc)
+    fps = eng._frozen_for(64)
+    reg = eng.obs.registry
+    g_kb, g_fill = (reg.gauge(name, labelnames=("site", "gm"))
+                    for name in ("spamm_kb", "spamm_block_fill"))
+    sites = ["/".join(p) for p, _ in iter_gated_weights(params)]
+    assert len(sites) == 6
+    for site in sites:
+        assert g_fill.value(site=site, gm="4") == 1.0
+        node = fps
+        for part in site.split("/"):
+            node = node[part]
+        assert g_kb.value(site=site, gm="4") == node.kb > 1

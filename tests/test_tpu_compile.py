@@ -71,6 +71,30 @@ def test_spamm_mm_worklist_compiles(one_chip, k, n, dtype):
              *_step_tables(one_chip, ROWS, k, n))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("k,n", KN[1:])
+def test_blocked_worklist_compiles(one_chip, k, n, dtype):
+    """16 k-tiles a step, the serving cells' choice at tau = 0: one step per
+    (i, j) for K = 2048, four for K = 8192."""
+    kb = 16
+    s = (ROWS // TILE) * (k // (TILE * kb)) * (n // TILE)
+    _compile(lambda a, b, *t: spamm_mm.spamm_mm_worklist(a, b, *t, tile=TILE,
+                                                         kb=kb),
+             _spec(one_chip, (ROWS, k), dtype), _spec(one_chip, (k, n), dtype),
+             *[_spec(one_chip, (s,), jnp.int32)] * 4)
+
+
+def test_vmem_oversized_block_raises_own_error(one_chip):
+    """16 f32 k-tiles a step at block_n 4 need 11.3 MiB of VMEM, over the
+    kernel's 8 MiB budget: the program says so before the compiler does."""
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.jit(lambda a, b, *t: spamm_mm.spamm_mm_worklist(
+            a, b, *t, tile=TILE, kb=16, block_n=4)).lower(
+            _spec(one_chip, (ROWS, 2048), jnp.float32),
+            _spec(one_chip, (2048, 8192), jnp.float32),
+            *[_spec(one_chip, (256,), jnp.int32)] * 4)
+
+
 @pytest.mark.parametrize("k,n", KN)
 def test_spamm_mm_worklist_int8_compiles(one_chip, k, n):
     f32 = jnp.float32

@@ -129,13 +129,17 @@ def test_worklist_step_tables_bucketed_and_flagged():
     p = pl.plan(a, b, TAU32, tile=32, backend="interpret")
     w = p.work
     s = w.step_i.shape[0]
-    assert s >= w.num_valid and (s & (s - 1)) == 0  # power-of-two bucket
+    steps = int(p.steps)  # one per k-block of p.kb k-tiles with work
+    assert s >= steps and (s & (s - 1)) == 0  # power-of-two bucket
     flags = np.asarray(w.step_flags)
-    assert np.all(flags[w.num_valid:] == 0)  # padding steps are inert
+    assert np.all(flags[steps:] == 0)  # padding steps are inert
     # each pair opens with INIT and closes with FLUSH exactly once
     assert np.sum((flags & smm.STEP_INIT) != 0) == w.num_pairs
     assert np.sum((flags & smm.STEP_FLUSH) != 0) == w.num_pairs
-    assert np.sum((flags & smm.STEP_ACC) != 0) == w.num_valid
+    assert np.sum((flags & smm.STEP_ACC) != 0) == steps
+    # and the steps' surviving k-tiles are the valid products
+    _, real = pl._step_subtiles(w.step_k, w.step_flags, p.kb)
+    assert int(np.sum(np.asarray(real))) == w.num_valid
 
 
 @pytest.mark.parametrize("block_n", [1, 2])
@@ -281,3 +285,119 @@ def test_spamm_bmm_odd_n_block_n(shared_w):
                            backend="jnp")
         np.testing.assert_allclose(np.asarray(c[i]), np.asarray(want),
                                    atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# k-blocked work-lists: kb k-tiles a grid step
+# ---------------------------------------------------------------------------
+
+KB_GM, KB_GN, KB_GK, KB_T = 2, 3, 16, 32
+
+
+def _kb_mask(kind):
+    """(gm, gn, gk) gates at super-column granularity: every kind leaves
+    some k-blocks partly filled or empty, the banded one also output pairs
+    with no surviving k-tile at all."""
+    shape = (KB_GM, KB_GN, KB_GK)
+    if kind == "empty":
+        return np.zeros(shape, bool)
+    if kind == "full":
+        return np.ones(shape, bool)
+    if kind == "random":
+        return np.random.default_rng(5).uniform(size=shape) < 0.1
+    i, j, k = np.indices(shape)
+    # a decay-like band around the diagonals of A (i, k) and B (k, j)
+    return (np.abs(k - 8 * i - 4) <= 3) & (np.abs(k - 4 * j - 5) <= 3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("block_n", [1, 2])
+@pytest.mark.parametrize("kind", ["empty", "full", "random", "banded"])
+@pytest.mark.parametrize("kb", [2, 4, 16])
+def test_blocked_worklist_bit_identical_to_kb1(kb, kind, block_n, dtype):
+    """The k-blocked kernel adds the same tile dots in the same ascending-k
+    order as one tile a step, so C is bit for bit the kb = 1 kernel's, with
+    bucket-padding steps (bucket_min) and unvisited output pairs."""
+    mask = _kb_mask(kind)
+    m, k = KB_GM * KB_T, KB_GK * KB_T
+    n = KB_GN * KB_T * block_n
+    rng = np.random.default_rng(kb)
+    a = jnp.asarray(rng.standard_normal((m, k)), dtype)
+    b = jnp.asarray(rng.standard_normal((k, n)), dtype)
+
+    def run(kb_):
+        w, _ = pl.compact_from_triples(*np.nonzero(mask), gm=KB_GM, gn=KB_GN,
+                                       gk=KB_GK, kb=kb_, bucket_min=64)
+        steps = [jnp.asarray(x) for x in
+                 (w.step_i, w.step_j, w.step_k, w.step_flags)]
+        return w, smm.spamm_mm_worklist(a, b, *steps, tile=KB_T,
+                                        block_n=block_n, kb=kb_,
+                                        interpret=True)
+
+    w1, c1 = run(1)
+    wk, ck = run(kb)
+    np.testing.assert_array_equal(np.asarray(ck), np.asarray(c1))
+    # the k-blocked view names exactly the surviving k-tiles
+    got = np.zeros_like(mask)
+    k_, real = pl._step_subtiles(jnp.asarray(wk.step_k),
+                                 jnp.asarray(wk.step_flags), kb)
+    k_, real = np.asarray(k_), np.asarray(real)
+    si, sj = np.asarray(wk.step_i), np.asarray(wk.step_j)
+    for c in range(kb):
+        got[si[real[:, c]], sj[real[:, c]], k_[real[:, c], c]] = True
+    np.testing.assert_array_equal(got, mask)
+    assert wk.step_i.shape[0] == 64  # padded to the bucket
+    np.testing.assert_array_equal(wk.klist, w1.klist)  # pair view per tile
+
+
+def _legacy_tables(ii, jj, kk, s):
+    """One step per surviving triple, INIT/ACC/FLUSH only: the tables of the
+    one-tile-a-step kernel, built independently of compact_from_triples."""
+    order = np.lexsort((kk, jj, ii))
+    ii, jj, kk = ii[order], jj[order], kk[order]
+    v = ii.size
+    flags = np.full(v, smm.STEP_ACC, np.int32)
+    first = np.ones(v, bool)
+    first[1:] = (ii[1:] != ii[:-1]) | (jj[1:] != jj[:-1])
+    last = np.append(first[1:], True)
+    flags[first] |= smm.STEP_INIT
+    flags[last] |= smm.STEP_FLUSH
+    pad = lambda x, fill: np.concatenate([x, np.full(s - v, fill)])
+    return (pad(ii, ii[-1]), pad(jj, jj[-1]), pad(kk, kk[-1]), pad(flags, 0))
+
+
+def test_kb_choice_kb1_tables_full_gate_and_vmem_bound():
+    """kb = 1 reproduces the one-tile-a-step tables exactly; a full gate at
+    tile 128 takes the widest k-block the VMEM budget admits; the budget is
+    what bounds it, at f32 and at bf16."""
+    from repro.core import cost
+    from repro.kernels.common import VMEM_BUDGET, worklist_vmem_bytes
+
+    mask = _kb_mask("banded")
+    ii, jj, kk = np.nonzero(mask)
+    w, _ = pl.compact_from_triples(ii, jj, kk, gm=KB_GM, gn=KB_GN, gk=KB_GK)
+    s = w.step_i.shape[0]
+    for got, want in zip((w.step_i, w.step_j, w.step_k, w.step_flags),
+                         _legacy_tables(ii, jj, kk, s)):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    # a plan that chooses kb = 1 carries those same tables
+    coeffs = cost.DEFAULT_COEFFS["pallas"]
+    free_steps = coeffs._replace(step_overhead_s=0.0)
+    assert cost.choose_kb(ii, jj, kk, gk=KB_GK, tile=128, block_n=1,
+                          dtype="float32", coeffs=free_steps) == 1
+
+    gm, gn, gk = 2, 4, 64
+    full = [x.ravel() for x in np.mgrid[0:gm, 0:gn, 0:gk]]
+    for dtype, block_n, want in (("float32", 1, 16), ("float32", 4, 8),
+                                 ("bfloat16", 4, 16), ("int8", 1, 1)):
+        kb = cost.choose_kb(*full, gk=gk, tile=128, block_n=block_n,
+                            dtype=dtype, coeffs=coeffs)
+        assert kb == want, (dtype, block_n, kb)
+        isize = 1 if dtype == "int8" else 2 if dtype == "bfloat16" else 4
+        assert worklist_vmem_bytes(128, kb, block_n, isize) <= VMEM_BUDGET
+        if kb < 16 and dtype != "int8":
+            assert worklist_vmem_bytes(128, 2 * kb, block_n,
+                                       isize) > VMEM_BUDGET
+    # whole k-blocks only: 12 k-tiles admit 1, 2 and 4
+    assert cost.kb_candidates(12, tile=128, block_n=1,
+                              dtype="float32") == [1, 2, 4]
