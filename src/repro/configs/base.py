@@ -63,6 +63,37 @@ class MoEConfig:
     impl: str = "tp"                    # "tp": ff-dim TP; "ep": expert-parallel
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001    # load-balancing aux loss
+    # routing (DeepSeek-V3's "noaux_tc": sigmoid scores, a correction bias
+    # that only selects, normalised top-k weights times a routed scale);
+    # every config normalises its top-k weights
+    scoring: str = "softmax"            # softmax | sigmoid
+    score_bias: bool = False            # seeded e_score_correction_bias
+    routed_scale: float = 1.0
+    shared_gate: bool = True            # sigmoid gate on the shared expert
+    held: int = 0                       # experts this chip holds, ids
+                                        # [0, held); 0 = all of them
+
+    @property
+    def held_experts(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:                         # multi-head latent attention
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached floats per token and layer: the latent and the shared
+        rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -103,6 +134,9 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
+    mla: Optional[MLAConfig] = None     # latent attention in place of MHA
+    first_dense: int = 0                # leading layers with a dense d_ff
+                                        # MLP before the MoE layers
     frontend: Optional[str] = None      # None | "vision_stub" | "audio_stub"
     subquadratic: bool = False          # eligible for long_500k
     notes: str = ""
@@ -130,6 +164,18 @@ class ModelConfig:
                 shared_ff=64 if self.moe.num_shared else 0,
                 top_k=min(self.moe.top_k, 2),
             )
+            if self.moe.held:
+                # a chip's share: 8 of 16 experts held, top-3 over all 16
+                kw["moe"] = dataclasses.replace(
+                    kw["moe"], num_experts=min(self.moe.num_experts, 16),
+                    held=min(self.moe.held, 8),
+                    top_k=min(self.moe.top_k, 3))
+        if self.mla is not None:
+            kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                                  qk_rope_head_dim=8, v_head_dim=16)
+        if self.first_dense:
+            kw["first_dense"] = 1
+            kw["num_layers"] = 3       # the dense layer and two MoE layers
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(self.ssm, state=16, head_dim=16, chunk=32)
         if self.rglru is not None:
@@ -210,6 +256,10 @@ ARCH_IDS = (
     "mixtral-8x22b",
     "musicgen-large",
 )
+
+# every config the zoo builds: the dry-run's ten, and the configs the chip
+# benchmark serves beyond them
+ZOO_IDS = ARCH_IDS + ("moonlight-16b-a3b",)
 
 # archs for which long_500k runs (sub-quadratic sequence mixing); the rest
 # record a documented skip (see DESIGN.md §6).

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.core import cost as _cost
 from repro.core import plan as _plan
 from repro.core.plan import WeightPlanCache, pad_to_tile
+from repro.kernels import ops as kops
 
 
 class GemmTap(NamedTuple):
@@ -407,3 +408,91 @@ def maybe_spamm_matmul(x: jax.Array, w: jax.Array, spamm_cfg: Any,
     )
     ctx.tap(frac, nbytes, site=site)
     return y
+
+
+class _Segments(NamedTuple):
+    seg_first: jax.Array
+    seg_last: jax.Array
+
+
+def _segments(pair: jax.Array) -> _Segments:
+    """First and last step index of each step's run of equal `pair`."""
+    s = pair.shape[0]
+    idx = jnp.arange(s, dtype=jnp.int32)
+    change = pair[1:] != pair[:-1]
+    new = jnp.concatenate([jnp.ones(1, bool), change])
+    end = jnp.concatenate([change, jnp.ones(1, bool)])
+    first = jax.lax.cummax(jnp.where(new, idx, 0))
+    last = jax.lax.cummin(jnp.where(end, idx, s - 1), reverse=True)
+    return _Segments(first, last)
+
+
+def spamm_experts_frozen(x: jax.Array, w: jax.Array, fx, tile_expert,
+                         live) -> tuple:
+    """One gated GEMM of an expert layer over a chunk of its routed rows.
+
+    x: (R, K) rows sorted by expert, each expert's segment padded to whole
+    row tiles; w: (E, K, N) the held experts' weights; `fx` the layer's
+    `plans.frozen.FrozenExperts` at this site (a jit argument);
+    `tile_expert` (R // tile,) int32 each row tile's expert; `live` (traced
+    int32) how many leading row tiles hold rows. Each row tile takes its
+    expert's frozen steps and the traced activation gate
+    `norm_a[i, k] · nbmax[e, k, j] ≥ τ` sets their flags, as
+    `core.plan._plan_frozen` does for one weight. On a backend with the
+    expert work-list kernel only the live tiles' steps carry flags: the
+    steps past them revisit the last live step's blocks, so tiles past
+    `live`, and experts with no routed row, read no weight block and run
+    no tile product; elsewhere a masked einsum over each tile's expert
+    weight.
+
+    Returns (y (R, N) in x's dtype, surviving tile products, gate size: live
+    row tiles × K tiles × N tiles, the live tiles' steps)."""
+    tile, kb = fx.tile, fx.kb
+    r, k = x.shape
+    e, _, n = w.shape
+    gk, gn = fx.gk, fx.gn
+    ct = r // tile
+    xp = pad_to_tile(x, tile)
+    wp = pad_to_tile(w, tile)
+    bk = kops.get_backend(fx.backend)
+    norm_a = bk.norms(xp, tile)                         # (ct, gk)
+    per = fx.steps_per_tile
+    te = jnp.asarray(tile_expert, jnp.int32)
+    live = jnp.asarray(live, jnp.int32)
+    si = jnp.repeat(jnp.arange(ct, dtype=jnp.int32), per)
+    wi = jnp.tile(jnp.arange(per, dtype=jnp.int32), ct)
+    es = te[si]
+    sj = fx.st_j[es, wi]
+    sk = fx.st_k[es, wi]
+    real = (wi < fx.st_n[es]) & (si < live)
+    ks = sk[:, None] * kb + jnp.arange(kb, dtype=jnp.int32)
+    pa = norm_a[si[:, None], ks]
+    pb = fx.nbmax[es[:, None], ks, sj[:, None]]
+    sub = real[:, None] & (pa * pb >= jnp.asarray(fx.tau, jnp.float32))
+    valid = jnp.sum(sub, dtype=jnp.int32)
+    gate = live * gk * gn
+    steps = live * per
+    if bk.matmul_worklist_experts is not None:
+        flags = _plan._frozen_step_flags(_segments(si * gn + sj),
+                                         jnp.any(sub, axis=1))
+        if kb > 1:
+            bits = sub.astype(jnp.int32) << (
+                _plan.STEP_SUB + jnp.arange(kb, dtype=jnp.int32))
+            flags = flags | jnp.sum(bits, axis=1, dtype=jnp.int32)
+        # a static grid over every tile's steps: the live tiles' steps
+        # come first, and the rest repeat the last of them with no flag
+        pos = jnp.arange(ct * per, dtype=jnp.int32)
+        last = jnp.minimum(pos, jnp.maximum(steps - 1, 0))
+        flags = jnp.where(pos < steps, flags, 0)
+        sb = es * (gk // kb) + sk
+        c = bk.matmul_worklist_experts(
+            xp, wp.reshape(e * gk * tile, gn * tile), si[last], sj[last],
+            sk[last], sb[last], flags, tile, kb, jnp.float32)
+    else:
+        mask = jnp.zeros((ct, gk, gn), jnp.int32).at[
+            si[:, None], ks, sj[:, None]].max(sub.astype(jnp.int32))
+        at = xp.reshape(ct, tile, gk, tile)
+        wt = wp[te].reshape(ct, gk, tile, gn, tile)
+        c = jnp.einsum("itkc,ikcjn,ikj->itjn", at, wt,
+                       mask.astype(xp.dtype)).reshape(ct * tile, gn * tile)
+    return c[:, :n].astype(x.dtype), valid, gate, steps

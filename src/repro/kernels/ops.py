@@ -84,6 +84,14 @@ class Backend:
       QUANTIZED view plus the per-tile quantization scales from one read.
       None ⇒ `int8_norms_and_scales` composes the unfused
       quantize→dequantize→norms path (bit-identical results either way).
+    matmul_worklist_experts(a, b, step_i, step_j, step_k, step_b,
+                            step_flags, tile, kb, out_dtype)
+                                                   → (M, N) out_dtype
+      the work-list kernel over an expert layer's routed rows: each row
+      tile of `a` reads its expert's weight from the stacked (E·K, N) `b`,
+      step s at B's k-block `step_b[s]`.
+      None ⇒ `core.module.spamm_experts_frozen` gates and multiplies with
+      a masked einsum.
     compiled: the kernels go through the TPU compiler, whose block layout
       rules bind the tile (`check_tile`); interpret/jnp take any tile.
     """
@@ -95,6 +103,7 @@ class Backend:
     matmul_worklist: Callable[..., jax.Array] = None
     matmul_worklist_int8: Callable[..., jax.Array] = None
     norms_quant: Callable[..., tuple] = None
+    matmul_worklist_experts: Callable[..., jax.Array] = None
     compiled: bool = False
 
     def check_tile(self, tile: int) -> None:
@@ -165,6 +174,16 @@ def _pallas_matmul_worklist(interpret):
     return matmul_worklist
 
 
+def _pallas_matmul_worklist_experts(interpret):
+    def matmul_worklist_experts(a, b, step_i, step_j, step_k, step_b,
+                                step_flags, tile, kb, out_dtype):
+        return _spamm_mm.spamm_mm_worklist_moe(
+            a, b, step_i, step_j, step_k, step_b, step_flags, tile=tile,
+            kb=kb, out_dtype=out_dtype, interpret=interpret)
+
+    return matmul_worklist_experts
+
+
 def _pallas_norms_quant(interpret):
     def norms_quant(x, tile, use_mxu=False):
         return _getnorm.tile_norms_quant(
@@ -197,12 +216,16 @@ BACKENDS = {
                          pyramid_norms=_pallas_pyramid_norms(True),
                          matmul_worklist=_pallas_matmul_worklist(True),
                          matmul_worklist_int8=_pallas_matmul_worklist_int8(True),
-                         norms_quant=_pallas_norms_quant(True)),
+                         norms_quant=_pallas_norms_quant(True),
+                         matmul_worklist_experts=(
+                             _pallas_matmul_worklist_experts(True))),
     "pallas": Backend("pallas", _pallas_norms(False), _pallas_matmul(False),
                       pyramid_norms=_pallas_pyramid_norms(False),
                       matmul_worklist=_pallas_matmul_worklist(False),
                       matmul_worklist_int8=_pallas_matmul_worklist_int8(False),
                       norms_quant=_pallas_norms_quant(False),
+                      matmul_worklist_experts=(
+                          _pallas_matmul_worklist_experts(False)),
                       compiled=True),
 }
 

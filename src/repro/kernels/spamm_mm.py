@@ -465,3 +465,91 @@ def spamm_mm_worklist_int8(
         name="spamm_mm_worklist_int8",
     )(step_i, step_j, step_k, step_flags, a_scale, b_scale,
       varying(jnp.zeros((m, n), out_dtype), vma), a_q, b_q)
+
+
+def _spamm_mm_worklist_moe_kernel(si_ref, sj_ref, sk_ref, sb_ref, fl_ref,
+                                  *refs, kb: int, tile: int):
+    del sb_ref  # read by the B index map only
+    _spamm_mm_worklist_kernel(si_ref, sj_ref, sk_ref, fl_ref, *refs, kb=kb,
+                              tile=tile)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("tile", "out_dtype", "interpret", "kb"),
+)
+def spamm_mm_worklist_moe(
+    a: jax.Array,
+    b: jax.Array,
+    step_i: jax.Array,
+    step_j: jax.Array,
+    step_k: jax.Array,
+    step_b: jax.Array,
+    step_flags: jax.Array,
+    *,
+    tile: int = 64,
+    kb: int = 1,
+    out_dtype=jnp.float32,
+    interpret: bool = False,
+) -> jax.Array:
+    """The work-list kernel over an expert layer's routed rows: each row
+    tile of `a` belongs to one expert, and its tile products read that
+    expert's weight.
+
+    a: (M, K) routed rows, sorted by expert, each expert's segment padded
+    to whole row tiles. b: (E·K, N), the experts' (K, N) weights stacked.
+    Step s reads A block (step_i[s], step_k[s]) and B block (step_b[s],
+    step_j[s]): `step_b` is the k-block of the stacked B, its row tile's
+    expert's k-block `step_k[s]` (expert · K/(tile·kb) + step_k). Tables
+    and flags as `spamm_mm_worklist`'s (one step per k-block of `kb`
+    k-tiles); steps with no flag bit revisit the previous step's blocks
+    and do nothing, so a caller pads its tables past the live row tiles by
+    repeating the last live step. Output tiles no step flushes stay zero.
+    Returns C: (M, N) in out_dtype."""
+    m, k = a.shape
+    kt, n = b.shape
+    assert m % tile == 0 and k % (tile * kb) == 0 and n % tile == 0, (
+        a.shape, b.shape, tile, kb)
+    assert kt % k == 0, (a.shape, b.shape)
+    assert 1 <= kb <= KB_MAX, kb
+    s = step_i.shape[0]
+    assert (step_j.shape == step_k.shape == step_b.shape == step_flags.shape
+            == (s,))
+    if not interpret:
+        check_tile(tile)
+        check_smem("spamm_mm_worklist_moe", step_i, step_j, step_k, step_b,
+                   step_flags)
+        check_vmem("spamm_mm_worklist_moe", worklist_vmem_bytes(
+            tile, kb, 1, a.dtype.itemsize, jnp.dtype(out_dtype).itemsize))
+
+    tk = tile * kb
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s,),
+        in_specs=[
+            pl.BlockSpec((tile, tile),
+                         lambda s, si, sj, sk, sb, fl: (si[s], sj[s])),
+            pl.BlockSpec((tile, tk),
+                         lambda s, si, sj, sk, sb, fl: (si[s], sk[s])),
+            pl.BlockSpec((tk, tile),
+                         lambda s, si, sj, sk, sb, fl: (sb[s], sj[s])),
+        ],
+        out_specs=pl.BlockSpec(
+            (tile, tile), lambda s, si, sj, sk, sb, fl: (si[s], sj[s])),
+        scratch_shapes=[pltpu.VMEM((tile, tile), jnp.float32)],
+    )
+    vma = mesh_vma(a, b, step_i, step_j, step_k, step_b, step_flags)
+    return pl.pallas_call(
+        functools.partial(_spamm_mm_worklist_moe_kernel, kb=kb, tile=tile),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
+        # index 5 counts the scalar-prefetch tables (the zeros operand
+        # seeds the output buffer, as in spamm_mm_worklist)
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="spamm_mm_worklist_moe",
+    )(step_i, step_j, step_k, step_b, step_flags,
+      varying(jnp.zeros((m, n), out_dtype), vma), a, b)

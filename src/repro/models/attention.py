@@ -66,8 +66,9 @@ def _causal_flash_packed(q5, k4, v4, scale, chunk):
     (o, m, l) carry resets at each row start; normalized row outputs are
     emitted at row ends and gathered afterwards.
     """
-    b, nq, qc, hk, g, d = q5.shape
+    b, nq, qc, hk, g, _ = q5.shape
     nk = k4.shape[1]
+    d = v4.shape[-1]
     assert nq == nk and k4.shape[2] == qc
 
     pairs = [(iq, ik) for iq in range(nq) for ik in range(iq + 1)]
@@ -117,7 +118,7 @@ def _causal_flash_packed(q5, k4, v4, scale, chunk):
 def flash_attention(
     q: jax.Array,            # (B, Sq, Hq, D)
     k: jax.Array,            # (B, Skv, Hk, D)
-    v: jax.Array,            # (B, Skv, Hk, D)
+    v: jax.Array,            # (B, Skv, Hk, Dv) — Dv may differ from D
     *,
     causal: bool = True,
     window: Optional[int] = None,
@@ -129,6 +130,7 @@ def flash_attention(
 ) -> jax.Array:
     b, sq, hq, d = q.shape
     _, skv, hk, _ = k.shape
+    dv = v.shape[-1]
     g = hq // hk
     scale = 1.0 / math.sqrt(d)
     # only a STATIC offset can drive the banded dynamic-slice window path or
@@ -171,13 +173,13 @@ def flash_attention(
             return None, per_q(iq, qc)
 
         _, o = jax.lax.scan(scan_body, None, (jnp.arange(nq), q5.swapaxes(0, 1)))
-        o = o.swapaxes(0, 1)  # (B, nq, Hk, G, qc, D)
-        o = o.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, hq, d)
+        o = o.swapaxes(0, 1)  # (B, nq, Hk, G, qc, Dv)
+        o = o.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, hq, dv)
         return o[:, :sq_real].astype(q.dtype)
 
     nk = skv // kv_chunk
     k4 = k.reshape(b, nk, kv_chunk, hk, d)
-    v4 = v.reshape(b, nk, kv_chunk, hk, d)
+    v4 = v.reshape(b, nk, kv_chunk, hk, dv)
 
     if (
         causal
@@ -214,7 +216,7 @@ def flash_attention(
             l_acc = l_acc * r_old + l * r_new
             return (o_acc, m_new, l_acc), None
 
-        o0 = jnp.zeros((b, hk, g, q_chunk, d), jnp.float32)
+        o0 = jnp.zeros((b, hk, g, q_chunk, dv), jnp.float32)
         m0 = jnp.full((b, hk, g, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hk, g, q_chunk), jnp.float32)
         (o, m, l), _ = jax.lax.scan(
@@ -230,7 +232,7 @@ def flash_attention(
 
     _, o = jax.lax.scan(scan_body, None, (jnp.arange(nq), q5.swapaxes(0, 1)))
     o = o.swapaxes(0, 1)
-    o = o.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, hq, d)
+    o = o.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, hq, dv)
     return o[:, :sq_real].astype(q.dtype)
 
 
@@ -288,6 +290,28 @@ def decode_attention(
     )
     o = o / jnp.maximum(l, 1e-30)[..., None]
     return o.reshape(b, hq, d).astype(q.dtype)
+
+
+def latent_decode_attention(
+    q_lat: jax.Array,    # (B, H, C) — queries absorbed into the latent space
+    q_rope: jax.Array,   # (B, H, R) — roped query part
+    latent: jax.Array,   # (B, S, C + R) — cached latents, then roped keys
+    length,              # scalar: number of valid token positions
+    scale: float,
+) -> jax.Array:
+    """Latent attention of one new token per sequence over a latent cache:
+    scores q_lat·c + q_rope·k_rope, softmax over the valid positions, and
+    the weighted sum of the latents c. Returns (B, H, C) f32."""
+    c = q_lat.shape[-1]
+    s = (jnp.einsum("bhc,bsc->bhs", q_lat, latent[..., :c],
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,bsr->bhs", q_rope, latent[..., c:],
+                      preferred_element_type=jnp.float32)) * scale
+    valid = jnp.arange(latent.shape[1]) < length
+    s = jnp.where(valid[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bsc->bhc", p, latent[..., :c],
+                      preferred_element_type=jnp.float32)
 
 
 def _local_cache_update(cache_loc, new_val, slot, offset, s_loc):

@@ -70,9 +70,15 @@ def init_params(cfg: ModelConfig, pcfg: ParallelConfig, key,
         }
     else:
         lkeys = jax.random.split(k_layers, cfg.num_layers)
+        fd = cfg.first_dense
+        if fd:
+            params["dense"] = jax.vmap(
+                lambda k: tr.layer_params(k, cfg, pdt, kind, model_axis_size,
+                                          dense=True)
+            )(lkeys[:fd])
         params["layers"] = jax.vmap(
             lambda k: tr.layer_params(k, cfg, pdt, kind, model_axis_size)
-        )(lkeys)
+        )(lkeys[fd:])
     return params
 
 
@@ -208,6 +214,8 @@ def init_cache(cfg: ModelConfig, pcfg: ParallelConfig, batch: int,
 
     def attn_cache():
         s = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
+        if cfg.mla is not None:      # latents and roped keys, no per-head K/V
+            return {"lat": jnp.zeros((batch, s, cfg.mla.latent_dim), cdt)}
         return {
             "k": jnp.zeros((batch, s, hk, hd), cdt),
             "v": jnp.zeros((batch, s, hk, hd), cdt),
@@ -245,6 +253,10 @@ def init_cache(cfg: ModelConfig, pcfg: ParallelConfig, batch: int,
         )
         tailc = {f"l{i}": rec_cache() for i, _ in enumerate(tail)}
         return {"groups": groups, "tail": tailc}
+    if cfg.first_dense:
+        return {"dense": stack_cache(attn_cache, cfg.first_dense),
+                "layers": stack_cache(attn_cache,
+                                      cfg.num_layers - cfg.first_dense)}
     return {"layers": stack_cache(attn_cache, cfg.num_layers)}
 
 

@@ -49,6 +49,8 @@ class NetCtx(NamedTuple):
 # ---------------------------------------------------------------------------
 
 def attn_params(key, cfg: ModelConfig, dtype) -> dict:
+    if cfg.mla is not None:
+        return mla_params(key, cfg, dtype)
     d, hq, hk, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     ks = jax.random.split(key, 4)
     s = 1.0 / math.sqrt(d)
@@ -241,10 +243,143 @@ def attention_prefill_chunk(
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+
+# query/key chunk of latent attention's prefill: the packed causal scan
+# keeps one output block per (q, kv) chunk pair, so a 32 x 1024-token
+# prefill at 64-token chunks would hold 136 blocks (2.3 GB) per layer;
+# at 256, 10 blocks
+MLA_PREFILL_CHUNK = 256
+
+
+def mla_params(key, cfg: ModelConfig, dtype) -> dict:
+    """wq (d, H·(nope+rope)); wkv_a (d, latent + rope) — the compressed
+    latent and the one rotary key all heads share; kv_ln, the latent's RMS
+    norm gain; wkv_b (latent, H·(nope+v)) — the latent's per-head keys and
+    values; wo (H·v, d)."""
+    m, h, d = cfg.mla, cfg.num_heads, cfg.d_model
+    ks = jax.random.split(key, 4)
+    s = 1.0 / math.sqrt(d)
+    return {
+        "wq": jax.random.normal(ks[0], (d, h * m.qk_head_dim), dtype) * s,
+        "wkv_a": jax.random.normal(ks[1], (d, m.latent_dim), dtype) * s,
+        "kv_ln": jnp.zeros((m.kv_lora_rank,), jnp.float32),
+        "wkv_b": jax.random.normal(
+            ks[2], (m.kv_lora_rank, h * (m.qk_nope_head_dim + m.v_head_dim)),
+            dtype) / math.sqrt(m.kv_lora_rank),
+        "wo": jax.random.normal(ks[3], (h * m.v_head_dim, d), dtype)
+        / math.sqrt(h * m.v_head_dim),
+    }
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array,
+                     theta: float) -> jax.Array:
+    """Rotary positions on interleaved pairs (x0, x1), (x2, x3), ...: the
+    pairs are first split into halves (evens, then odds) and the halves
+    rotated, as DeepSeek-V3's `apply_rotary_pos_emb_interleave` does. The
+    result is in the split order; queries and keys share it, so their dot
+    products are those of the interleaved rotation."""
+    d = x.shape[-1]
+    x = x.reshape(*x.shape[:-1], d // 2, 2).swapaxes(-1, -2).reshape(x.shape)
+    return apply_rope(x, positions, theta)
+
+
+def _mla_project(p, x, cfg: ModelConfig, positions, spamm_cfg, frozen,
+                 require_frozen: bool = False):
+    """(q_nope (B,S,H,nope), q_rope (B,S,H,rope), latent (B,S,C+rope)):
+    the queries and the token's cache entry — its normed latent and its
+    roped shared key."""
+    b, s, _ = x.shape
+    m, h = cfg.mla, cfg.num_heads
+    fz = frozen or {}
+    cdt = x.dtype
+    q = maybe_spamm_matmul(x, p["wq"].astype(cdt), spamm_cfg,
+                           frozen=fz.get("wq"), require_frozen=require_frozen,
+                           site="wq").reshape(b, s, h, m.qk_head_dim)
+    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+    q_rope = rope_interleaved(q_rope, positions, cfg.rope_theta)
+    kv = maybe_spamm_matmul(x, p["wkv_a"].astype(cdt), spamm_cfg,
+                            frozen=fz.get("wkv_a"),
+                            require_frozen=require_frozen, site="wkv_a")
+    c, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
+    c = rms_norm(c, p["kv_ln"], cfg.norm_eps)
+    k_rope = rope_interleaved(k_rope[:, :, None], positions,
+                              cfg.rope_theta)[:, :, 0]
+    return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+
+
+def mla_layer(p, x, cfg: ModelConfig, pcfg: ParallelConfig, ctx: NetCtx,
+              positions, *, spamm_cfg=None, frozen=None):
+    """Prefill and training: the latent is decompressed by wkv_b into
+    per-head keys and values (the shared rotary key joins every head's
+    key). Returns (out, latent cache entries (B, S, C + rope))."""
+    b, s, _ = x.shape
+    m, h = cfg.mla, cfg.num_heads
+    fz = frozen or {}
+    q_nope, q_rope, latent = _mla_project(p, x, cfg, positions, spamm_cfg,
+                                          frozen)
+    c, k_rope = jnp.split(latent, [m.kv_lora_rank], axis=-1)
+    kv = maybe_spamm_matmul(c, p["wkv_b"].astype(x.dtype), spamm_cfg,
+                            frozen=fz.get("wkv_b"), site="wkv_b")
+    k_nope, v = jnp.split(kv.reshape(b, s, h, -1), [m.qk_nope_head_dim],
+                          axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None],
+                                  (b, s, h, m.qk_rope_head_dim))], axis=-1)
+    q = ctx.shard(q, ctx.batch_axes, None, ctx.model_axis, None)
+    chunk = max(pcfg.attn_q_chunk, MLA_PREFILL_CHUNK)
+    o = attn_mod.flash_attention(q, k, v, causal=True, q_chunk=chunk,
+                                 kv_chunk=chunk)
+    out = maybe_spamm_matmul(o.reshape(b, s, h * m.v_head_dim),
+                             p["wo"].astype(x.dtype), spamm_cfg,
+                             frozen=fz.get("wo"), site="wo")
+    return out, latent
+
+
+def mla_decode(p, x, cache_lat, pos, cfg: ModelConfig, *, spamm_cfg=None,
+               frozen=None):
+    """One new token per sequence at the lockstep position `pos`, attending
+    in the latent space: wkv_b's key half is absorbed into the query and
+    its value half applied to the attended latent (plain f32 einsums), so
+    the cache holds only latents and rotary keys. Returns (out, cache)."""
+    b = x.shape[0]
+    m, h = cfg.mla, cfg.num_heads
+    fz = frozen or {}
+    pos = jnp.asarray(pos, jnp.int32)
+    if pos.ndim:
+        raise NotImplementedError(
+            "latent attention decodes at one lockstep position (per-row "
+            "positions belong to the chunked scheduler, which MLA does not "
+            "take)")
+    posb = jnp.full((b, 1), pos, jnp.int32)
+    q_nope, q_rope, latent = _mla_project(p, x, cfg, posb, spamm_cfg, frozen,
+                                          require_frozen=True)
+    cache_lat = jax.lax.dynamic_update_slice(
+        cache_lat, latent.astype(cache_lat.dtype), (0, pos, 0))
+    wkv_b = p["wkv_b"].astype(jnp.float32).reshape(m.kv_lora_rank, h, -1)
+    wk, wv = jnp.split(wkv_b, [m.qk_nope_head_dim], axis=-1)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(jnp.float32), wk)
+    o_lat = attn_mod.latent_decode_attention(
+        q_lat, q_rope[:, 0].astype(jnp.float32),
+        cache_lat.astype(jnp.float32), pos + 1,
+        1.0 / math.sqrt(m.qk_head_dim))
+    o = jnp.einsum("bhc,chv->bhv", o_lat, wv).astype(x.dtype)
+    out = maybe_spamm_matmul(
+        o.reshape(b, 1, h * m.v_head_dim), p["wo"].astype(x.dtype),
+        spamm_cfg, frozen=fz.get("wo"), require_frozen=True, site="wo")
+    return out, cache_lat
+
+
+# ---------------------------------------------------------------------------
 # layer bodies
 # ---------------------------------------------------------------------------
 
-def layer_params(key, cfg: ModelConfig, dtype, kind: str, model_axis_size: int):
+def layer_params(key, cfg: ModelConfig, dtype, kind: str, model_axis_size: int,
+                 dense: bool = False):
+    """One layer's params; `dense` gives an MoE config's leading dense
+    layers their d_ff MLP."""
     ks = jax.random.split(key, 4)
     if kind == "ssm":
         return {
@@ -259,7 +394,7 @@ def layer_params(key, cfg: ModelConfig, dtype, kind: str, model_axis_size: int):
         p["mix"] = rglru_mod.rglru_params(ks[0], cfg.rglru, cfg.d_model, dtype)
     else:
         p["mix"] = attn_params(ks[0], cfg, dtype)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["moe"] = moe_mod.moe_params(ks[1], cfg.moe, cfg.d_model, dtype,
                                       model_axis_size)
     else:
@@ -294,12 +429,19 @@ def _stack_stats(parts) -> Optional[StepStats]:
 
 
 def _ffn(p, h, cfg: ModelConfig, ctx: NetCtx, spamm_cfg, frozen=None,
-         require_frozen: bool = False):
-    """MLP or MoE sub-layer on normalized input h. Returns (out, aux).
+         require_frozen: bool = False, serving: bool = False):
+    """MLP or MoE sub-layer on normalized input h. Returns (out, aux): aux
+    the router's load-balance loss, or on the share layer a dict of its
+    counters and routes (`moe.moe_share`).
 
-    MoE blocks keep the traced gating path (their expert buffers live
-    inside shard_map; frozen plans cover the dense attention/MLP GEMMs)."""
-    if cfg.moe is not None:
+    Serving (`serving`), and every config that holds a share of its
+    experts, runs the share layer with frozen expert plans; training of
+    configs that hold all experts keeps the capacity-dropping shard_map
+    dispatch on the traced gating path."""
+    if "moe" in p and (serving or cfg.moe.held):
+        return moe_mod.moe_share(p["moe"], h, cfg.moe, cfg.act,
+                                 spamm_cfg=spamm_cfg, frozen=frozen)
+    if "moe" in p:
         return moe_mod.moe_block(
             p["moe"], h, cfg.moe, cfg.act,
             mesh=ctx.mesh, batch_axes=ctx.batch_axes,
@@ -339,7 +481,12 @@ def layer_fwd(
         return x + h, jnp.float32(0.0), (cache if collect_cache else None)
 
     window = cfg.sliding_window if kind == "attn" else None
-    if kind == "attn":
+    if kind == "attn" and cfg.mla is not None:
+        h, lat = mla_layer(p["mix"], rms_norm(x, p["ln1"], cfg.norm_eps), cfg,
+                           pcfg, ctx, positions, spamm_cfg=spamm_cfg,
+                           frozen=fz.get("mix"))
+        cache = {"lat": lat} if collect_cache else None
+    elif kind == "attn":
         if collect_cache:
             h, (k, v) = attention_layer(
                 p["mix"], rms_norm(x, p["ln1"], cfg.norm_eps), cfg, pcfg, ctx,
@@ -361,8 +508,18 @@ def layer_fwd(
         cache = cache if collect_cache else None
     x = x + h
     f, aux = _ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg, ctx, spamm_cfg,
-                  fz.get("mlp"))
+                  _ffn_frozen(p, fz), serving=collect_cache)
+    if isinstance(aux, dict):          # the share layer's step outputs
+        if cache is not None:
+            cache = dict(cache, moe=aux)
+        aux = jnp.float32(0.0)
     return x + f, aux, cache
+
+
+def _ffn_frozen(p, fz):
+    """The frozen plans of a layer's FFN: "mlp", or "moe" on an MoE
+    layer."""
+    return fz.get("moe") if "moe" in p else fz.get("mlp")
 
 
 def layer_prefill_chunk(
@@ -391,8 +548,10 @@ def layer_prefill_chunk(
     )
     new = dict(cache, k=ck, v=cv)
     x = x + h
-    f, _ = _ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg, ctx, spamm_cfg,
-                fz.get("mlp"))
+    f, aux = _ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg, ctx, spamm_cfg,
+                  _ffn_frozen(p, fz), serving=True)
+    if isinstance(aux, dict):
+        new = dict(new, moe=aux)
     return x + f, new
 
 
@@ -417,7 +576,12 @@ def layer_decode(
         )
         return x + h[:, None], new
 
-    if kind == "attn":
+    if kind == "attn" and cfg.mla is not None:
+        h, lat = mla_decode(p["mix"], rms_norm(x, p["ln1"], cfg.norm_eps),
+                            cache["lat"], pos, cfg, spamm_cfg=spamm_cfg,
+                            frozen=fz.get("mix"))
+        new = dict(cache, lat=lat)
+    elif kind == "attn":
         # ring buffer iff the cache is exactly the sliding window (static)
         ring = (
             cfg.sliding_window is not None
@@ -436,8 +600,10 @@ def layer_decode(
         )
         h = h1[:, None]
     x = x + h
-    f, _ = _ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg, ctx, spamm_cfg,
-                fz.get("mlp"), require_frozen=True)
+    f, aux = _ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg, ctx, spamm_cfg,
+                  _ffn_frozen(p, fz), require_frozen=True, serving=True)
+    if isinstance(aux, dict):
+        new = dict(new, moe=aux)
     return x + f, new
 
 
@@ -453,6 +619,13 @@ def hybrid_pattern(cfg: ModelConfig):
     n_groups = cfg.num_layers // glen
     tail = cfg.num_layers - n_groups * glen
     return n_groups, tuple(kinds[k] for k in pat), ("rec",) * tail
+
+
+def chunkable(cfg: ModelConfig) -> bool:
+    """Whether chunked prefill can run the stack: MHA attention stacks of
+    one layer group (latent attention decodes at a lockstep position)."""
+    return (stack_kinds(cfg) == "attn" and cfg.mla is None
+            and not cfg.first_dense)
 
 
 def stack_kinds(cfg: ModelConfig):
@@ -541,20 +714,41 @@ def stack_fwd(
         h, a, s, c = tapped_layer(p, h, kind)
         return (h, aux + a, vs + s, vc + c), (s, c)
 
-    if pcfg.scan_layers:
-        (x, aux, vs, vc), (lvs, lvc) = jax.lax.scan(
-            _remat(body, pcfg), (x, zero, zero, zero), params["layers"]
-        )
-    else:
-        aux = vs = vc = zero
-        ls, lc = [], []
-        for i in range(cfg.num_layers):
-            p = jax.tree.map(lambda t: t[i], params["layers"])
-            (x, aux, vs, vc), (s, c) = _remat(body, pcfg)((x, aux, vs, vc), p)
-            ls.append(s)
-            lc.append(c)
-        lvs, lvc = jnp.stack(ls), jnp.stack(lc)
+    carry = (x, zero, zero, zero)
+    lvs, lvc = [], []
+    for group in stack_groups(params):
+        if pcfg.scan_layers:
+            carry, (gs, gc) = jax.lax.scan(_remat(body, pcfg), carry,
+                                           params[group])
+        else:
+            ls, lc = [], []
+            n = jax.tree.leaves(params[group])[0].shape[0]
+            for i in range(n):
+                p = jax.tree.map(lambda t: t[i], params[group])
+                carry, (s, c) = _remat(body, pcfg)(carry, p)
+                ls.append(s)
+                lc.append(c)
+            gs, gc = jnp.stack(ls), jnp.stack(lc)
+        lvs.append(gs)
+        lvc.append(gc)
+    x, aux, vs, vc = carry
+    lvs, lvc = jnp.concatenate(lvs), jnp.concatenate(lvc)
     return (x, aux, (vs, vc, lvs, lvc)) if tctx is not None else (x, aux)
+
+
+def stack_groups(tree) -> tuple:
+    """The scanned layer groups of a non-hybrid stack in depth order: an
+    MoE config's leading dense layers ("dense"), then "layers"."""
+    return tuple(g for g in ("dense", "layers") if g in tree)
+
+
+def _pop_moe(caches: dict, out: dict) -> dict:
+    """Move the share layers' stacked step outputs (counters, routes) out
+    of a scan's per-layer caches into `out["moe"]`."""
+    if "moe" in caches:
+        caches = dict(caches)
+        out["moe"] = caches.pop("moe")
+    return caches
 
 
 def stack_prefill(
@@ -636,17 +830,22 @@ def stack_prefill(
         return (x, {"groups": gcaches, "tail": tcaches},
                 _stack_stats(parts))
 
-    rows = []
+    out, parts, base = {}, [], 0
+    for group in stack_groups(params):
+        rows = []
 
-    def body(h, pf):
-        p, f = pf
-        h, c, tv, r = layer(p, h, kind, f)
-        rows[:] = [(0,) + y for y in r]
-        return h, (c, tv)
+        def body(h, pf):
+            p, f = pf
+            h, c, tv, r = layer(p, h, kind, f)
+            rows[:] = [(0,) + y for y in r]
+            return h, (c, tv)
 
-    x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
-                                             fz.get("layers", {})))
-    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])
+        x, (caches, tv) = jax.lax.scan(body, x, (params[group],
+                                                 fz.get(group, {})))
+        out[group] = _pop_moe(caches, out)
+        parts.append((tv, rows, 1, base))
+        base += tv.shape[0] if tv is not None else 0
+    return x, out, _stack_stats(parts)
 
 
 def stack_prefill_chunk(
@@ -669,10 +868,11 @@ def stack_prefill_chunk(
 
     Returns (x, cache, stats), the stats as `stack_prefill`'s."""
     kind = stack_kinds(cfg)
-    if kind != "attn":
+    if not chunkable(cfg):
         raise NotImplementedError(
-            f"chunked prefill covers stateless-FFN attention stacks only "
-            f"(got stack kind {kind!r}: recurrent prefill state does not "
+            f"chunked prefill covers stateless-FFN attention stacks of one "
+            f"layer group only (got stack kind {kind!r}, latent attention "
+            f"{cfg.mla is not None}: recurrent prefill state does not "
             f"checkpoint at a chunk boundary)")
     fz = frozen or {}
     tctx = _tap_ctx(spamm_cfg)
@@ -686,10 +886,12 @@ def stack_prefill_chunk(
         rows[:] = [(0,) + y for y in r]
         return h, (nc, tv)
 
+    out = {}
     x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
                                              cache["layers"],
                                              fz.get("layers", {})))
-    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])
+    out["layers"] = _pop_moe(caches, out)
+    return x, out, _stack_stats([(tv, rows, 1, 0)])
 
 
 def stack_decode(
@@ -747,15 +949,20 @@ def stack_decode(
         return (x, {"groups": gcaches, "tail": tcaches},
                 _stack_stats(parts))
 
-    rows = []
+    out, parts, base = {}, [], 0
+    for group in stack_groups(params):
+        rows = []
 
-    def body(h, pcf):
-        p, c, f = pcf
-        (h, nc), tv, r = layer(p, h, c, kind, f)
-        rows[:] = [(0,) + y for y in r]
-        return h, (nc, tv)
+        def body(h, pcf):
+            p, c, f = pcf
+            (h, nc), tv, r = layer(p, h, c, kind, f)
+            rows[:] = [(0,) + y for y in r]
+            return h, (nc, tv)
 
-    x, (caches, tv) = jax.lax.scan(body, x, (params["layers"],
-                                             cache["layers"],
-                                             fz.get("layers", {})))
-    return x, {"layers": caches}, _stack_stats([(tv, rows, 1, 0)])
+        x, (caches, tv) = jax.lax.scan(body, x, (params[group],
+                                                 cache[group],
+                                                 fz.get(group, {})))
+        out[group] = _pop_moe(caches, out)
+        parts.append((tv, rows, 1, base))
+        base += tv.shape[0] if tv is not None else 0
+    return x, out, _stack_stats(parts)

@@ -586,3 +586,89 @@ def stack_plans(fps) -> FrozenPlan:
             "stack_plans needs identical static metadata (shapes/bucket): "
             f"{fp.tree_flatten()[1]} != {aux0}")
     return jax.tree.map(lambda *xs: jnp.stack(xs), *fps)
+
+
+# row tiles an expert's plan is priced at when its k-block is chosen: a
+# decode step's held expert sees one row tile, a prefill chunk's a few
+EXPERT_KB_WIDTH = 8
+
+
+@jax.tree_util.register_pytree_node_class
+class FrozenExperts:
+    """Frozen weight sides of one MoE layer's held experts at one site
+    (w1, w3 or w2): what the expert layer's gated GEMM takes as a jit
+    argument. Row tiles of the routed-row operand belong to experts only at
+    run time, so the step tables are per expert and the traced gate
+    expands them per row tile (`core.module.spamm_experts_frozen`).
+
+    Array fields (children; a leading layer dim when stacked):
+      tau     f32 scalar, the gate threshold
+      nbmax   (E, gk, gn) f32 — each expert's weight tile norms
+      st_j/st_k (E, W) int32 — one row tile's steps per expert: (j, k-block)
+              over the weight-admissible pairs, pair-major, ascending
+              k-block; entries past `st_n` repeat the last real step
+      st_n    (E,) int32 — real steps per expert
+
+    Static metadata (aux): tile, kb, gk, gn, backend."""
+
+    def __init__(self, tau, nbmax, st_j, st_k, st_n, *, tile: int, kb: int,
+                 gk: int, gn: int, backend: str):
+        self.tau = tau
+        self.nbmax = nbmax
+        self.st_j = st_j
+        self.st_k = st_k
+        self.st_n = st_n
+        self.tile = tile
+        self.kb = kb
+        self.gk = gk
+        self.gn = gn
+        self.backend = backend
+
+    def tree_flatten(self):
+        return ((self.tau, self.nbmax, self.st_j, self.st_k, self.st_n),
+                (self.tile, self.kb, self.gk, self.gn, self.backend))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        tile, kb, gk, gn, backend = aux
+        return cls(*children, tile=tile, kb=kb, gk=gk, gn=gn,
+                   backend=backend)
+
+    @property
+    def steps_per_tile(self) -> int:
+        return self.st_j.shape[-1]
+
+
+def freeze_experts(fws, width: int = EXPERT_KB_WIDTH):
+    """Group per-expert `FrozenWeight`s into `FrozenExperts`.
+
+    `fws` is a list (one per layer) of lists (one per held expert). Every
+    layer gets one kb (the smallest any expert's cost model picks at
+    `width` row tiles) and one step count per row tile, so the layers stack
+    into one scan input. Returns the list of per-layer FrozenExperts."""
+    flat = [fw for layer in fws for fw in layer]
+    fw0 = flat[0]
+    if any(fw.block_n != 1 or fw.compute_dtype != "float32" for fw in flat):
+        raise ValueError("expert plans are frozen at block_n 1 in float32")
+    kb = min(fw.choose_kb(width) for fw in flat)
+    steps = [[fw._row_steps(kb) for fw in layer] for layer in fws]
+    w = max(max(j.size for j, _ in layer) for layer in steps)
+    gk, gn = fw0.grid
+    out = []
+    for layer, tables in zip(fws, steps):
+        e = len(layer)
+        st_j = np.zeros((e, w), np.int32)
+        st_k = np.zeros((e, w), np.int32)
+        st_n = np.zeros(e, np.int32)
+        for x, (j, k) in enumerate(tables):
+            n = j.size
+            st_n[x] = n
+            if n:
+                st_j[x, :n], st_j[x, n:] = j, j[-1]
+                st_k[x, :n], st_k[x, n:] = k, k[-1]
+        out.append(FrozenExperts(
+            jnp.asarray(float(np.asarray(fw0.tau)), jnp.float32),
+            jnp.stack([fw.nbmax for fw in layer]),
+            jnp.asarray(st_j), jnp.asarray(st_k), jnp.asarray(st_n),
+            tile=fw0.tile, kb=kb, gk=gk, gn=gn, backend=fw0.backend))
+    return out

@@ -2,36 +2,41 @@
 freeze/store their weight-side plans.
 
 The model zoo gates exactly the GEMMs that go through
-`core.module.maybe_spamm_matmul`: the attention projections (wq/wk/wv/wo
-under a layer's "mix" subtree) and the MLP matmuls (w1/w3/w2 under "mlp").
-MoE expert/shared FFNs are gated too but run inside shard_map with
-per-token buffers; they keep the traced gating path and are not frozen
-(documented engine limitation — their GEMMs simply fall back).
+`core.module.maybe_spamm_matmul`: the attention projections (wq/wk/wv/wo,
+and latent attention's wkv_a/wkv_b, under a layer's "mix" subtree), the MLP
+matmuls (w1/w3/w2 under "mlp") and the MoE shared experts' (under
+"shared"). The held experts' stacked weights (w1/w3/w2 directly under
+"moe") freeze per expert into `FrozenExperts`, which the expert layer's
+work-list kernel reads (`core.module.spamm_experts_frozen`).
 
 `freeze_tree` mirrors the params structure at those leaves: a stacked
 (L, K, N) leaf becomes a list of per-layer `FrozenWeight`s (what
-`stack_plans` later turns into scan inputs), a 2-D leaf a single one.
+`stack_plans` later turns into scan inputs), a 2-D leaf a single one, and
+an expert leaf (L, E, K, N) a list of per-layer `FrozenExperts`.
 `populate` is the CLI-facing store writer (`repro.launch.precompute_plans`).
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 from typing import Optional
 
 import numpy as np
 
-from repro.plans.frozen import FrozenWeight
+from repro.plans.frozen import FrozenWeight, freeze_experts
 from repro.plans.store import PlanStore, fingerprint
 
 # leaf name × parent subtree that identifies a gated GEMM weight
-GATED_NAMES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
-GATED_PARENTS = ("mix", "mlp")
+GATED_NAMES = ("wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "w1", "w2", "w3")
+GATED_PARENTS = ("mix", "mlp", "shared")
+EXPERT_NAMES = ("w1", "w2", "w3")        # under "moe": (..., E, K, N)
 
 
 def iter_gated_weights(params, _prefix=()):
     """Yield (path_tuple, leaf) for every gated GEMM weight in a params
-    pytree: leaves named wq/wk/wv/wo/w1/w2/w3 directly under a "mix" or
-    "mlp" subtree. Stacked leaves (leading layer/group dim) are yielded
-    whole; callers slice axis 0 per layer."""
+    pytree: leaves named wq/wk/wv/wo/wkv_a/wkv_b/w1/w2/w3 directly under a
+    "mix", "mlp" or "shared" subtree. Stacked leaves (leading layer/group
+    dim) are yielded whole; callers slice axis 0 per layer."""
     if not isinstance(params, dict):
         return
     for name, sub in params.items():
@@ -40,6 +45,20 @@ def iter_gated_weights(params, _prefix=()):
             yield from iter_gated_weights(sub, path)
         elif (len(path) >= 2 and path[-2] in GATED_PARENTS
               and name in GATED_NAMES and getattr(sub, "ndim", 0) >= 2):
+            yield path, sub
+
+
+def iter_expert_weights(params, _prefix=()):
+    """Yield (path_tuple, leaf) for every MoE layer's stacked expert weight
+    (w1/w3/w2 directly under "moe", shaped (..., E, K, N))."""
+    if not isinstance(params, dict):
+        return
+    for name, sub in params.items():
+        path = _prefix + (name,)
+        if isinstance(sub, dict):
+            yield from iter_expert_weights(sub, path)
+        elif (len(path) >= 2 and path[-2] == "moe" and name in EXPERT_NAMES
+              and getattr(sub, "ndim", 0) >= 3):
             yield path, sub
 
 
@@ -97,7 +116,7 @@ def _freeze_one(w, scfg, *, cache=None, store: Optional[PlanStore] = None,
 
 
 def freeze_tree(params, scfg, *, cache=None, store: Optional[PlanStore] = None,
-                use_mxu: bool = False):
+                use_mxu: bool = False, span=None):
     """Freeze every gated weight of a params pytree.
 
     Returns (tree, count): `tree` mirrors the params dict structure at the
@@ -110,7 +129,11 @@ def freeze_tree(params, scfg, *, cache=None, store: Optional[PlanStore] = None,
     With `scfg.autotune`, each 2-D weight is tuned individually; a stacked
     leaf is tuned ONCE (from its first slice) and every layer slice is
     frozen at that shared config — stacked per-layer plans must agree on
-    block_n/levels/bucket to ride one lax.scan (`stack_plans`)."""
+    block_n/levels/bucket to ride one lax.scan (`stack_plans`).
+
+    `span(name, **args)` (a context-manager factory, e.g. an
+    `Observability.span`) times the expert weights' freezing as
+    "freeze_experts"."""
     autotune = getattr(scfg, "autotune", False)
     profile = None
     if autotune:
@@ -141,6 +164,25 @@ def freeze_tree(params, scfg, *, cache=None, store: Optional[PlanStore] = None,
         for name in path[:-1]:
             node = node.setdefault(name, {})
         node[path[-1]] = fz
+    for path, leaf in iter_expert_weights(params):
+        # (L, E, K, N) or (E, K, N): one FrozenWeight per expert, grouped
+        # per layer into FrozenExperts (expert plans are never autotuned:
+        # every expert of a stack shares one kb and one step count)
+        with (span("freeze_experts", site="/".join(path)) if span
+              else contextlib.nullcontext()):
+            flat = np.asarray(leaf).reshape(-1, *leaf.shape[-3:])
+            ecfg = dataclasses.replace(scfg, block_n=1, levels=0,
+                                       dtype="float32", autotune=False)
+            fws = [[_freeze_one(flat[l, e], ecfg, cache=cache, store=store,
+                                use_mxu=use_mxu)
+                    for e in range(flat.shape[1])]
+                   for l in range(flat.shape[0])]
+            count += flat.shape[0] * flat.shape[1]
+            fz = freeze_experts(fws)
+        node = tree
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = fz if leaf.ndim > 3 else fz[0]
     return tree, count
 
 

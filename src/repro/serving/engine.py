@@ -35,7 +35,7 @@ from repro.core import module as spmod
 from repro.core import schedule as _schedule
 from repro.core.plan import _bucket
 from repro.models import model as M
-from repro.models.transformer import NetCtx, stack_kinds
+from repro.models.transformer import NetCtx, chunkable, stack_kinds
 from repro.obs import (FRACTION_BUCKETS, Histogram, LATENCY_BUCKETS_S,
                        Observability)
 
@@ -244,11 +244,13 @@ class Engine:
                 raise ValueError(
                     f"prefill_chunk must be >= 1 (or 0/None), got "
                     f"{prefill_chunk}")
-            if stack_kinds(cfg) != "attn":
+            if not chunkable(cfg):
                 raise ValueError(
-                    f"chunked prefill needs a stateless-FFN attention stack "
-                    f"(got {stack_kinds(cfg)!r}: recurrent prefill state "
-                    f"does not checkpoint at a chunk boundary)")
+                    f"chunked prefill needs a stateless-FFN MHA attention "
+                    f"stack (got {stack_kinds(cfg)!r}, latent attention "
+                    f"{cfg.mla is not None}: recurrent prefill state does "
+                    f"not checkpoint at a chunk boundary, and latent "
+                    f"attention decodes at one lockstep position)")
             if enabled and c % self.spamm_ctx.cfg.tile:
                 raise ValueError(
                     f"prefill_chunk={c} must be a multiple of the SpAMM "
@@ -280,6 +282,12 @@ class Engine:
         # the last wave's prefill logits and, when sharded, the slots of its
         # real requests (`first_logits` gathers them on read)
         self._first = None
+        # the last wave's expert routes per step (device arrays, read
+        # lazily by `moe_routes`) and the MoE step outputs of the wave in
+        # flight, (phase, {"counts", "routes"})
+        self._routes = None
+        self._wave_moe: list = []
+        self._groups: dict = {}  # (batch, prompt_len) → prefill groups
         self._ndev = int(mesh_devices) if mesh_devices else 0
         self._sharded = self._ndev > 1
         self._shard_width = shard_max_width
@@ -289,11 +297,12 @@ class Engine:
                     "mesh_devices > 1 needs frozen plans (per-shard step "
                     "tables ARE the sharding mechanism) — enable spamm_cfg "
                     "and keep freeze_plans on")
-            if cfg.moe is not None:
+            if cfg.moe is not None or cfg.mla is not None:
                 raise ValueError(
-                    "pod-sharded serving cannot take MoE archs yet: expert "
-                    "FFNs open their own shard_map over the outer mesh "
-                    "(per-expert frozen plans are the ROADMAP item)")
+                    "pod-sharded serving cannot take MoE or latent-"
+                    "attention archs yet: its shards re-gather only MHA "
+                    "caches, and the expert layer holds no shard of its "
+                    "routed rows")
             devs = jax.devices()
             if len(devs) < self._ndev:
                 raise ValueError(
@@ -393,7 +402,7 @@ class Engine:
 
     def _build_steps(self):
         cfg, pcfg = self.cfg, self.pcfg
-        chunkable = stack_kinds(cfg) == "attn"
+        can_chunk = chunkable(cfg)
         if not self._sharded:
             self._prefill = jax.jit(self._counted(_two_outputs(
                 M.make_prefill_step(cfg, pcfg, self.ctx,
@@ -405,7 +414,7 @@ class Engine:
                 cache_in=2), "decode"))
             # chunked prefill shares the "prefill" trace counter: the
             # jit-cache-bound guard counts every prefill-side trace
-            self._chunk = None if not chunkable else jax.jit(self._counted(
+            self._chunk = None if not can_chunk else jax.jit(self._counted(
                 _two_outputs(M.make_prefill_chunk_step(
                     cfg, pcfg, self.ctx, spamm_cfg=self.spamm_ctx),
                     cache_in=2), "prefill"))
@@ -448,7 +457,7 @@ class Engine:
             in_specs=(P(), P("rows"), P("rows"), P(), P("rows")),
             out_specs=(P("rows"), P("rows"))))
         self._chunk = None
-        if chunkable:
+        if can_chunk:
             inner_chunk = M.make_prefill_chunk_step(
                 cfg, pcfg, body_ctx, spamm_cfg=self.spamm_ctx)
 
@@ -465,6 +474,18 @@ class Engine:
                 in_specs=(P(), P("rows"), P("rows"), P("rows"), P("rows"),
                           P("rows")),
                 out_specs=(P("rows"), P("rows"))))
+
+    @property
+    def moe_routes(self):
+        """The last wave's expert selections, per MoE layer, as the steps
+        returned them: (prefill (L, B·P, top_k), decode (steps, L, B,
+        top_k) or None) int32 device arrays, token rows in request order
+        (prompt position-major within a request). None before a wave and
+        for configs without the share layer."""
+        if self._routes is None:
+            return None
+        pre, dec = self._routes
+        return pre, (jnp.stack(dec) if dec else None)
 
     @property
     def first_logits(self):
@@ -563,11 +584,17 @@ class Engine:
             return self._assemble_frozen(gm)
 
     def _assemble_frozen(self, gm: int) -> dict:
-        from repro.plans.frozen import stack_plans
+        from repro.plans.frozen import FrozenExperts, stack_plans
 
         def specialize(node):
             if isinstance(node, dict):
                 return {k: specialize(v) for k, v in node.items()}
+            if isinstance(node, list) and isinstance(node[0], FrozenExperts):
+                # expert plans take no row grid: their step tables expand
+                # per routed row tile inside the step
+                return jax.tree.map(lambda *xs: jnp.stack(xs), *node)
+            if isinstance(node, FrozenExperts):
+                return node
             if isinstance(node, list):
                 # per-layer plans must share one kb (the smallest any layer
                 # chose) and one step bucket to stack into a scan input;
@@ -601,10 +628,14 @@ class Engine:
             "spamm_block_fill", labelnames=("site", "gm"),
             help="frozen tile products over the k-blocks the steps cover")
 
+        from repro.plans.frozen import FrozenExperts
+
         def walk(fws, fps, path):
             if isinstance(fws, dict):
                 for k in fws:
                     walk(fws[k], fps[k], path + (k,))
+                return
+            if isinstance(fps, FrozenExperts):
                 return
             layers = fws if isinstance(fws, list) else [fws]
             kb = fps.kb
@@ -627,7 +658,8 @@ class Engine:
                                store=self.plan_store is not None):
                 self._fw_tree, _ = freeze_tree(
                     self.params, self.spamm_ctx.cfg,
-                    cache=self.spamm_ctx.cache, store=self.plan_store)
+                    cache=self.spamm_ctx.cache, store=self.plan_store,
+                    span=self.obs.span)
 
     def _note_gm(self, gm: int, n: int = 1):
         self._gm_hist[int(gm)] = self._gm_hist.get(int(gm), 0) + int(n)
@@ -807,9 +839,11 @@ class Engine:
 
         def grow(path, t):
             keys = [getattr(k, "key", None) for k in path]
-            if keys and keys[-1] in ("k", "v") and t.shape[-3] < target:
+            # MHA K/V (..., S, Hk, hd); latent attention's (..., S, C)
+            ax = {"k": -3, "v": -3, "lat": -2}.get(keys[-1] if keys else None)
+            if ax is not None and t.shape[ax] < target:
                 pad = [(0, 0)] * t.ndim
-                pad[-3] = (0, target - t.shape[-3])
+                pad[ax] = (0, target - t.shape[ax])
                 return jnp.pad(t, pad)
             return t
 
@@ -1079,7 +1113,8 @@ class Engine:
                 frozen_pre = self._sharded_frozen_for(plen)
                 frozen_dec = self._sharded_frozen_for(1)
             else:
-                frozen_pre = self._frozen_for(b * plen)
+                groups = self._prefill_groups(b, plen)
+                frozen_pre = self._frozen_for(b * plen // groups)
                 frozen_dec = self._frozen_for(b) if self._freeze else {}
             tile = self.spamm_ctx.cfg.tile if collect else 0
             outs = [[] for _ in range(b)]
@@ -1098,6 +1133,14 @@ class Engine:
                 pend = tracer.begin("prefill")
                 cache, logits = self._sharded_chunk_prefill(
                     toks_in, plen, chunk, steps)
+            elif not self._sharded and groups > 1:
+                opening.end()
+                pend = tracer.begin("prefill")
+                cache, logits = self._grouped_prefill(toks_in, groups,
+                                                      frozen_pre, steps)
+                if collect:
+                    self._note_gm(-(-(b * plen // groups) // tile), groups)
+                cache = self._pad_cache(cache)
             else:
                 batch = {"tokens": jnp.asarray(toks_in)}
                 opening.end()
@@ -1169,6 +1212,59 @@ class Engine:
             return self._close_wave(requests, outs, steps, deltas, ttft_s,
                                     decode_lat)
 
+    def _prefill_groups(self, b: int, plen: int) -> int:
+        """Request groups a wave's one-shot prefill runs in: 1, unless a
+        frozen plan's step tables at the wave's rows would overflow the
+        work-list kernel's SMEM (`kernels.common.check_smem`); then the
+        fewest power-of-two groups of whole requests whose tables fit."""
+        if not self._freeze:
+            return 1
+        hit = self._groups.get((b, plen))
+        if hit is not None:
+            return hit
+        from repro.kernels import common
+        from repro.plans.frozen import FrozenWeight
+
+        self._ensure_fw_tree()
+        tile = self.spamm_ctx.cfg.tile
+        budget = (common.SMEM_BYTES - common.SMEM_RESERVE) // 16
+
+        def fits(node, gm):
+            if isinstance(node, dict):
+                return all(fits(v, gm) for v in node.values())
+            layers = node if isinstance(node, list) else [node]
+            if not isinstance(layers[0], FrozenWeight):
+                return True          # expert plans chunk their own rows
+            kb = min(fw.choose_kb(gm) for fw in layers)
+            return max(_bucket(fw.real_steps(gm, kb), fw.bucket_floor)
+                       for fw in layers) <= budget
+
+        g = 1
+        while (not fits(self._fw_tree, -(-(b * plen // g) // tile))
+               and b % (2 * g) == 0):
+            g *= 2
+        self._groups[(b, plen)] = g
+        return g
+
+    def _grouped_prefill(self, toks_in: np.ndarray, groups: int, frozen,
+                         steps: list):
+        """The wave's prefill as `groups` calls over consecutive equal
+        groups of requests, their caches, logits and expert routes joined
+        in request order (every cache leaf of a layer stack is (layers,
+        batch, ...))."""
+        per = toks_in.shape[0] // groups
+        caches, logits = [], []
+        for g in range(groups):
+            cache, lg = self._prefill(
+                self.params,
+                {"tokens": jnp.asarray(toks_in[g * per:(g + 1) * per])},
+                frozen)
+            caches.append(self._keep_stats(cache, "prefill", steps))
+            logits.append(lg)
+        cache = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1),
+                             *caches)
+        return cache, jnp.concatenate(logits)
+
     def _counters(self) -> tuple:
         """The plan-cache, plan-store and re-shard counters at a wave's
         start, for the wave's deltas (`_spamm_stats`)."""
@@ -1188,21 +1284,80 @@ class Engine:
         with self.obs.span("wave_close"):
             spamm_meta = (None if deltas is None else self._spamm_stats(
                 steps, *deltas, ttft_s, decode_lat))
+            moe_meta = self._moe_stats()
             results = [np.asarray(o, np.int32) for o in outs]
             if self.obs.enabled:
                 self._m_waves.inc()
                 self._m_tokens.inc(sum(len(o) for o in results))
             for r, toks_out in zip(requests, results):
                 r.out = {"tokens": toks_out, "spamm": spamm_meta}
+                if moe_meta is not None:
+                    r.out["moe"] = moe_meta
         return results
+
+    def _moe_stats(self) -> Optional[dict]:
+        """The wave's expert-layer counters (`moe.COUNTERS`), fetched in
+        one transfer: per phase, summed over the phase's steps and the MoE
+        layers (`busiest_rows` their largest), with the phase's step count
+        (`steps`) and `tile` the routed-row tile. Feeds the registry per
+        (phase, layer); keeps the wave's routes on the device for
+        `moe_routes` (prefill calls joined in request order). None
+        without a share layer."""
+        wave, self._wave_moe = self._wave_moe, []
+        if not wave:
+            return None
+        from repro.models.moe import COUNTERS
+
+        host = jax.device_get([m["counts"] for _, m in wave])
+        pre = [m["routes"] for ph, m in wave if ph == "prefill"]
+        self._routes = (jnp.concatenate(pre, axis=1),
+                        [m["routes"] for ph, m in wave if ph == "decode"])
+        base = self.cfg.first_dense
+        per = {}
+        for (phase, _), v in zip(wave, host):
+            v = np.asarray(v, np.int64).reshape(-1, len(COUNTERS))
+            acc = per.setdefault(phase, np.zeros_like(v))
+            busiest = np.maximum(acc[:, 1], v[:, 1])
+            acc += v
+            acc[:, 1] = busiest
+        tile = (self.spamm_ctx.cfg.tile
+                if self.spamm_ctx is not None and self.spamm_ctx.enable
+                else None)
+        meta = {"tile": tile}
+        for phase, acc in per.items():
+            tot = dict(zip(COUNTERS, acc.sum(0).tolist()))
+            tot["busiest_rows"] = int(acc[:, 1].max())
+            tot["steps"] = sum(ph == phase for ph, _ in wave)
+            meta[phase] = tot
+            if not self.obs.enabled:
+                continue
+            reg = self.obs.registry
+            for layer, row in enumerate(acc.tolist()):
+                lab = {"phase": phase, "layer": str(base + layer)}
+                c = dict(zip(COUNTERS, row))
+                for name, key in (("moe_rows", "routed_rows"),
+                                  ("moe_tiles", "row_tiles"),
+                                  ("moe_empty_experts", "empty_experts"),
+                                  ("moe_dropped_rows", "dropped_rows")):
+                    reg.counter(name, labelnames=("phase", "layer"),
+                                help=f"expert layer {key} per step, summed"
+                                ).inc(c[key], **lab)
+                reg.gauge("moe_busiest_rows", labelnames=("phase", "layer"),
+                          help="rows of the busiest held expert in the "
+                               "last wave").set(c["busiest_rows"], **lab)
+        return meta
 
     def _keep_stats(self, cache, phase: str, steps: list):
         """A step's returned cache without its gating stats, which join the
-        wave's `steps` (left on the device until the wave closes)."""
-        if _STATS_KEY not in cache:
-            return cache
-        steps.append((phase, cache[_STATS_KEY]))
-        return {k: v for k, v in cache.items() if k != _STATS_KEY}
+        wave's `steps`, or its expert layers' counters and routes, which
+        join the wave's MoE record (both left on the device until the wave
+        closes)."""
+        if "moe" in cache:
+            self._wave_moe.append((phase, cache["moe"]))
+        if _STATS_KEY in cache:
+            steps.append((phase, cache[_STATS_KEY]))
+        return {k: v for k, v in cache.items()
+                if k not in (_STATS_KEY, "moe")}
 
     def _sharded_chunk_prefill(self, toks_in: np.ndarray, plen: int,
                                chunk: int, steps: list):

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import repro.models.layers as L
-from repro.configs import ARCH_IDS, ParallelConfig, TrainConfig, get_config
+from repro.configs import ZOO_IDS, ParallelConfig, TrainConfig, get_config
 from repro.models import model as M
 from repro.models.transformer import NetCtx
 from repro.optim.adamw import AdamW
@@ -36,7 +36,7 @@ def _inputs(cfg, key=1):
                                          cfg.vocab)}
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", ZOO_IDS)
 def test_forward_and_train_step(arch):
     cfg = get_config(arch).reduced()
     ctx = _ctx()
@@ -61,7 +61,7 @@ def test_forward_and_train_step(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", ZOO_IDS)
 def test_decode_matches_forward(arch):
     cfg = get_config(arch).reduced()
     if cfg.moe is not None:  # avoid capacity drops confounding the check
@@ -88,9 +88,11 @@ def test_decode_matches_forward(arch):
 
     def grow_kv(path, t):
         keys = [getattr(k, "key", None) for k in path]
-        if keys and keys[-1] in ("k", "v") and t.shape[-3] == S - 1:
+        # MHA K/V (..., S, Hk, hd); latent attention's (..., S, C)
+        ax = {"k": -3, "v": -3, "lat": -2}.get(keys[-1] if keys else None)
+        if ax is not None and t.shape[ax] == S - 1:
             pad = [(0, 0)] * t.ndim
-            pad[-3] = (0, 1)
+            pad[ax] = (0, 1)
             return jnp.pad(t, pad)
         return t
 

@@ -172,3 +172,21 @@ def test_largest_smem_worklist_compiles(one_chip):
              _spec(one_chip, (n, n), jnp.float32),
              *[_spec(one_chip, (s,), jnp.int32)] * 4)
     np.testing.assert_array_less(4 * 4 * s, SMEM_BYTES)
+
+
+@pytest.mark.parametrize("rows,k,n,kb", [
+    (16384, 2048, 1408, 16),   # a prefill chunk's w1/w3: 128 row tiles
+    (16384, 1408, 2048, 1),    # its w2: 11 k-tiles admit kb 1 only
+    (1024, 2048, 1408, 16),    # a decode step: one row tile per expert
+])
+def test_expert_worklist_compiles(one_chip, rows, k, n, kb):
+    """The expert layer's work-list kernel at moonlight-16b-a3b's widths,
+    8 held experts stacked under B, one step per k-block of every row
+    tile."""
+    s = (rows // TILE) * (n // TILE) * (k // (TILE * kb))
+    _compile(lambda a, b, si, sj, sk, sb, fl:
+             spamm_mm.spamm_mm_worklist_moe(a, b, si, sj, sk, sb, fl,
+                                            tile=TILE, kb=kb),
+             _spec(one_chip, (rows, k), jnp.float32),
+             _spec(one_chip, (8 * k, n), jnp.float32),
+             *[_spec(one_chip, (s,), jnp.int32)] * 5)
